@@ -1,8 +1,9 @@
 """Figs. 8/9 analog: distributed-shared-memory (ICI) benchmarks.
 
 RBC ring copy + bin-partitioned histogram need >1 device, so they run
-in a subprocess with a forced 8-device host platform (the main process
-keeps its single device).  Wall-clock on host-CPU "ICI" measures the
+in a subprocess on a forced 8-device CPU platform, pinned to
+JAX_PLATFORMS=cpu: the parent has imported JAX, and on a TPU host it
+holds the chip, which a second process cannot open.  Wall-clock on host-CPU "ICI" measures the
 XLA collective machinery, not real links; the derived column carries
 the v5e-modeled throughput (core/dsm.modeled_rbc_throughput).
 """
@@ -73,6 +74,7 @@ def dsm_bench():
     rows = []
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _CHILD],
                           capture_output=True, text=True, env=env,
                           cwd=os.path.dirname(os.path.dirname(

@@ -21,12 +21,14 @@ from benchmarks import (async_copy, dpx, dsm, llm_gen, memory,  # noqa: F401
                         roofline_table, te_layer, te_linear,
                         tensorcore)
 from repro.core.bench import run_all
+from repro.launch import compile_cache
 
 SERVING_JSON = "BENCH_serving.json"
 
 
 def main() -> None:
     names = sys.argv[1:] or None
+    compile_cache.enable()
     failures = run_all(names)
     if llm_gen.SERVING_RESULTS:
         with open(SERVING_JSON, "w") as f:

@@ -38,12 +38,11 @@ HOT_PATH_ROOTS = [
 ]
 
 # attention score/PV bodies that must stay explicit multiply+sum (the
-# PR-6 bitwise kernel-vs-gather contract: XLA strength-reduces small-M
-# dots data-dependently, so dot/einsum formulations drift ~1 ulp).
-# path suffix (repo-relative) -> function names.
+# bitwise agreement of the XLA serving paths: XLA strength-reduces
+# small-M dots data-dependently, so dot/einsum formulations drift
+# ~1 ulp).  path suffix (repo-relative) -> function names.
 PARITY_BODIES = {
     "models/attention.py": {"decode_attention", "chunk_attention"},
-    "kernels/paged_attention.py": {"sdpa_rows"},
 }
 
 # packages scanned by ast_lint (plus the PARITY_BODIES files);

@@ -287,6 +287,12 @@ def default_workloads() -> List[Tuple[str, Callable[[], Any]]]:
         return lambda: jax.eval_shape(functools.partial(raw(fn), **kw),
                                       *args)
 
+    def fp8_scaled(fn):
+        # the scale pools are arrays the wrapper indexes, so they are
+        # traced positionally rather than bound as keywords
+        return lambda q, k, v, bt, n, ks, vs: fn(q, k, v, bt, n,
+                                                 k_scale=ks, v_scale=vs)
+
     B, KH, G, hd, bs, NB, MB, C = 2, 2, 2, 64, 16, 8, 4, 16
     return [
         ("matmul_train_auto",
@@ -321,29 +327,29 @@ def default_workloads() -> List[Tuple[str, Callable[[], Any]]]:
         ("paged_decode",
          ev(ops.paged_decode_attention,
             _sds(B, 1, KH * G, hd, dtype=bf16),
-            _sds(NB, bs, KH, hd, dtype=bf16),
-            _sds(NB, bs, KH, hd, dtype=bf16),
+            _sds(NB, KH, bs, hd, dtype=bf16),
+            _sds(NB, KH, bs, hd, dtype=bf16),
             _sds(B, MB, dtype=i32), _sds(B, dtype=i32))),
         ("paged_decode_fp8",
-         ev(ops.paged_decode_attention,
+         ev(fp8_scaled(ops.paged_decode_attention),
             _sds(B, 1, KH * G, hd, dtype=bf16),
-            _sds(NB, bs, KH, hd, dtype=e4m3),
-            _sds(NB, bs, KH, hd, dtype=e4m3),
+            _sds(NB, KH, bs, hd, dtype=e4m3),
+            _sds(NB, KH, bs, hd, dtype=e4m3),
             _sds(B, MB, dtype=i32), _sds(B, dtype=i32),
-            k_scale=_sds(NB, bs, KH, 1), v_scale=_sds(NB, bs, KH, 1))),
+            _sds(NB, KH, bs), _sds(NB, KH, bs))),
         ("paged_chunk",
          ev(ops.paged_chunk_attention,
             _sds(B, C, KH * G, hd, dtype=bf16),
-            _sds(NB, bs, KH, hd, dtype=bf16),
-            _sds(NB, bs, KH, hd, dtype=bf16),
+            _sds(NB, KH, bs, hd, dtype=bf16),
+            _sds(NB, KH, bs, hd, dtype=bf16),
             _sds(B, MB, dtype=i32), _sds(B, dtype=i32))),
         ("paged_chunk_fp8",
-         ev(ops.paged_chunk_attention,
+         ev(fp8_scaled(ops.paged_chunk_attention),
             _sds(B, C, KH * G, hd, dtype=bf16),
-            _sds(NB, bs, KH, hd, dtype=e4m3),
-            _sds(NB, bs, KH, hd, dtype=e4m3),
+            _sds(NB, KH, bs, hd, dtype=e4m3),
+            _sds(NB, KH, bs, hd, dtype=e4m3),
             _sds(B, MB, dtype=i32), _sds(B, dtype=i32),
-            k_scale=_sds(NB, bs, KH, 1), v_scale=_sds(NB, bs, KH, 1))),
+            _sds(NB, KH, bs), _sds(NB, KH, bs))),
     ]
 
 

@@ -14,8 +14,9 @@ Three artifacts, mirroring the paper's three DSM benchmarks:
     (reduce_scatter routing) vs. private per-core histograms (psum)
 
 These functions are mesh-generic; tests/benchmarks run them on a
-host-platform CPU mesh in a subprocess (so the main process keeps a
-single device).
+forced 8-device CPU platform in a child process pinned to
+JAX_PLATFORMS=cpu, so the child never reaches for an accelerator the
+parent holds.
 """
 
 from __future__ import annotations
@@ -27,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _ring_perm(axis_size: int):
@@ -46,7 +46,7 @@ def rbc_ring_copy(x: jax.Array, mesh: Mesh, axis: str, *, hops: int = 1,
     assert hops < size or size == 1
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+        jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     def _rbc(xs):
         chunks = jnp.split(xs, ilp, axis=-1) if ilp > 1 else [xs]
         acc = [c for c in chunks]
@@ -67,7 +67,7 @@ def ring_latency_probe(mesh: Mesh, axis: str) -> jax.Array:
     x = jnp.arange(size, dtype=jnp.int32).reshape(size, 1)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+        jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     def _hop(xs):
         return lax.ppermute(xs, axis, _ring_perm(size))
 
@@ -82,7 +82,7 @@ def histogram_private_psum(values: jax.Array, nbins: int, mesh: Mesh,
     histograms are summed.  VMEM cost per core: O(nbins).
     """
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=P(axis), out_specs=P())
+        jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P())
     def _hist(vals):
         local = jnp.zeros((nbins,), jnp.int32).at[vals].add(1)
         return lax.psum(local, axis)
@@ -105,7 +105,7 @@ def histogram_dsm(values: jax.Array, nbins: int, mesh: Mesh, axis: str
     assert nbins % size == 0, "bins must split evenly across the cluster"
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+        jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     def _hist(vals):
         local = jnp.zeros((nbins,), jnp.int32).at[vals].add(1)
         # reduce_scatter: each rank receives the summed shard it owns
@@ -120,7 +120,7 @@ def all_to_all_exchange(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
     size = mesh.shape[axis]
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+        jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     def _a2a(xs):
         # xs: [chunk, size, ...] -> exchange dim 1 across the axis
         return lax.all_to_all(xs.reshape(size, -1), axis, split_axis=0,

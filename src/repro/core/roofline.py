@@ -240,7 +240,11 @@ def paged_decode_kv_bytes(kv_len: int, *, block_size: int,
     traffic for unallocated tail blocks.
 
     mode="fp8_kernel": same walk on an e4m3 pool — 1 byte per element
-    plus one f32 scale per token-row per kv-head (`scale_bytes`).
+    of the valid blocks — plus the f32 scales (`scale_bytes` per
+    token row per kv-head), which the kernel wrapper gathers for the
+    slot's whole table extent: three passes like the gather path
+    (read the scale pool, write the per-slot rows, the kernel reads
+    them).
 
     The factor-of-3 gather overhead and the valid-block-only kernel
     traffic are what BENCH_serving.json's `modeled_decode_speedup`
@@ -253,7 +257,8 @@ def paged_decode_kv_bytes(kv_len: int, *, block_size: int,
     if mode == "kernel":
         return valid_tokens * row * kv_dtype_bytes * 2
     if mode == "fp8_kernel":
-        return valid_tokens * kv_heads * (head_dim + scale_bytes) * 2
+        scales = 3 * max_blocks * block_size * kv_heads * scale_bytes
+        return (valid_tokens * row + scales) * 2
     raise ValueError(f"unknown mode {mode!r}")
 
 

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 INT_MIN = jnp.iinfo(jnp.int32).min // 2
 
@@ -62,7 +61,7 @@ def tropical_matmul(a: jax.Array, b: jax.Array, *, bm: int = 32,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -158,7 +157,7 @@ def smith_waterman(seq_a: jax.Array, seq_b: jax.Array, *, match: int = 2,
             pltpu.VMEM((1, width_p), jnp.int32),
             pltpu.VMEM((1, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lanes)[:, 0]

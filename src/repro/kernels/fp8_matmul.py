@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def fp8_matmul_kernel(sx_ref, sw_ref, a_ref, b_ref, o_ref, acc_ref):
     k = pl.program_id(2)
@@ -54,7 +52,7 @@ def fp8_matmul(aq: jax.Array, bq: jax.Array, sx: jax.Array, sw: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(sx.reshape(1), sw.reshape(1), aq, bq)

@@ -12,8 +12,9 @@ evenly) but a tile strictly larger than its operand dimension raises
 a mis-sized launch, which is exactly the class of bug the decode-tile
 audit was after.
 
-`interpret` defaults to True off-TPU so the whole suite validates on
-this CPU host; on a real TPU backend it compiles to Mosaic.
+`interpret` defaults to True on the CPU backend so the whole suite
+validates there; on a TPU backend the kernels compile to Mosaic, and
+any other backend is an error (paged_attention.resolve_interpret).
 """
 
 from __future__ import annotations
@@ -34,12 +35,7 @@ from repro.kernels import matmul as _mm
 from repro.kernels import paged_attention as _paged
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interp(interpret: Optional[bool]) -> bool:
-    return (not on_tpu()) if interpret is None else interpret
+_interp = _paged.resolve_interpret
 
 
 class TileAlignmentWarning(UserWarning):

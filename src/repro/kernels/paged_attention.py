@@ -1,43 +1,46 @@
-"""Paged flash-decode/chunk attention: block-table walk inside the kernel.
+"""Paged decode/chunk attention: block-table walk inside the kernel.
 
-The serving hot path (runtime/server.py) reads KV through
-`attention.gather_paged_cache`, which materializes a
-[B, max_blocks*block_size, KH, hd] virtual view per layer per step — an
-O(max_len) gather that costs exactly the HBM bandwidth the paper's
-memory-hierarchy dissection says decode must conserve.  These kernels
-never build that view: each (b, kv_head) grid cell walks the slot's
-block table, DMAs only the `ceil(kv_len/bs)` *valid* physical blocks
-from the pool (ANY/HBM memory space) into a VMEM scratch, and runs the
-softmax(QK^T)V rows there.  Unallocated table entries (-1) beyond the
-valid prefix are never touched — the loop bound comes from `kv_len`,
-not the table width — so poisoned pool blocks cannot leak (the gather
-path instead relies on masking; see attention.gather_paged_cache).
+The gather read path (`attention.gather_paged_cache`) materializes a
+[B, max_blocks*block_size, KH, hd] view per layer per step, an
+O(max_len) copy whatever the slot's live context.  These kernels never
+build that view: each (slot, kv-head) grid cell walks the slot's block
+table, DMAs only the `ceil(kv_len/bs)` *valid* blocks of its head from
+the pool (HBM, memory space ANY) into a VMEM scratch, and attends its
+query rows there.  Table entries past the valid prefix are never
+touched — the loop bound comes from the slot's position, not the table
+width — so poisoned pool blocks cannot leak (the gather path instead
+relies on masking; see attention.gather_paged_cache).
 
-Bit-parity contract
--------------------
-The bf16/f32 kernels are BITWISE identical to the gather path
-(`gather_paged_cache` + `decode_attention`/`chunk_attention`).  That
-only holds because both sides compute scores and the PV contraction as
-an explicit broadcast-multiply + `jnp.sum` in fp32 (`sdpa_rows` here,
-the batched analog in models/attention.py): XLA strength-reduces
-small-M `dot_general`s (the G=1 decode matvec) data-dependently inside
-larger jitted graphs, so a dot-based kernel and a dot-based oracle
-round differently at ~1 ulp.  The mul+reduce form lowers to the same
-HLO in both, eagerly, jitted, and under shard_map.  Scratch rows past
-the valid frontier are zero-filled: the oracle's masked positions carry
-exact-0.0 softmax weight (NEG_INF scores underflow), and 0.0 * x == 0.0
-for any finite x, so the padded sums agree bitwise too.
+Pool layout
+-----------
+Head-major blocks, ``[NB, KH, bs, hd]`` per layer: one (block, head)
+tile is a contiguous ``[bs, hd]`` slab, so the per-head DMA slices only
+leading dims.  A ``[NB, bs, KH, hd]`` pool would slice its KH axis down
+to 1, and the TPU compiler refuses DMA slices that are not aligned to
+the memory tiling.
 
-FP8 layout (e4m3 KV pool)
--------------------------
-With `k_scale`/`v_scale` given, the pools hold e4m3 codes and the
-scales hold one f32 per token-row per kv-head ([NB, bs, KH, 1] — the
-"per-block scales" of the TE recipe at block = pool row).  The kernel
-DMAs the fp8 block plus its scale column and dequantizes in-tile
-(`(codes.astype(f32) * scale).astype(q.dtype)`) into the same VMEM
-scratch — elementwise identical to the dequantizing gather in
-models/attention.gather_paged_cache_fp8, so fp8-kernel vs fp8-gather
-is still bit-exact; only fp8-vs-bf16 needs a tolerance tier.
+Decode is the one-row case of the chunk kernel: a decode at `kv_len` is
+a chunk of one query position at `pos = kv_len - 1`.
+
+Numerics
+--------
+Scores and the PV contraction are `dot_general`s with float32
+accumulation (the MXU on a TPU).  Scratch rows past the valid frontier
+are zero-filled, and masked scores carry exactly-zero softmax weight.
+The result is not bitwise equal to the gather path, whose attention is
+an XLA multiply+sum: tests/test_paged_kernel.py compares both against
+a float32 reference within tolerances stated there.
+
+FP8 pools
+---------
+With `k_scale`/`v_scale` (``[NB, KH, bs]`` f32, one scale per token row
+per kv-head, rows on the lane axis) the pools hold e4m3 codes.  The
+kernel converts the codes to q.dtype (exact: every e4m3 value is a
+bf16 value) and applies the scales after the contractions — scores
+per key column, softmax weights per value row — so no scale ever has
+to move from the lane axis to the sublane axis.  The wrapper gathers
+the slot's scale rows through the block table into ``[B, KH, 1, T]``
+(4 bytes per token row, next to the hd bytes of codes).
 """
 
 from __future__ import annotations
@@ -47,117 +50,151 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _interp(interpret: Optional[bool]) -> bool:
-    return (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Pallas interpret mode: explicit, or chosen by backend — compiled
+    on a TPU, interpreted on the CPU (the test suite), and an error on
+    any other backend, where these TPU kernels cannot run."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels need a TPU (or interpret mode on the CPU); "
+        f"the default backend is {backend!r}")
 
 
-def sdpa_rows(q2: jax.Array, k2: jax.Array, v2: jax.Array,
-              bound: jax.Array) -> jax.Array:
-    """softmax(q2 @ k2^T / sqrt(hd)) @ v2 for q2 [R, hd] vs k2/v2
-    [T, hd], with per-row valid length `bound` [R] int32; fp32 out.
+def _attend(q, k, v, pos, group, k_scale=None, v_scale=None):
+    """Causal attention of query rows q [R, hd] against k/v [T, hd].
 
-    Multiply+reduce instead of dot_general — see the module docstring:
-    this is what makes the kernel bitwise-equal to the batched oracle.
-    """
-    hd = q2.shape[-1]
-    s = jnp.sum(q2.astype(jnp.float32)[:, None, :]
-                * k2.astype(jnp.float32)[None, :, :], axis=-1) * hd ** -0.5
-    t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(t < bound[:, None], s, NEG_INF)
+    Row r is query position ``pos + r // group`` and sees keys
+    t <= pos + r // group, i.e. ``group * (t - pos) <= r`` (integer
+    rows; no vector division).  Optional [1, T] fp8 scales multiply
+    the scores per key and the softmax weights per value row.  f32
+    result [R, hd]."""
+    hd = q.shape[-1]
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    if k_scale is not None:
+        s = s * k_scale
+    s = s * hd ** -0.5
+    r = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    t = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(group * (t - pos) <= r, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)
-    pv = p.astype(v2.dtype)
-    return jnp.sum(pv.astype(jnp.float32)[:, :, None]
-                   * v2.astype(jnp.float32)[None, :, :], axis=1)
+    if v_scale is not None:
+        p = p * v_scale
+    return lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
-
-# ----------------------------------------------------------------------
-# block-table walk: DMA valid blocks into VMEM scratch
-# ----------------------------------------------------------------------
 
 def _fetch_blocks(bt_ref, b, kh, nvb, kpool_ref, vpool_ref, k_s, v_s,
-                  sem, *, bs):
-    """Copy physical blocks bt[b, 0:nvb] of both pools into the scratch
-    rows [i*bs, (i+1)*bs).  -1 entries only occur at i >= nvb (the
-    allocator assigns blocks up to the frontier), so the max(.., 0)
-    clamp is pure defense; rows past nvb*bs stay zero-filled."""
+                  stage, sem, *, bs):
+    """Copy head `kh` of physical blocks bt[b, 0:nvb] of both pools into
+    scratch rows [i*bs, (i+1)*bs).  -1 entries only occur at i >= nvb
+    (the allocator assigns blocks up to the frontier), so the
+    max(.., 0) clamp is pure defense.  With a staging buffer (fp8
+    pools) each block lands there first and is converted into the
+    scratch dtype."""
 
-    def body(i, _):
+    def body(i, carry):
         blk = jnp.maximum(bt_ref[b, i], 0)
+        rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
         for pool, dst in ((kpool_ref, k_s), (vpool_ref, v_s)):
-            cp = pltpu.make_async_copy(pool.at[blk, :, kh, :],
-                                       dst.at[pl.ds(i * bs, bs), :], sem)
+            cp = pltpu.make_async_copy(
+                pool.at[blk, kh], dst.at[rows] if stage is None else stage,
+                sem)
             cp.start()
             cp.wait()
-        return 0
+            if stage is not None:
+                dst[rows, :] = stage[...].astype(dst.dtype)
+        return carry
 
-    jax.lax.fori_loop(0, nvb, body, 0)
-
-
-def _fetch_blocks_fp8(bt_ref, b, kh, nvb, kpool_ref, vpool_ref,
-                      ks_ref, vs_ref, k_s, v_s, kq_s, sq_s, sem, *, bs):
-    """fp8 variant: DMA the e4m3 block + its per-row scale column into
-    small staging scratch, dequantize, store into the bf16/f32 rows."""
-
-    def body(i, _):
-        blk = jnp.maximum(bt_ref[b, i], 0)
-        for pool, scl, dst in ((kpool_ref, ks_ref, k_s),
-                               (vpool_ref, vs_ref, v_s)):
-            cp = pltpu.make_async_copy(pool.at[blk, :, kh, :], kq_s, sem)
-            cp.start()
-            cp.wait()
-            cp = pltpu.make_async_copy(scl.at[blk, :, kh, :], sq_s, sem)
-            cp.start()
-            cp.wait()
-            dst[pl.ds(i * bs, bs), :] = (
-                kq_s[...].astype(jnp.float32) * sq_s[...]
-            ).astype(dst.dtype)
-        return 0
-
-    jax.lax.fori_loop(0, nvb, body, 0)
+    lax.fori_loop(0, nvb, body, 0)
 
 
-# ----------------------------------------------------------------------
-# decode: one query row per slot
-# ----------------------------------------------------------------------
-
-def _decode_kernel(bt_ref, len_ref, q_ref, kpool_ref, vpool_ref,
-                   o_ref, k_s, v_s, sem, *, bs):
+def _kernel(bt_ref, pos_ref, q_ref, kpool_ref, vpool_ref, *refs, bs,
+            group, fp8):
+    if fp8:
+        ks_ref, vs_ref, o_ref, k_s, v_s, stage, sem = refs
+    else:
+        o_ref, k_s, v_s, sem = refs
+        stage = None
     b = pl.program_id(0)
     kh = pl.program_id(1)
     k_s[...] = jnp.zeros_like(k_s)
     v_s[...] = jnp.zeros_like(v_s)
-    bound = len_ref[b]
-    nvb = (bound + bs - 1) // bs
+    pos = pos_ref[b]
+    n_pos = q_ref.shape[2] // group
+    nvb = jnp.minimum((pos + n_pos + bs - 1) // bs, bt_ref.shape[1])
     _fetch_blocks(bt_ref, b, kh, nvb, kpool_ref, vpool_ref, k_s, v_s,
-                  sem, bs=bs)
-    G = q_ref.shape[2]
-    o_ref[0, 0] = sdpa_rows(q_ref[0, 0], k_s[...], v_s[...],
-                            jnp.full((G,), bound))
+                  stage, sem, bs=bs)
+    scales = (ks_ref[0, 0], vs_ref[0, 0]) if fp8 else (None, None)
+    o_ref[0, 0] = _attend(q_ref[0, 0], k_s[...], v_s[...], pos, group,
+                          *scales)
 
 
-def _decode_kernel_fp8(bt_ref, len_ref, q_ref, kpool_ref, vpool_ref,
-                       ks_ref, vs_ref, o_ref, k_s, v_s, kq_s, sq_s, sem,
-                       *, bs):
-    b = pl.program_id(0)
-    kh = pl.program_id(1)
-    k_s[...] = jnp.zeros_like(k_s)
-    v_s[...] = jnp.zeros_like(v_s)
-    bound = len_ref[b]
-    nvb = (bound + bs - 1) // bs
-    _fetch_blocks_fp8(bt_ref, b, kh, nvb, kpool_ref, vpool_ref, ks_ref,
-                      vs_ref, k_s, v_s, kq_s, sq_s, sem, bs=bs)
-    G = q_ref.shape[2]
-    o_ref[0, 0] = sdpa_rows(q_ref[0, 0], k_s[...], v_s[...],
-                            jnp.full((G,), bound))
+def _row_scales(scale: jax.Array, block_table: jax.Array) -> jax.Array:
+    """Per-slot scale rows through the table: [NB, KH, bs] ->
+    [B, KH, 1, MB*bs] (the unit axis keeps the kernel's block equal to
+    the array's trailing dims)."""
+    s = scale[jnp.maximum(block_table, 0)]                # [B,MB,KH,bs]
+    B, MB, KH, bs = s.shape
+    return s.transpose(0, 2, 1, 3).reshape(B, KH, 1, MB * bs)
+
+
+def _paged_attention(q, ck, cv, block_table, pos, k_scale, v_scale,
+                     interpret):
+    B, C, H, hd = q.shape
+    NB, KH, bs, _ = ck.shape
+    MB = block_table.shape[1]
+    G, T = H // KH, MB * bs
+    R = C * G
+    # rows ordered (position, group): row r is position pos + r // G
+    qr = q.reshape(B, C, KH, G, hd).transpose(0, 2, 1, 3, 4
+                                              ).reshape(B, KH, R, hd)
+    pos = jnp.broadcast_to(jnp.asarray(pos), (B,)).astype(jnp.int32)
+    fp8 = k_scale is not None
+    scratch_dtype = q.dtype if fp8 else ck.dtype
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    row_spec = pl.BlockSpec((1, 1, R, hd), lambda b, kh, *_: (b, kh, 0, 0))
+    in_specs = [row_spec, pool_spec, pool_spec]
+    operands = [block_table, pos, qr, ck, cv]
+    scratch = [pltpu.VMEM((T, hd), scratch_dtype),
+               pltpu.VMEM((T, hd), scratch_dtype)]
+    if fp8:
+        scale_spec = pl.BlockSpec((1, 1, 1, T),
+                                  lambda b, kh, *_: (b, kh, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        operands += [_row_scales(k_scale, block_table),
+                     _row_scales(v_scale, block_table)]
+        scratch += [pltpu.VMEM((bs, hd), ck.dtype)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, KH),
+        in_specs=in_specs,
+        out_specs=row_spec,
+        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, bs=bs, group=G, fp8=fp8),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KH, R, hd), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(*operands)
+    out = out.reshape(B, KH, C, G, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, C, H, hd).astype(q.dtype)
 
 
 def paged_decode(q: jax.Array, ck: jax.Array, cv: jax.Array,
@@ -165,88 +202,13 @@ def paged_decode(q: jax.Array, ck: jax.Array, cv: jax.Array,
                  k_scale: Optional[jax.Array] = None,
                  v_scale: Optional[jax.Array] = None,
                  interpret: Optional[bool] = None) -> jax.Array:
-    """One-step paged decode.  q [B,1,H,hd]; pools [NB,bs,KH,hd];
-    block_table [B,MB] int32 (-1 = unallocated); kv_len scalar or [B].
-    With `k_scale`/`v_scale` ([NB,bs,KH,1] f32) the pools are e4m3 and
-    the kernel dequantizes in-tile to q.dtype.  Returns [B,1,H,hd]."""
-    B, _, H, hd = q.shape
-    NB, bs, KH, _ = ck.shape
-    MB = block_table.shape[1]
-    G, T = H // KH, MB * bs
-    qg = q.reshape(B, KH, G, hd)
-    kv_len = jnp.broadcast_to(jnp.asarray(kv_len), (B,)).astype(jnp.int32)
-    fp8 = k_scale is not None
-    scratch_dtype = q.dtype if fp8 else ck.dtype
-    pool_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = [pl.BlockSpec((1, 1, G, hd), lambda b, kh, *_: (b, kh, 0, 0)),
-                pool_spec, pool_spec]
-    operands = [block_table, kv_len, qg, ck, cv]
-    scratch = [pltpu.VMEM((T, hd), scratch_dtype),
-               pltpu.VMEM((T, hd), scratch_dtype)]
-    if fp8:
-        in_specs += [pool_spec, pool_spec]
-        operands += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((bs, hd), ck.dtype),
-                    pltpu.VMEM((bs, 1), jnp.float32)]
-        kern = functools.partial(_decode_kernel_fp8, bs=bs)
-    else:
-        kern = functools.partial(_decode_kernel, bs=bs)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KH),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, kh, *_: (b, kh, 0, 0)),
-        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), jnp.float32),
-        interpret=_interp(interpret),
-    )(*operands)
-    return out.reshape(B, 1, H, hd).astype(q.dtype)
-
-
-# ----------------------------------------------------------------------
-# chunk: a C-token prefill window per slot (cache-aware causal)
-# ----------------------------------------------------------------------
-
-def _chunk_kernel(bt_ref, pos_ref, q_ref, kpool_ref, vpool_ref,
-                  o_ref, k_s, v_s, sem, *, bs, C):
-    b = pl.program_id(0)
-    kh = pl.program_id(1)
-    k_s[...] = jnp.zeros_like(k_s)
-    v_s[...] = jnp.zeros_like(v_s)
-    pos = pos_ref[b]
-    nvb = jnp.minimum((pos + C + bs - 1) // bs, bt_ref.shape[1])
-    _fetch_blocks(bt_ref, b, kh, nvb, kpool_ref, vpool_ref, k_s, v_s,
-                  sem, bs=bs)
-    G, hd = q_ref.shape[2], q_ref.shape[4]
-    q2 = q_ref[0, 0].reshape(G * C, hd)
-    # row (g, i) attends cache positions <= pos + i (the chunk's own
-    # k/v is already written at those positions)
-    bound = jnp.tile(pos + 1 + jax.lax.iota(jnp.int32, C), (G,))
-    o_ref[0, 0] = sdpa_rows(q2, k_s[...], v_s[...], bound
-                            ).reshape(G, C, hd)
-
-
-def _chunk_kernel_fp8(bt_ref, pos_ref, q_ref, kpool_ref, vpool_ref,
-                      ks_ref, vs_ref, o_ref, k_s, v_s, kq_s, sq_s, sem,
-                      *, bs, C):
-    b = pl.program_id(0)
-    kh = pl.program_id(1)
-    k_s[...] = jnp.zeros_like(k_s)
-    v_s[...] = jnp.zeros_like(v_s)
-    pos = pos_ref[b]
-    nvb = jnp.minimum((pos + C + bs - 1) // bs, bt_ref.shape[1])
-    _fetch_blocks_fp8(bt_ref, b, kh, nvb, kpool_ref, vpool_ref, ks_ref,
-                      vs_ref, k_s, v_s, kq_s, sq_s, sem, bs=bs)
-    G, hd = q_ref.shape[2], q_ref.shape[4]
-    q2 = q_ref[0, 0].reshape(G * C, hd)
-    bound = jnp.tile(pos + 1 + jax.lax.iota(jnp.int32, C), (G,))
-    o_ref[0, 0] = sdpa_rows(q2, k_s[...], v_s[...], bound
-                            ).reshape(G, C, hd)
+    """One-step paged decode.  q [B,1,H,hd]; pools [NB,KH,bs,hd];
+    block_table [B,MB] int32 (-1 = unallocated); kv_len scalar or [B]
+    (the query sits at position kv_len - 1).  With `k_scale`/`v_scale`
+    ([NB,KH,bs] f32) the pools are e4m3.  Returns [B,1,H,hd]."""
+    kv_len = jnp.broadcast_to(jnp.asarray(kv_len), (q.shape[0],))
+    return _paged_attention(q, ck, cv, block_table, kv_len - 1, k_scale,
+                            v_scale, interpret)
 
 
 def paged_chunk(q: jax.Array, ck: jax.Array, cv: jax.Array,
@@ -259,41 +221,5 @@ def paged_chunk(q: jax.Array, ck: jax.Array, cv: jax.Array,
     chunk's k/v must already be scattered).  Rows past a slot's valid
     token count attend in-pool garbage and produce garbage rows the
     caller discards — same contract as attention.chunk_attention."""
-    B, C, H, hd = q.shape
-    NB, bs, KH, _ = ck.shape
-    MB = block_table.shape[1]
-    G, T = H // KH, MB * bs
-    qc = q.reshape(B, C, KH, G, hd).transpose(0, 2, 3, 1, 4)
-    pos = jnp.broadcast_to(jnp.asarray(pos), (B,)).astype(jnp.int32)
-    fp8 = k_scale is not None
-    scratch_dtype = q.dtype if fp8 else ck.dtype
-    pool_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = [pl.BlockSpec((1, 1, G, C, hd),
-                             lambda b, kh, *_: (b, kh, 0, 0, 0)),
-                pool_spec, pool_spec]
-    operands = [block_table, pos, qc, ck, cv]
-    scratch = [pltpu.VMEM((T, hd), scratch_dtype),
-               pltpu.VMEM((T, hd), scratch_dtype)]
-    if fp8:
-        in_specs += [pool_spec, pool_spec]
-        operands += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((bs, hd), ck.dtype),
-                    pltpu.VMEM((bs, 1), jnp.float32)]
-        kern = functools.partial(_chunk_kernel_fp8, bs=bs, C=C)
-    else:
-        kern = functools.partial(_chunk_kernel, bs=bs, C=C)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KH),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, C, hd),
-                               lambda b, kh, *_: (b, kh, 0, 0, 0)),
-        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, C, hd), jnp.float32),
-        interpret=_interp(interpret),
-    )(*operands)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, C, H, hd).astype(q.dtype)
+    return _paged_attention(q, ck, cv, block_table, pos, k_scale,
+                            v_scale, interpret)

@@ -14,13 +14,8 @@ from jax.sharding import Mesh
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """jax.make_mesh across jax versions: older releases have no
-    jax.sharding.AxisType (meshes are implicitly Auto there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -3,8 +3,14 @@
     PYTHONPATH=src python -m repro.launch.serve --arch yi-6b \
         --requests 8 --slots 4
 
-Reduced configs on CPU; the full configs' serve_step is exercised (and
-memory-proved) by the dry-run decode cells.  ``--workload sysprompt``
+serves the published config (Yi-6B: 32 layers, d_model 4096) with
+random bf16 weights made from ``--seed`` on the device — a TPU run.
+On the CPU serve the same-family reduced config instead:
+
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+        --arch yi-6b --reduced
+
+``--workload sysprompt``
 serves the shared-prefix mix (a few system-prompt templates × unique
 tails) and prints the radix prefix cache's hit-rate stats; disable the
 cache with ``--no-prefix-cache`` for an A/B run.  ``--spec-decode K``
@@ -12,11 +18,11 @@ turns on speculative decoding (n-gram drafts + one-dispatch verify,
 bit-identical outputs); pair it with ``--workload repetitive`` to see
 the accepted-tokens-per-step climb above 1.  ``--tp N`` serves
 tensor-parallel over an N-device mesh (weights head-wise/column-row,
-KV pool along the KV-head axis; token-identical outputs) and prints
-the per-device sharding stats:
+KV pool along the KV-head axis; token-identical outputs on the CPU) and
+prints the per-device sharding stats:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
-        PYTHONPATH=src python -m repro.launch.serve --tp 4
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python -m repro.launch.serve --reduced --tp 4
 
 ``--arrival-rate R`` switches from the closed batch to open-loop
 serving: requests arrive on a Poisson stream at R req/s
@@ -27,7 +33,7 @@ deadlines and print the attainment / goodput / windowed-throughput
 summary (obs/slo, obs/windows).  All of it composes with
 ``--trace/--trace-out``:
 
-    PYTHONPATH=src python -m repro.launch.serve --arrival-rate 4 \
+    PYTHONPATH=src python -m repro.launch.serve --reduced --arrival-rate 4 \
         --duration 5 --slo-ttft-ms 500 --slo-tpot-ms 80 \
         --trace --trace-out /tmp/online
 """
@@ -39,6 +45,8 @@ import argparse
 import jax
 
 from repro.configs import get_config, list_archs, reduced_config
+from repro.launch import compile_cache
+from repro.launch.mesh import make_tp_mesh
 from repro.models import api
 from repro.obs import (SLOSpec, Tracer, phase_summary, slo_report,
                        summary_table, window_series, window_summary,
@@ -48,11 +56,16 @@ from repro.runtime.server import (ChunkedServer, SlotServer,
                                   repetitive_requests,
                                   sharegpt_like_requests,
                                   sysprompt_sharegpt_requests)
+from repro.sharding import plans
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b", choices=list_archs())
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the same-family reduced config (2 "
+                         "layers, d_model 64, vocab 256) instead of the "
+                         "published one — for CPU runs")
     ap.add_argument("--engine", default="chunked",
                     choices=("chunked", "slot"),
                     help="chunked-prefill scheduler (default) or the "
@@ -93,8 +106,8 @@ def main() -> None:
     ap.add_argument("--kernel", action="store_true",
                     help="read paged KV through the fused Pallas "
                          "block-table kernels (kernels/paged_attention"
-                         ") instead of the gather path — bf16 greedy "
-                         "outputs are bit-identical either way "
+                         ") instead of the gather path — outputs "
+                         "agree within the kernel tests' tolerance "
                          "(chunked engine, paged pool)")
     ap.add_argument("--fp8-kv", action="store_true",
                     help="store the paged KV pool as fp8 e4m3 codes + "
@@ -109,8 +122,9 @@ def main() -> None:
                          "shard the weights head-wise/column-row-wise "
                          "and the paged KV pool along its KV-head axis "
                          "over an N-device mesh "
-                         "(sharding/plans.ServingPlan); greedy outputs "
-                         "are token-identical to tp=1.  On CPU fan "
+                         "(sharding/plans.ServingPlan); on the CPU "
+                         "greedy outputs are token-identical to tp=1.  "
+                         "On CPU fan "
                          "devices out first: XLA_FLAGS=--xla_force_"
                          "host_platform_device_count=N")
     ap.add_argument("--workload", default="sharegpt",
@@ -171,13 +185,20 @@ def main() -> None:
     if args.duration is not None:
         args.requests = max(1, round(args.arrival_rate * args.duration))
 
-    cfg = reduced_config(args.arch)
+    compile_cache.enable()
+    cfg = (reduced_config if args.reduced else get_config)(args.arch)
     if cfg.family not in ("dense", "moe", "vlm"):
         raise SystemExit(
             f"{args.arch} ({cfg.family}): the serving engines currently "
             "drive the transformer decode path; SSM/hybrid/enc-dec "
             "decode is exercised via api.decode_step (see tests).")
-    params = api.init(cfg, jax.random.PRNGKey(args.seed))
+    # serving weights: cfg.dtype, built on the device, and under a tp
+    # mesh built already sharded (no whole copy on the first device)
+    mesh = make_tp_mesh(args.tp) if args.tp > 1 else None
+    params = api.init(
+        cfg, jax.random.PRNGKey(args.seed), dtype=cfg.dtype,
+        shardings=(plans.serving_plan(mesh).param_shardings(cfg)
+                   if mesh is not None else None))
     max_len = args.max_input + args.max_output + 8
     tracer = None
     if args.trace:
@@ -196,7 +217,7 @@ def main() -> None:
                             spec_decode=args.spec_decode,
                             kernel=args.kernel, fp8_kv=args.fp8_kv,
                             fp8_linear=args.fp8_linear,
-                            tp=args.tp, tracer=tracer)
+                            mesh=mesh, tracer=tracer)
     else:
         if args.spec_decode:
             raise SystemExit("--spec-decode needs the chunked engine "

@@ -43,8 +43,13 @@ def param_shapes(cfg: ModelConfig) -> Params:
     raise ValueError(f"unknown family {cfg.family}")
 
 
-def init(cfg: ModelConfig, rng: jax.Array) -> Params:
-    return common.init_params(param_shapes(cfg), rng)
+def init(cfg: ModelConfig, rng: jax.Array, *, dtype=None,
+         shardings=None) -> Params:
+    """Random weights from `rng` (common.init_params): float32 master
+    weights by default (training); serving passes ``dtype=cfg.dtype``
+    and, under a mesh, its ServingPlan.param_shardings."""
+    return common.init_params(param_shapes(cfg), rng, dtype=dtype,
+                              shardings=shardings)
 
 
 def abstract(cfg: ModelConfig) -> Params:
@@ -175,9 +180,9 @@ def cow_copy_block(cfg: ModelConfig, cache: Params, src, dst) -> Params:
 def compile_count(fn) -> int:
     """Number of programs a jitted callable has compiled (-1 unknown).
 
-    Probes the jit program cache (`_cache_size`), which exists on
-    jax.jit wrappers across the supported jax versions; servers expose
-    this so tests/benchmarks can assert O(1) compilation.
+    Probes the jit program cache (`_cache_size` of a jax.jit
+    wrapper); servers expose this so tests/benchmarks can assert O(1)
+    compilation.
     """
     probe = getattr(fn, "_cache_size", None)
     if probe is None:

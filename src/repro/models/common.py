@@ -9,6 +9,7 @@ and the smoke tests (real arrays) can never diverge.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -33,26 +34,48 @@ class ParamSpec:
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
-def _init_leaf(spec: ParamSpec, key: jax.Array) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("spec", "dtype", "sharding"))
+def _init_leaf(key: jax.Array, spec: ParamSpec, dtype, sharding
+               ) -> jax.Array:
+    """One leaf in one program: the float32 draw, scale and cast fuse,
+    so only the `dtype` result is written, laid out by `sharding`."""
     if spec.init == "zeros":
-        return jnp.zeros(spec.shape, spec.dtype)
-    if spec.init == "ones":
-        return jnp.ones(spec.shape, spec.dtype)
-    fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
-    scale = spec.scale if spec.scale is not None else fan_in ** -0.5
-    return (jax.random.normal(key, spec.shape, jnp.float32) * scale
-            ).astype(spec.dtype)
+        out = jnp.zeros(spec.shape, dtype)
+    elif spec.init == "ones":
+        out = jnp.ones(spec.shape, dtype)
+    else:
+        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+        scale = spec.scale if spec.scale is not None else fan_in ** -0.5
+        out = (jax.random.normal(key, spec.shape, jnp.float32) * scale
+               ).astype(dtype)
+    if sharding is not None:
+        out = jax.lax.with_sharding_constraint(out, sharding)
+    return out
 
 
 def is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
-def init_params(specs, rng: jax.Array):
+def init_params(specs, rng: jax.Array, *, dtype=None, shardings=None):
+    """Random parameters, built leaf by leaf on the device.
+
+    ``dtype`` overrides every spec's dtype: training keeps the specs'
+    float32 master weights, serving builds ``cfg.dtype`` (bf16)
+    directly, so a model whose float32 weights would not fit one chip
+    never exists in float32.  ``shardings`` (a NamedSharding per leaf,
+    e.g. sharding/plans.ServingPlan.param_shardings) builds each leaf
+    already sharded instead of whole on one device.  Values do not
+    depend on either: a leaf is the float32 draw of its key, cast."""
     leaves, treedef = jax.tree_util.tree_flatten(specs, is_leaf=is_spec)
     keys = jax.random.split(rng, len(leaves))
-    return jax.tree_util.tree_unflatten(
-        treedef, [_init_leaf(s, k) for s, k in zip(leaves, keys)])
+    shs = (treedef.flatten_up_to(shardings) if shardings is not None
+           else [None] * len(leaves))
+    return jax.tree_util.tree_unflatten(treedef, [
+        _init_leaf(k, spec=s,
+                   dtype=jnp.dtype(s.dtype if dtype is None else dtype),
+                   sharding=sh)
+        for s, k, sh in zip(leaves, keys, shs)])
 
 
 def abstract_params(specs):

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Params = Any
 
@@ -81,8 +80,8 @@ def compressed_psum(x: jax.Array, mesh: Mesh, axis: str,
     if size == 1:
         return x
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P(),
-                       out_specs=P(), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
+                       out_specs=P(), check_vma=False)
     def _cpsum(xs):
         if method == "bf16":
             return lax.psum(xs.astype(jnp.bfloat16), axis).astype(xs.dtype)

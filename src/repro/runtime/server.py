@@ -17,7 +17,7 @@ are distributed (probe: ``compile_counts()``).
 
 KV memory is **paged** by default (vLLM-style): instead of reserving a
 contiguous ``max_len + chunk`` region per slot up front, the cache is a
-shared pool of fixed-size blocks ([num_blocks, block_size, KH, hd] per
+shared pool of fixed-size blocks ([num_blocks, KH, block_size, hd] per
 layer) addressed through a per-slot block table — a fixed-shape
 [slots, max_blocks] int32 jit operand, so the compiled programs are
 unchanged in number.  ``paged=False`` restores the contiguous layout
@@ -78,18 +78,17 @@ runtime/spec_decode.py).
 through the fused Pallas block-table kernels of
 kernels/paged_attention.py instead of materializing each slot's
 gathered view: the block-table walk happens inside the kernel, only
-the ``ceil(kv_len/block_size)`` valid blocks move, and on bf16 pools
-the greedy outputs are **bit-identical** to ``kernel=False`` — the
-gather path stays as the always-on A/B parity oracle.
+the ``ceil(kv_len/block_size)`` valid blocks move, and the
+contractions run on the MXU; results agree with ``kernel=False`` within
+the tolerances of tests/test_paged_kernel.py — the gather path stays
+as the always-on A/B oracle.
 ``fp8_kv=True`` (requires ``paged``) stores the pool as e4m3 codes
 plus per-token-row f32 scales (halving per-device KV bytes + scale
 overhead; see core/roofline.paged_decode_kv_bytes for the modeled
 bytes/step), and ``fp8_linear=True`` (tp=1, non-MoE) pre-quantizes
 the layer weights once at init and serves every matmul through
 te/linear.fp8_serving_dot.  The fp8 options change numerics within
-documented tolerance (tests/test_paged_kernel.py); kernel-vs-gather
-stays bitwise even on fp8 pools because both dequantize with the
-same elementwise op.
+documented tolerance (tests/test_paged_kernel.py).
 
 ``tp=N`` (default 1) serves **tensor-parallel** over an N-device mesh
 (launch/mesh.make_tp_mesh; sharding/plans.ServingPlan documents the
@@ -101,8 +100,12 @@ refcounted allocator and the radix tree stay host-side and replicated,
 so paging, prefix caching and spec decode compose with TP unchanged.
 The only cross-shard float reductions (attention out-projection, MLP
 down-projection) run through order-deterministic fixed-tree grouped
-sums (models.transformer.serving_det_groups), so greedy outputs at any
-supported ``tp`` are token-identical to ``tp=1``.
+sums (models.transformer.serving_det_groups), so on the CPU greedy
+outputs at any supported ``tp`` are token-identical to ``tp=1``.  On a
+TPU v5e the sharded programs do not round bitwise like the whole one:
+at Yi-6B width 5 of 16 greedy requests came out identical at tp=4,
+with prompt logits 0.019 relative RMS apart (chip_smoke.py --tp 4
+holds them to its bf16 tolerance instead).
 
 ``SlotServer`` — the original engine, kept as the measured baseline:
 prefill feeds one token per ``decode_step`` through a scan and
@@ -313,9 +316,9 @@ class ChunkedServer:
                         else MetricsRegistry())
         # -- serving hot-path variants (models/transformer fwd kwargs):
         # kernel=True reads paged KV through the fused Pallas
-        # block-table kernels (kernels/paged_attention; bitwise-equal
-        # to the gather path on bf16 pools, so kernel=False stays the
-        # always-available A/B parity oracle); fp8_kv stores the pool
+        # block-table kernels (kernels/paged_attention; within stated
+        # tolerance of the gather path, so kernel=False stays the
+        # always-available A/B oracle); fp8_kv stores the pool
         # as e4m3 + per-row scales; fp8_linear pre-quantizes the layer
         # weights once and serves matmuls through te/linear.
         self.kernel = bool(kernel)
@@ -354,7 +357,7 @@ class ChunkedServer:
                  f"output parity")
         if self._plan is not None:
             self._param_sh = self._plan.param_shardings(cfg)
-            self._cache_sh = self._plan.cache_sharding(cfg)
+            self._cache_sh = self._plan.cache_sharding(cfg, paged=paged)
             self._repl = self._plan.replicated
             params = jax.device_put(params, self._param_sh)
         self.params = params
@@ -638,6 +641,46 @@ class ChunkedServer:
         if self.spec_decode:
             counts["verify_step"] = api.compile_count(self._verify_fn)
         return counts
+
+    def prompt_logits(self, prompts: List[np.ndarray]) -> np.ndarray:
+        """Last-position logits [n, vocab] (f32, on the host) of each
+        prompt through this server's serving forward — its chunk size,
+        block size, KV read path (gather/kernel), pool dtype and mesh —
+        on a scratch paged pool of its own, so the served pool and the
+        radix tree are untouched.  These are the logits a request's
+        first token is drawn from; checks compare them with a
+        reference (api.prefill).  The serving loop never calls this."""
+        assert self.paged, "prompt_logits reads through the paged pool"
+        n, C, mb = len(prompts), self.chunk, self.max_blocks
+        cache = api.init_cache(
+            self.cfg, n, self.max_len, paged=True,
+            block_size=self.block_size, num_blocks=n * mb,
+            sharding=self._cache_sh if self._plan is not None else None,
+            fp8_kv=self.fp8_kv)
+        table = self._put(np.arange(n * mb, dtype=np.int32).reshape(n, mb))
+
+        def step(params, cache, tokens, pos, n_tokens):
+            with self._trace_ctx():
+                return transformer.chunk_step(
+                    self.cfg, params, cache, tokens, pos, n_tokens, table,
+                    **self._fwd_kw())
+
+        fn = jax.jit(step, donate_argnums=(1,))
+        lens = np.array([len(p) for p in prompts], np.int32)
+        out = np.zeros((n, self.cfg.vocab_size), np.float32)
+        for off in range(0, int(lens.max()), C):
+            tokens = np.zeros((n, C), np.int32)
+            n_tok = np.clip(lens - off, 0, C).astype(np.int32)
+            for i, p in enumerate(prompts):
+                tokens[i, :n_tok[i]] = p[off:off + n_tok[i]]
+            logits, cache = fn(self.params, cache, self._put(tokens),
+                               self._put(np.full(n, off, np.int32)),
+                               self._put(n_tok))
+            last = (lens > off) & (lens <= off + C)
+            if last.any():
+                out[last] = np.asarray(jax.device_get(logits),
+                                       np.float32)[last]
+        return out
 
     # -- host-side refcounted block allocator (paged) ---------------------
     def _blocks_needed(self, req: Request) -> int:

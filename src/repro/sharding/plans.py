@@ -151,13 +151,13 @@ class ServingPlan:
         embedding/unembedding over ``vocab``.  Logical dims that do not
         divide the mesh fall back to replicated
         (models/common.partition_specs).
-      * The KV cache — paged pool ``[L, num_blocks, block_size, KH,
-        hd]`` or contiguous ``[L, B, T, KH, hd]`` — shards its KV-head
-        dim (index 3 in both layouts) over ``tp``; each device holds
-        every pool block but only ``KH / tp`` heads of it, so the
-        per-device KV bytes shrink by the TP degree while the host
-        allocator keeps addressing whole logical blocks.  Requires
-        ``KH % tp == 0`` (the server asserts).
+      * The KV cache — paged pool ``[L, num_blocks, KH, block_size,
+        hd]`` (fp8 scales ``[L, num_blocks, KH, block_size]``) or
+        contiguous ``[L, B, T, KH, hd]`` — shards its KV-head dim over
+        ``tp``; each device holds every pool block but only ``KH / tp``
+        heads of it, so the per-device KV bytes shrink by the TP degree
+        while the host allocator keeps addressing whole logical blocks.
+        Requires ``KH % tp == 0`` (the server asserts).
       * Every other jit operand (tokens, positions, block tables,
         output buffer, n-gram table) is replicated: ``replicated``.
       * Activations inside the jitted steps follow ``act_rules``
@@ -167,8 +167,10 @@ class ServingPlan:
     Cross-shard float reductions (attention out-projection, MLP
     down-projection) are made order-deterministic by the grouped
     fixed-tree sums in models/{attention,mlp}.py
-    (models.transformer.serving_det_groups), so greedy outputs at any
-    ``tp`` dividing the group counts are token-identical to ``tp=1``.
+    (models.transformer.serving_det_groups), so on the CPU greedy
+    outputs at any ``tp`` dividing the group counts are token-identical
+    to ``tp=1`` (on a TPU they agree within a tolerance; see
+    runtime/server.py).
     """
 
     mesh: Mesh
@@ -203,14 +205,15 @@ class ServingPlan:
             lambda s: NamedSharding(self.mesh, s), pspecs,
             is_leaf=lambda x: isinstance(x, P))
 
-    def cache_sharding(self, cfg) -> NamedSharding:
-        """KV cache sharding — one spec fits both layouts because the
-        KV-head dim sits at index 3 of the rank-5 ``k``/``v`` leaves
-        ([L, num_blocks, block_size, KH, hd] paged, [L, B, T, KH, hd]
-        contiguous).  Falls back to replicated when KH doesn't divide."""
+    def cache_sharding(self, cfg, *, paged: bool) -> NamedSharding:
+        """KV cache sharding over the KV-head dim: index 2 of every
+        paged leaf ([L, num_blocks, KH, block_size, ...]), index 3 of
+        the contiguous [L, B, T, KH, hd].  Falls back to replicated when
+        KH doesn't divide."""
         ax = self.axis if (cfg.num_kv_heads
                            and cfg.num_kv_heads % self.tp == 0) else None
-        return NamedSharding(self.mesh, P(None, None, None, ax, None))
+        return NamedSharding(self.mesh, P(None, None, ax) if paged
+                             else P(None, None, None, ax, None))
 
 
 def serving_plan(mesh: Mesh, axis: str = SERVING_TP_AXIS) -> ServingPlan:

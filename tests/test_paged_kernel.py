@@ -1,28 +1,26 @@
-"""Fused paged flash-decode/chunk kernels vs the gather path.
+"""Fused paged decode/chunk kernels vs the gather path.
 
 Three tiers:
 
-1. Unit bit-parity: jitted kernel vs jitted gather+oracle, decode and
-   chunk, f32/bf16, small/large block sizes, G=1 (matvec) and G>1,
-   ragged mid-block frontiers, -1 table tails, COW-fresh blocks.
-   "Bitwise" means bitwise — both sides are compared as raw bytes.
-   (Parity is a property of the JITTED graphs: eager per-op dispatch
-   may round reductions differently at ~1 ulp, which is exactly the
-   strength-reduction hazard the mul+reduce formulation in
-   models/attention.py and kernels/paged_attention.py exists to pin
-   down.  Serving always runs jitted.)
+1. Unit accuracy: the jitted kernel and the jitted gather path, decode
+   and chunk, f32/bf16, small/large block sizes, G=1 (matvec) and G>1,
+   ragged mid-block frontiers, -1 table tails, COW-fresh blocks — each
+   compared with a float64 numpy reference of the same attention.  The
+   kernel contracts on the MXU with f32 accumulation and the gather
+   path multiplies and sums in XLA, so the two round differently; both
+   must sit inside the tolerance of their dtype (below).
 
-2. fp8 tier: fp8-kernel vs fp8-gather is still bitwise (the in-tile
-   dequant is elementwise identical to gather_paged_cache_fp8);
-   fp8-vs-bf16 is a tolerance tier with the e4m3 bound documented
-   below.
+2. fp8 tier: the fp8 kernel (scales applied after the contractions)
+   and the fp8 gather (dequantize to bf16, then attend) against the
+   reference computed from the same dequantized cache; fp8-vs-bf16 is
+   a quantization tier with the e4m3 bound documented below.
 
-3. E2E: ChunkedServer(kernel=True) greedy outputs are token-identical
-   to kernel=False on the ShareGPT / sysprompt / repetitive mixes with
-   paged + prefix cache + spec decode all on (COW-fresh blocks and
-   spec rollback-then-redecode included), with O(1) compile counts;
-   fp8_kv shrinks the per-device pool by exactly (hd+4)/(2*hd);
-   tp=2 kernel parity runs on a forced 8-device mesh in a subprocess.
+3. E2E: ChunkedServer(kernel=True) greedy outputs match kernel=False
+   on the ShareGPT / sysprompt / repetitive mixes with paged + prefix
+   cache + spec decode all on (COW-fresh blocks and spec
+   rollback-then-redecode included), with O(1) compile counts; fp8_kv
+   shrinks the per-device pool by exactly (hd+4)/(2*hd); tp=2 kernel
+   runs on a forced 8-device mesh in a subprocess.
 """
 
 import json
@@ -50,9 +48,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # rows so they inherit the same bound.
 FP8_ATOL = 0.15
 
+# Absolute error against the float64 reference, for unit-normal q/k/v
+# (outputs are convex combinations of V rows, |o| < 4).
+#   float32: f32 accumulation over hd <= 64 products and T <= 48 softmax
+#     terms stays within ~1e-6; 1e-5 leaves margin, and a path that
+#     computed in bf16 anywhere would miss it by three orders.
+#   bfloat16: both paths round the softmax weights to bf16 before PV
+#     (2^-9 relative each) and the output to bf16 (half an ulp: 2^-9
+#     relative, 7.8e-3 at |o| < 4); 2e-2 covers both with margin, and
+#     fp8 or lower-precision KV would miss it (see FP8_ATOL).
+REF_ATOL = {"float32": 1e-5, "bfloat16": 2e-2}
+
 
 # ----------------------------------------------------------------------
-# jitted comparison endpoints (parity holds between JITTED graphs)
+# jitted endpoints and the float64 reference
 # ----------------------------------------------------------------------
 
 @jax.jit
@@ -83,12 +92,39 @@ def _bitwise(a, b):
     return a.shape == b.shape and a.tobytes() == b.tobytes()
 
 
+def _ref(q, ck, cv, bt, pos):
+    """float64 attention of q [B,C,H,hd] at positions pos[b] + i over
+    each slot's blocks of the head-major pools [NB,KH,bs,hd]."""
+    q = np.asarray(q, np.float64)
+    ck, cv = np.asarray(ck, np.float64), np.asarray(cv, np.float64)
+    bt = np.maximum(np.asarray(bt), 0)
+    B, C, H, hd = q.shape
+    NB, KH, bs, _ = ck.shape
+    G, T = H // KH, bt.shape[1] * bs
+    out = np.zeros(q.shape)
+    for b in range(B):
+        k = ck[bt[b]].transpose(1, 0, 2, 3).reshape(KH, T, hd)
+        v = cv[bt[b]].transpose(1, 0, 2, 3).reshape(KH, T, hd)
+        for i in range(C):
+            seen = np.arange(T) <= int(pos[b]) + i
+            for h in range(H):
+                s = k[h // G] @ q[b, i, h] * hd ** -0.5
+                w = np.exp(s - s[seen].max()) * seen
+                out[b, i, h] = w @ v[h // G] / w.sum()
+    return out
+
+
+def _close(got, ref, dtype):
+    err = np.abs(np.asarray(got, np.float64) - ref).max()
+    assert err < REF_ATOL[dtype], (dtype, err)
+
+
 def _mk(dtype, B, H, KH, hd, NB, bs, MB, seed, kv_lens=None):
     """Pool + per-slot table with -1 tails + ragged kv_len."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), dtype)
-    ck = jnp.asarray(rng.standard_normal((NB, bs, KH, hd)), dtype)
-    cv = jnp.asarray(rng.standard_normal((NB, bs, KH, hd)), dtype)
+    ck = jnp.asarray(rng.standard_normal((NB, KH, bs, hd)), dtype)
+    cv = jnp.asarray(rng.standard_normal((NB, KH, bs, hd)), dtype)
     T = MB * bs
     if kv_lens is None:
         kv_lens = [1, bs + bs // 2 + 1, T, T // 2 + 1, bs, 2 * bs - 1]
@@ -112,8 +148,9 @@ CASES = [
 @pytest.mark.parametrize("dtype,B,H,KH,hd,NB,bs,MB,seed", CASES)
 def test_decode_bit_parity(dtype, B, H, KH, hd, NB, bs, MB, seed):
     q, ck, cv, bt, kv_len = _mk(dtype, B, H, KH, hd, NB, bs, MB, seed)
-    assert _bitwise(_kernel_decode(q, ck, cv, bt, kv_len),
-                    _oracle_decode(q, ck, cv, bt, kv_len))
+    ref = _ref(q, ck, cv, bt, np.asarray(kv_len) - 1)
+    _close(_kernel_decode(q, ck, cv, bt, kv_len), ref, dtype)
+    _close(_oracle_decode(q, ck, cv, bt, kv_len), ref, dtype)
 
 
 @pytest.mark.parametrize("dtype,B,H,KH,hd,NB,bs,MB,seed", CASES)
@@ -123,8 +160,14 @@ def test_chunk_bit_parity(dtype, B, H, KH, hd, NB, bs, MB, seed):
     rng = np.random.default_rng(seed + 100)
     q = jnp.asarray(rng.standard_normal((B, C, H, hd)), dtype)
     pos = jnp.maximum(kv_len - C, 0)
-    assert _bitwise(_kernel_chunk(q, ck, cv, bt, pos),
-                    _oracle_chunk(q, ck, cv, bt, pos))
+    ref = _ref(q, ck, cv, bt, np.asarray(pos))
+    # rows past a slot's table reach unallocated blocks; the caller
+    # discards them (paged_chunk's contract), so compare valid rows
+    valid = (np.asarray(pos)[:, None] + np.arange(C)[None, :]
+             < np.asarray(kv_len)[:, None])
+    for got in (_kernel_chunk(q, ck, cv, bt, pos),
+                _oracle_chunk(q, ck, cv, bt, pos)):
+        _close(np.asarray(got, np.float64)[valid], ref[valid], dtype)
 
 
 @pytest.mark.parametrize("kv", [1, 7, 8, 9, 47, 48])
@@ -133,15 +176,17 @@ def test_decode_ragged_frontiers(kv):
     exactly-full table."""
     q, ck, cv, bt, kv_len = _mk("float32", 2, 4, 2, 32, 24, 8, 6, 11,
                                 kv_lens=[kv, 48])
-    assert _bitwise(_kernel_decode(q, ck, cv, bt, kv_len),
-                    _oracle_decode(q, ck, cv, bt, kv_len))
+    ref = _ref(q, ck, cv, bt, np.asarray(kv_len) - 1)
+    _close(_kernel_decode(q, ck, cv, bt, kv_len), ref, "float32")
+    _close(_oracle_decode(q, ck, cv, bt, kv_len), ref, "float32")
 
 
 def test_cow_fresh_block_parity():
     """COW in the serving allocator copies a shared block to a fresh
     physical index and repoints one slot's table entry.  Simulate the
     post-COW state: identical contents at a different index must give
-    identical outputs, and kernel-vs-gather parity must hold."""
+    bitwise identical kernel outputs, still within tolerance of the
+    reference."""
     q, ck, cv, bt, kv_len = _mk("bfloat16", 2, 4, 2, 32, 24, 8, 6, 13,
                                 kv_lens=[20, 20])
     before = _kernel_decode(q, ck, cv, bt, kv_len)
@@ -154,7 +199,8 @@ def test_cow_fresh_block_parity():
     bt = bt.at[1, 1].set(fresh)
     after = _kernel_decode(q, ck, cv, bt, kv_len)
     assert _bitwise(before, after)
-    assert _bitwise(after, _oracle_decode(q, ck, cv, bt, kv_len))
+    _close(after, _ref(q, ck, cv, bt, np.asarray(kv_len) - 1),
+           "bfloat16")
 
 
 # ----------------------------------------------------------------------
@@ -166,7 +212,8 @@ def test_poisoned_block_finite_garbage_never_leaks():
     garbage while no slot's valid prefix references it.  The gather
     path clamps -1 -> 0 and masks (0.0 softmax weight x finite = 0.0);
     the kernel path never touches it (the walk stops at the frontier).
-    Both outputs must be bitwise identical to a clean-pool oracle."""
+    Both outputs must be bitwise identical to their clean-pool
+    results."""
     q, ck, cv, bt, kv_len = _mk("float32", 3, 4, 2, 32, 24, 8, 6, 17,
                                 kv_lens=[5, 20, 33])
     bt = np.array(bt)
@@ -174,11 +221,12 @@ def test_poisoned_block_finite_garbage_never_leaks():
     free = [i for i in range(ck.shape[0]) if i not in set(bt.ravel())]
     bt[bt == 0] = free.pop()
     bt = jnp.asarray(bt)
-    clean = _oracle_decode(q, ck, cv, bt, kv_len)
     ckp = ck.at[0].set(1e30)
     cvp = cv.at[0].set(-1e30)
-    assert _bitwise(_oracle_decode(q, ckp, cvp, bt, kv_len), clean)
-    assert _bitwise(_kernel_decode(q, ckp, cvp, bt, kv_len), clean)
+    assert _bitwise(_oracle_decode(q, ckp, cvp, bt, kv_len),
+                    _oracle_decode(q, ck, cv, bt, kv_len))
+    assert _bitwise(_kernel_decode(q, ckp, cvp, bt, kv_len),
+                    _kernel_decode(q, ck, cv, bt, kv_len))
 
 
 def test_poisoned_block_nan_kernel_never_reads_it():
@@ -222,17 +270,25 @@ def _mk_fp8(B, H, KH, hd, NB, bs, MB, seed):
                                 seed)
     ck, ks = te_fp8.quantize_rowwise(kf, te_fp8.E4M3)
     cv, vs = te_fp8.quantize_rowwise(vf, te_fp8.E4M3)
-    cl = {"k": ck, "v": cv, "k_scale": ks, "v_scale": vs}
+    cl = {"k": ck, "v": cv, "k_scale": ks[..., 0], "v_scale": vs[..., 0]}
     return q, kf, vf, cl, bt, kv_len
 
 
+def _dequant(cl, name):
+    """float64 dequantized pool: codes * per-row scale."""
+    return (np.asarray(cl[name], np.float64)
+            * np.asarray(cl[name + "_scale"], np.float64)[..., None])
+
+
 def test_fp8_kernel_vs_fp8_gather_bitwise():
-    """The in-tile dequant is elementwise identical to
-    gather_paged_cache_fp8, so the fp8 kernel is still bit-exact
-    against the fp8 gather path."""
+    """The fp8 kernel (scales after the contractions) and the fp8
+    gather (dequantize to bf16, then attend) both match the reference
+    over the same dequantized cache within the bf16 tolerance."""
     q, _, _, cl, bt, kv_len = _mk_fp8(3, 4, 2, 32, 24, 8, 6, 23)
-    assert _bitwise(_kernel_decode_fp8(q, cl, bt, kv_len),
-                    _oracle_decode_fp8(q, cl, bt, kv_len))
+    ref = _ref(q, _dequant(cl, "k"), _dequant(cl, "v"), bt,
+               np.asarray(kv_len) - 1)
+    _close(_kernel_decode_fp8(q, cl, bt, kv_len), ref, "bfloat16")
+    _close(_oracle_decode_fp8(q, cl, bt, kv_len), ref, "bfloat16")
 
 
 def test_fp8_chunk_kernel_vs_gather_bitwise():
@@ -255,7 +311,13 @@ def test_fp8_chunk_kernel_vs_gather_bitwise():
         positions = pos[:, None] + jnp.arange(C)[None, :]
         return attention.chunk_attention(qc, kg, vg, positions)
 
-    assert _bitwise(kern(qc, cl, bt, pos), oracle(qc, cl, bt, pos))
+    ref = _ref(qc, _dequant(cl, "k"), _dequant(cl, "v"), bt,
+               np.asarray(pos))
+    valid = (np.asarray(pos)[:, None] + np.arange(C)[None, :]
+             < np.asarray(kv_len)[:, None])
+    for got in (kern(qc, cl, bt, pos), oracle(qc, cl, bt, pos)):
+        _close(np.asarray(got, np.float64)[valid], ref[valid],
+               "bfloat16")
 
 
 def test_fp8_vs_bf16_tolerance():
@@ -277,7 +339,7 @@ def test_fp8_scatter_gather_roundtrip():
                                        fp8=True)
     cl = jax.tree_util.tree_map(lambda x: x[0], cl)
     assert cl["k"].dtype == te_fp8.E4M3
-    assert cl["k_scale"].shape == (NB, bs, KH, 1)
+    assert cl["k_scale"].shape == (NB, KH, bs)
     k1 = jnp.asarray(rng.standard_normal((B, C, KH, hd)), jnp.bfloat16)
     v1 = jnp.asarray(rng.standard_normal((B, C, KH, hd)), jnp.bfloat16)
     pos = jnp.asarray([0, 5, 9], jnp.int32)
@@ -345,10 +407,12 @@ def _serve(cfg, params, reqs, **extra):
 
 @pytest.mark.parametrize("mix", ["sharegpt", "sysprompt", "repetitive"])
 def test_e2e_kernel_flag_token_identical(setup, mix):
-    """kernel=True is bit-identical to the gather path end-to-end with
-    paged + prefix cache + spec decode all on — greedy argmax amplifies
-    any 1-ulp logit divergence into a token flip, so token-identity
-    over whole mixes is the sharpest E2E parity probe there is."""
+    """kernel=True emits the same greedy tokens as the gather path
+    end-to-end with paged + prefix cache + spec decode all on.  The two
+    read paths round differently (the unit tier above), so this pins
+    that no logit gap on these mixes is small enough to flip an argmax
+    — and that COW, rollback and radix sharing feed the kernel the
+    same KV the gather path reads."""
     cfg, params, mixes = setup
     base, _, _ = _serve(cfg, params, mixes[mix])
     kern, srv, _ = _serve(cfg, params, mixes[mix], kernel=True)
@@ -376,14 +440,29 @@ def test_e2e_fp8_kv_pool_shrink(setup):
 
 
 def test_e2e_fp8_kv_gather_path_matches_kernel(setup):
-    """With the SAME fp8 pool, kernel=True and kernel=False greedy
-    outputs are identical (the dequant is elementwise identical), so
-    the A/B oracle property survives quantization."""
+    """On fp8 pools both read paths serve the whole mix, and their
+    prompt logits (ChunkedServer.prompt_logits) sit within the fp8 tier
+    of the bf16 gather path's and of each other's.
+
+    Tier: max |dlogit| < 0.6 x RMS(logits).  Each layer requantizes its
+    k/v to e4m3 (3-bit mantissa, 6% steps), so an upstream rounding
+    difference can flip codes and grow layer by layer: on this mix both
+    fp8 paths measure 0.35-0.40 x RMS from bf16 and 0.22 x RMS from each
+    other.  A read path that dropped or misapplied the scales misses by
+    more than 1 x RMS."""
     cfg, params, mixes = setup
-    a, _, _ = _serve(cfg, params, mixes["sharegpt"], fp8_kv=True)
-    b, _, _ = _serve(cfg, params, mixes["sharegpt"], fp8_kv=True,
-                     kernel=True)
-    assert a == b
+    a, srv_a, _ = _serve(cfg, params, mixes["sharegpt"], fp8_kv=True)
+    b, srv_b, _ = _serve(cfg, params, mixes["sharegpt"], fp8_kv=True,
+                         kernel=True)
+    assert [len(o) for o in a] == [len(o) for o in b]
+    prompts = [r.prompt for r in mixes["sharegpt"]]
+    ref = ChunkedServer(cfg, params, **KW).prompt_logits(prompts)
+    tol = 0.6 * np.sqrt(np.mean(ref ** 2))
+    la = srv_a.prompt_logits(prompts)
+    lb = srv_b.prompt_logits(prompts)
+    for got in (la, lb):
+        assert np.abs(got - ref).max() < tol, np.abs(got - ref).max()
+    assert np.abs(la - lb).max() < tol, np.abs(la - lb).max()
 
 
 def test_e2e_fp8_linear_serves(setup):

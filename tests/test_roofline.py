@@ -138,8 +138,9 @@ def test_paged_decode_kv_bytes_ratios():
     # at full context the gather's 3 passes over the full extent vs
     # the kernel's single pass over the (all-valid) blocks = exactly 3x
     assert r["kernel_speedup"] == 3.0
-    # fp8 kernel bytes per token-row per head: hd + 4 vs 2*hd
-    assert r["fp8_vs_kernel_bytes"] == (64 + 4) / (2 * 64) == 0.53125
+    # fp8 kernel bytes per token-row per head at full context: hd codes
+    # + 3 passes of 4-byte scales over the table extent, vs 2*hd
+    assert r["fp8_vs_kernel_bytes"] == (64 + 3 * 4) / (2 * 64) == 0.59375
     # at quarter context the kernel touches 1/4 of the blocks: 12x
     r4 = roofline.paged_decode_speedup(full // 4, **kw)
     assert r4["kernel_speedup"] == 12.0
