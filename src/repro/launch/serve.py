@@ -48,9 +48,9 @@ from repro.configs import get_config, list_archs, reduced_config
 from repro.launch import compile_cache
 from repro.launch.mesh import make_tp_mesh
 from repro.models import api
-from repro.obs import (SLOSpec, Tracer, phase_summary, slo_report,
-                       summary_table, window_series, window_summary,
-                       write_chrome_trace, write_jsonl)
+from repro.obs import (SLOSpec, Tracer, gap_summary, phase_summary,
+                       slo_report, summary_table, window_series,
+                       window_summary, write_chrome_trace, write_jsonl)
 from repro.runtime.arrivals import poisson_stream
 from repro.runtime.server import (ChunkedServer, SlotServer,
                                   repetitive_requests,
@@ -296,10 +296,12 @@ def main() -> None:
         # metrics registry the dispatch methods feed (repro.obs) —
         # live with or without --trace
         phases = phase_summary(srv.metrics)
+        gap = gap_summary(srv.metrics)
         breakdown = " ".join(
             f"{name}={d['wall_s']:.2f}s/{d['dispatches']}d"
+            f"(host={d['host_s']:.2f}s,wait={d['wait_s']:.2f}s)"
             for name, d in phases.items() if d["dispatches"])
-        print(f"  phases: {breakdown} "
+        print(f"  phases: {breakdown} gap={gap['gap_s']:.2f}s "
               f"compiled_programs="
               f"{sum(max(v, 0) for v in counts.values())} "
               f"({per_program})")

@@ -1,8 +1,8 @@
 """Low-overhead serving observability: per-request lifecycle tracing,
-a counters/gauges/histograms registry with a no-op fast path, derived
-latency/occupancy/roofline views, time-windowed series, SLO/goodput
-accounting, and JSONL + Chrome-trace exports (with windowed counter
-tracks).
+scheduler spans in the profiler's trace, a counters/gauges/histograms
+registry with a no-op fast path, derived latency/phase/occupancy
+views, time-windowed series, SLO/goodput accounting, and JSONL +
+Chrome-trace exports (with windowed counter tracks).
 
 Host-side only by construction — timestamps wrap jitted dispatches
 (after ``block_until_ready()``), never enter them; the analyzer's
@@ -15,9 +15,9 @@ from repro.obs.metrics import (Counter, Gauge, Histogram,
                                NULL_METRICS)
 from repro.obs.tracer import (RequestRecord, Tracer, NullTracer,
                               NULL_TRACER)
-from repro.obs.views import (occupancy_summary, percentiles,
-                             phase_summary, request_latency_summary,
-                             roofline_efficiency, summary_table)
+from repro.obs.views import (gap_summary, occupancy_summary,
+                             percentiles, phase_summary,
+                             request_latency_summary, summary_table)
 from repro.obs.windows import window_series, window_summary
 from repro.obs.slo import (SLOSpec, attainment, goodput,
                            max_sustainable_rate, request_met,
@@ -29,7 +29,8 @@ __all__ = [
     "NullMetricsRegistry", "NULL_METRICS",
     "RequestRecord", "Tracer", "NullTracer", "NULL_TRACER",
     "percentiles", "request_latency_summary", "phase_summary",
-    "occupancy_summary", "roofline_efficiency", "summary_table",
+    "gap_summary",
+    "occupancy_summary", "summary_table",
     "window_series", "window_summary",
     "SLOSpec", "request_met", "attainment", "goodput", "slo_report",
     "max_sustainable_rate",

@@ -24,12 +24,22 @@ Event kinds recorded by runtime/server.py (+ prefix_cache / spec):
   enqueue admit prefix_match chunk_dispatch span_dispatch
   verify_dispatch spec_rollback cow_resolve eviction first_token
   finish harvest stall
+
+The dispatch events carry ``step``, the scheduler loop counter, which
+is also the ``step_num`` of the ``serve.step`` span: ``phase`` opens
+``jax.profiler`` annotations, so the scheduler's phases land in a
+profiler trace on the device's clock (names in runtime/server.py).
+With the profiler off an annotation costs a microsecond or less of
+host time; ``NullTracer.phase`` opens nothing.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
 
 from repro.obs.metrics import MetricsRegistry, NULL_METRICS
 
@@ -39,13 +49,23 @@ __all__ = ["RequestRecord", "Tracer", "NullTracer", "NULL_TRACER"]
 class RequestRecord:
     """Lifecycle timestamps + token accounting for one request."""
 
-    __slots__ = ("rid", "t_enqueue", "t_admit", "t_first_token",
+    __slots__ = ("rid", "t_enqueue", "t_seen", "seen_after", "t_admit",
+                 "admit_step", "t_first_token", "first_token_step",
                  "t_done", "n_prompt", "n_out", "max_output",
                  "cached_tokens", "truncated", "slot")
 
     def __init__(self, rid: int):
         self.rid = rid
         self.t_enqueue: Optional[float] = None
+        # when the scheduler released the request into its admission
+        # queue, and the kind of the dispatch it ran last before that
+        # ("chunk" | "span" | "verify"; None before the first one)
+        self.t_seen: Optional[float] = None
+        self.seen_after: Optional[str] = None
+        # scheduler loop counters of the admission and the first token
+        # (the step_num of the serve.step span in a profiler trace)
+        self.admit_step: Optional[int] = None
+        self.first_token_step: Optional[int] = None
         self.t_admit: Optional[float] = None
         self.t_first_token: Optional[float] = None
         self.t_done: Optional[float] = None
@@ -85,8 +105,10 @@ class RequestRecord:
 
     def to_dict(self) -> Dict[str, Any]:
         return {"rid": self.rid, "t_enqueue": self.t_enqueue,
-                "t_admit": self.t_admit,
+                "t_seen": self.t_seen, "seen_after": self.seen_after,
+                "t_admit": self.t_admit, "admit_step": self.admit_step,
                 "t_first_token": self.t_first_token,
+                "first_token_step": self.first_token_step,
                 "t_done": self.t_done, "n_prompt": self.n_prompt,
                 "n_out": self.n_out, "max_output": self.max_output,
                 "cached_tokens": self.cached_tokens,
@@ -111,13 +133,20 @@ class Tracer:
         self.clock = clock
         self.events: List[Tuple[float, str, dict]] = []
         self.requests: Dict[int, RequestRecord] = {}
-        # server constants stamped once at construction (block_size,
-        # kv_heads, head_dim, num_layers, span, chunk, B, ...): the
-        # derived roofline view needs them next to the events
+        # server constants stamped once at construction (batch_slots,
+        # chunk, span, ...): the windowed views need them next to the
+        # events
         self.meta: Dict[str, Any] = {}
 
     def now(self) -> float:
         return self.clock()
+
+    def phase(self, name: str, step_num: Optional[int] = None):
+        """A host span in the profiler's trace around one scheduler
+        phase; with ``step_num``, a step span (``serve.step``)."""
+        if step_num is None:
+            return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
 
     def clear(self) -> None:
         """Drop events + records (keeps meta); resets metrics."""
@@ -142,26 +171,34 @@ class Tracer:
         return r
 
     def enqueue(self, rid: int, n_prompt: int, max_output: int,
-                t: Optional[float] = None) -> None:
+                t: Optional[float] = None, *,
+                t_seen: Optional[float] = None,
+                seen_after: Optional[str] = None) -> None:
         """``t`` lets the open-loop serving path stamp the request's
         *scheduled arrival* instead of the observation time: a request
         that arrived mid-dispatch is only seen by the scheduler after
         ``block_until_ready()``, but its queue delay (and TTFT) must
-        be charged from arrival (runtime/arrivals.py)."""
+        be charged from arrival (runtime/arrivals.py).  ``t_seen`` is
+        when the scheduler saw it (default: now), ``seen_after`` the
+        kind of the dispatch it ran last before then."""
+        now = self.clock() if t is None or t_seen is None else None
         if t is None:
-            t = self.clock()
+            t = now
         r = self._rec(rid)
         r.t_enqueue = t
+        r.t_seen = now if t_seen is None else t_seen
+        r.seen_after = seen_after
         r.n_prompt = n_prompt
         r.max_output = max_output
         self.events.append((t, "enqueue", {"rid": rid,
                                            "n_prompt": n_prompt}))
 
     def admit(self, rid: int, slot: int, cached_tokens: int,
-              truncated: bool) -> None:
+              truncated: bool, step: Optional[int] = None) -> None:
         t = self.clock()
         r = self._rec(rid)
         r.t_admit = t
+        r.admit_step = step
         r.slot = slot
         r.cached_tokens = cached_tokens
         r.truncated = truncated
@@ -169,11 +206,12 @@ class Tracer:
                             {"rid": rid, "slot": slot,
                              "cached_tokens": cached_tokens}))
 
-    def first_token(self, rid: int) -> None:
+    def first_token(self, rid: int, step: Optional[int] = None) -> None:
         t = self.clock()
         r = self._rec(rid)
         if r.t_first_token is None:
             r.t_first_token = t
+            r.first_token_step = step
             self.events.append((t, "first_token", {"rid": rid}))
 
     def finish(self, rid: int, n_out: int) -> None:
@@ -204,9 +242,13 @@ class NullTracer:
     events: List[Tuple[float, str, dict]] = []
     requests: Dict[int, RequestRecord] = {}
     meta: Dict[str, Any] = {}
+    _NO_SPAN = contextlib.nullcontext()
 
     def now(self) -> float:
         return 0.0
+
+    def phase(self, name: str, step_num: Optional[int] = None):
+        return self._NO_SPAN
 
     def clear(self) -> None:
         pass
@@ -218,14 +260,16 @@ class NullTracer:
         pass
 
     def enqueue(self, rid: int, n_prompt: int, max_output: int,
-                t: Optional[float] = None) -> None:
+                t: Optional[float] = None, *,
+                t_seen: Optional[float] = None,
+                seen_after: Optional[str] = None) -> None:
         pass
 
     def admit(self, rid: int, slot: int, cached_tokens: int,
-              truncated: bool) -> None:
+              truncated: bool, step: Optional[int] = None) -> None:
         pass
 
-    def first_token(self, rid: int) -> None:
+    def first_token(self, rid: int, step: Optional[int] = None) -> None:
         pass
 
     def finish(self, rid: int, n_out: int) -> None:

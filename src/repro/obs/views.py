@@ -1,6 +1,6 @@
 """Derived views over a serving trace: latency percentiles, phase
-breakdowns, occupancy/utilization rollups, and a roofline-anchored
-efficiency estimate.
+breakdowns (host, device-wait and gap time beside each phase's wall
+time) and occupancy/utilization rollups.
 
 These are pure post-hoc reductions over the tracer's host-side event
 log and metrics registry — nothing here runs during serving, so the
@@ -13,12 +13,11 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional, Sequence
 
-from repro.core import roofline
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracer import Tracer
 
 __all__ = ["percentiles", "request_latency_summary", "phase_summary",
-           "occupancy_summary", "roofline_efficiency", "summary_table"]
+           "gap_summary", "occupancy_summary", "summary_table"]
 
 _QS = (50, 95, 99)
 
@@ -64,14 +63,19 @@ def request_latency_summary(tracer: Tracer) -> Dict[str, Dict[str, float]]:
 def phase_summary(metrics: MetricsRegistry) -> Dict[str, Dict[str, float]]:
     """Per-phase dispatch counts + wall-time totals (prefill chunk
     steps vs decode spans vs spec verify steps), straight from the
-    registry the scheduler feeds."""
-    out = {}
+    registry the scheduler feeds.  Each phase's wall time splits into
+    ``host_s`` (method entry to the jitted call's return) and
+    ``wait_s`` (that return to ``block_until_ready``); the time between
+    dispatches is ``gap_summary``'s."""
+    out: Dict[str, Dict[str, float]] = {}
     for phase in ("prefill", "span", "verify"):
         h = metrics.hist(f"serving.wall_s.{phase}")
         n = metrics.counter_value(f"serving.dispatches.{phase}")
         out[phase] = {
             "dispatches": n,
             "wall_s": h.total if h is not None else 0.0,
+            "host_s": metrics.hist_total(f"serving.host_s.{phase}"),
+            "wait_s": metrics.hist_total(f"serving.wait_s.{phase}"),
             "mean_dispatch_s": (h.total / h.count
                                 if h is not None and h.count else 0.0),
         }
@@ -79,6 +83,16 @@ def phase_summary(metrics: MetricsRegistry) -> Dict[str, Dict[str, float]]:
     for p in out.values():
         p["wall_frac"] = p["wall_s"] / total if total > 0 else 0.0
     return out
+
+
+def gap_summary(metrics: MetricsRegistry) -> Dict[str, float]:
+    """Host seconds from one dispatch's ready stamp to the next
+    dispatch's call (``serving.gap_s``): the device's idle time between
+    steps while no dispatch overlaps another."""
+    g = metrics.hist("serving.gap_s")
+    n = g.count if g is not None else 0
+    return {"count": n, "gap_s": g.total if n else 0.0,
+            "mean_gap_s": g.mean if n else 0.0}
 
 
 def occupancy_summary(metrics: MetricsRegistry) -> Dict[str, float]:
@@ -95,63 +109,13 @@ def occupancy_summary(metrics: MetricsRegistry) -> Dict[str, float]:
     return out
 
 
-def roofline_efficiency(tracer: Tracer) -> Dict[str, float]:
-    """Achieved vs modeled paged-KV decode traffic.
-
-    Each span/verify dispatch event records the active slots' kv_lens
-    (host mirror values).  With the server's geometry in
-    ``tracer.meta`` we can price every dispatch through
-    ``core/roofline.paged_decode_kv_bytes``: the *achieved* read path
-    (kernel mode walks only valid blocks; gather mode always touches
-    the full extent) vs the gather ceiling.  The ratio is the fraction
-    of the gather-path bytes the configured read path actually moved —
-    a measurement-anchored efficiency number in the spirit of the
-    paper's memory-hierarchy dissection.
-    """
-    meta = tracer.meta
-    need = ("block_size", "max_blocks", "kv_heads", "head_dim",
-            "num_layers")
-    if not all(k in meta for k in need):
-        return {"modeled": False}
-    kw = dict(block_size=meta["block_size"],
-              max_blocks=meta["max_blocks"], kv_heads=meta["kv_heads"],
-              head_dim=meta["head_dim"])
-    mode = meta.get("kv_read_mode", "gather")
-    layers = meta["num_layers"]
-    achieved = modeled_gather = 0.0
-    steps = 0
-    for _t, kind, args in tracer.events:
-        if kind not in ("span_dispatch", "verify_dispatch"):
-            continue
-        kv_lens = args.get("kv_lens") or ()
-        n_steps = args.get("steps", 1)
-        for kv in kv_lens:
-            if kv <= 0:
-                continue
-            achieved += layers * n_steps * roofline.paged_decode_kv_bytes(
-                int(kv), mode=mode, **kw)
-            modeled_gather += (layers * n_steps
-                               * roofline.paged_decode_kv_bytes(
-                                   int(kv), mode="gather", **kw))
-            steps += n_steps
-    if steps == 0:
-        return {"modeled": False}
-    return {"modeled": True, "kv_read_mode": mode,
-            "decode_slot_steps": steps,
-            "achieved_kv_bytes": achieved,
-            "gather_ceiling_bytes": modeled_gather,
-            "bytes_vs_gather": (achieved / modeled_gather
-                                if modeled_gather else 0.0),
-            "mean_kv_bytes_per_step": achieved / steps}
-
-
 def summary_table(tracer: Tracer) -> str:
     """Human-readable trace summary for launch/serve.py --trace."""
     lines: List[str] = []
     lat = request_latency_summary(tracer)
     phases = phase_summary(tracer.metrics)
     occ = occupancy_summary(tracer.metrics)
-    eff = roofline_efficiency(tracer)
+    gap = gap_summary(tracer.metrics)
 
     lines.append("  trace: %d events, %d requests"
                  % (len(tracer.events), len(tracer.requests)))
@@ -165,21 +129,16 @@ def summary_table(tracer: Tracer) -> str:
                      % (label, d["p50"] * 1e3, d["p95"] * 1e3,
                         d["p99"] * 1e3, d["mean"] * 1e3))
     lines.append(f"  {'phase':<14}{'dispatches':>10}{'wall_s':>10}"
-                 f"{'frac':>10}")
+                 f"{'host_s':>10}{'wait_s':>10}{'frac':>10}")
     for phase, d in phases.items():
-        lines.append("  %-14s%10d%10.3f%10.2f"
-                     % (phase, d["dispatches"], d["wall_s"],
-                        d["wall_frac"]))
+        lines.append("  %-14s%10d%10.3f%10.3f%10.3f%10.2f"
+                     % (phase, d["dispatches"], d["wall_s"], d["host_s"],
+                        d["wait_s"], d["wall_frac"]))
+    lines.append("  %-14s%10d%10.3f  (host time between dispatches)"
+                 % ("gap", gap["count"], gap["gap_s"]))
     lines.append("  chunk-occupancy=%.2f span-utilization=%.2f "
                  "peak-blocks=%d"
                  % (occ["chunk_occupancy_mean"],
                     occ["span_utilization_mean"],
                     occ["peak_blocks_in_use"]))
-    if eff.get("modeled"):
-        lines.append("  kv-read=%s achieved=%.2e B vs gather-ceiling="
-                     "%.2e B (x%.3f) over %d decode slot-steps"
-                     % (eff["kv_read_mode"], eff["achieved_kv_bytes"],
-                        eff["gather_ceiling_bytes"],
-                        eff["bytes_vs_gather"],
-                        eff["decode_slot_steps"]))
     return "\n".join(lines)

@@ -176,10 +176,9 @@ class RadixPrefixCache:
         """
         full, partial, plen = self._match(tokens)
         matched = len(full) * self.bs + plen
+        # hits are counted once per admitted request by the server's
+        # admission, which may look a stalled queue head up again
         self.metrics.counter("serving.prefix.lookups").inc()
-        if matched:
-            self.metrics.counter("serving.prefix.hits").inc()
-            self.metrics.counter("serving.prefix.hit_tokens").inc(matched)
         if self.obs.enabled:
             self.obs.event("prefix_lookup", matched_tokens=matched,
                            full_blocks=len(full), partial_len=plen)

@@ -124,6 +124,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -145,6 +146,21 @@ from repro.sharding import plans as plans_mod
 from repro.te import linear as te_linear
 
 Params = Any
+
+# dispatch kind (span names, RequestRecord.seen_after) -> the phase the
+# registry's serving.{dispatches,wall_s,host_s,wait_s}.* are named by
+_PHASE = {"chunk": "prefill", "span": "span", "verify": "verify"}
+
+
+def _in_phase(name: str):
+    """Run a scheduler method inside the tracer's ``name`` span."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(self, *a, **k):
+            with self.obs.phase(name):
+                return fn(self, *a, **k)
+        return run
+    return wrap
 
 
 @dataclasses.dataclass
@@ -273,6 +289,17 @@ class ChunkedServer:
     table, resumes prefill at the first uncached token, and
     copy-on-writes when the request extends into a shared,
     partially-matching block.
+
+    With a ``Tracer`` the scheduler writes host spans into a profiler
+    trace (``Tracer.phase``): ``serve.step`` (one loop iteration,
+    ``step_num`` = the loop counter) holding ``serve.poll``,
+    ``serve.admit``, ``serve.sleep``, ``serve.harvest`` and, per
+    dispatch kind (chunk, span, verify), ``serve.<kind>.prepare`` /
+    ``.put`` / ``.enqueue`` / ``.wait`` / ``.commit``.  Always, the
+    registry splits each dispatch's ``serving.wall_s.<phase>`` into
+    ``serving.host_s.<phase>`` (entry to the jitted call's return) and
+    ``serving.wait_s.<phase>`` (that return to ``block_until_ready``),
+    and ``serving.gap_s`` holds the host time between dispatches.
     """
 
     def __init__(self, cfg: ModelConfig, params: Params, *,
@@ -466,24 +493,20 @@ class ChunkedServer:
             self.spec_drafted = 0
             self.spec_accepted = 0
             self.spec_emitted = 0
+        # scheduler loop counter (the serve.step span's step_num and the
+        # dispatch events' `step`), the kind of the last dispatch, and
+        # its ready stamp (the start of the host gap before the next)
+        self._step = 0
+        self._last_kind: Optional[str] = None
+        self._t_ready: Optional[float] = None
         if self.obs.enabled:
-            # server geometry next to the events: the roofline view
-            # (obs/views.roofline_efficiency) prices each recorded
-            # decode dispatch through core/roofline with these
+            # server geometry next to the events (obs/windows.py)
             self.obs.meta.update(
                 batch_slots=self.B, chunk=self.chunk, span=self.span,
-                max_len=self.max_len, num_layers=cfg.num_layers,
-                kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
-                spec_decode=self.spec_decode, tp=self.tp,
-                paged=self.paged)
+                max_len=self.max_len, spec_decode=self.spec_decode,
+                tp=self.tp, paged=self.paged)
             if self.paged:
-                self.obs.meta.update(
-                    block_size=self.block_size,
-                    max_blocks=self.max_blocks,
-                    num_blocks=self.num_blocks,
-                    kv_read_mode=("fp8_kernel"
-                                  if self.kernel and self.fp8_kv else
-                                  "kernel" if self.kernel else "gather"))
+                self.obs.meta.update(num_blocks=self.num_blocks)
 
     def _sharding_kw(self, *, n_ops: int, n_out: Optional[int] = None,
                      with_params: bool = True) -> Dict[str, Any]:
@@ -828,6 +851,7 @@ class ChunkedServer:
         self._reserved[s] = 0
 
     # -- host-side scheduling --------------------------------------------
+    @_in_phase("serve.admit")
     def _admit(self, queue: List[Request]) -> None:
         for s in range(self.B):
             if self.slot_req[s] is None and queue:
@@ -908,6 +932,12 @@ class ChunkedServer:
                     self.total_prompt_tokens += len(req.prompt)
                     self.cached_prompt_tokens += matched
                     self.prefix_hits += matched > 0
+                    if matched:
+                        # once per admitted request (a stalled queue
+                        # head is looked up again on every retry)
+                        self.metrics.counter("serving.prefix.hits").inc()
+                        self.metrics.counter(
+                            "serving.prefix.hit_tokens").inc(matched)
                 queue.pop(0)
                 # the pos cap stops generation at max_len - in_len tokens;
                 # flag the shortfall instead of harvesting silently short
@@ -928,7 +958,8 @@ class ChunkedServer:
                 self.samp_seed[s] = sp.seed
                 self.metrics.counter("serving.requests.admitted").inc()
                 if self.obs.enabled:
-                    self.obs.admit(req.rid, s, matched, req.truncated)
+                    self.obs.admit(req.rid, s, matched,
+                                   req.truncated, step=self._step)
                     if matched:
                         self.obs.event("prefix_match", rid=req.rid,
                                        slot=s, matched_tokens=matched)
@@ -957,147 +988,154 @@ class ChunkedServer:
         """One packed step: prefill chunks + piggybacked decodes."""
         t0 = time.perf_counter()
         B, C = self.B, self.chunk
-        tokens_host = np.zeros((B, C), np.int32)
-        n_tokens = np.zeros(B, np.int32)
-        is_decode = np.zeros(B, bool)
-        emit = np.zeros(B, bool)
-        for s, req in enumerate(self.slot_req):
-            if req is None:
-                continue
-            if self.mode[s] == "prefill":
-                off = int(self.prompt_off[s])
-                n = min(C, len(req.prompt) - off)
-                tokens_host[s, :n] = req.prompt[off:off + n]
-                n_tokens[s] = n
-                emit[s] = off + n == len(req.prompt)
-                if self.paged:
-                    self._ensure_blocks(s, int(self.pos[s]) + n)
-            elif self.mode[s] == "decode":
-                n_tokens[s] = 1
-                is_decode[s] = True
-                emit[s] = True
-                if self.paged:
-                    self._ensure_blocks(s, int(self.pos[s]) + 1)
-        self.cache, self.cur_tok, self.out_buf = self._chunk_fn(
-            self.params, self.cache, self.cur_tok, self.out_buf,
-            self._put(tokens_host), self._put(self.pos.copy()),
-            self._put(n_tokens), self._put(is_decode), self._put(emit),
-            self._put(self.out_len.copy()),
-            self._put(self.samp_temp.copy()),
-            self._put(self.samp_top_k.copy()),
-            self._put(self.samp_top_p.copy()),
-            self._put(self.samp_seed.copy()),
-            self._put(self._device_block_table()))
-        self.cur_tok.block_until_ready()
-        # dispatch wall time: host prep + device step, measured AFTER
-        # block_until_ready so async dispatch can't hide the step (the
-        # timestamp never enters the jitted body — JX001/AST001)
+        with self.obs.phase("serve.chunk.prepare"):
+            tokens_host = np.zeros((B, C), np.int32)
+            n_tokens = np.zeros(B, np.int32)
+            is_decode = np.zeros(B, bool)
+            emit = np.zeros(B, bool)
+            for s, req in enumerate(self.slot_req):
+                if req is None:
+                    continue
+                if self.mode[s] == "prefill":
+                    off = int(self.prompt_off[s])
+                    n = min(C, len(req.prompt) - off)
+                    tokens_host[s, :n] = req.prompt[off:off + n]
+                    n_tokens[s] = n
+                    emit[s] = off + n == len(req.prompt)
+                    if self.paged:
+                        self._ensure_blocks(s, int(self.pos[s]) + n)
+                elif self.mode[s] == "decode":
+                    n_tokens[s] = 1
+                    is_decode[s] = True
+                    emit[s] = True
+                    if self.paged:
+                        self._ensure_blocks(s, int(self.pos[s]) + 1)
+        with self.obs.phase("serve.chunk.put"):
+            ops = (self._put(tokens_host), self._put(self.pos.copy()),
+                   self._put(n_tokens), self._put(is_decode),
+                   self._put(emit), self._put(self.out_len.copy()),
+                   self._put(self.samp_temp.copy()),
+                   self._put(self.samp_top_k.copy()),
+                   self._put(self.samp_top_p.copy()),
+                   self._put(self.samp_seed.copy()),
+                   self._put(self._device_block_table()))
+        t_call = time.perf_counter()
+        with self.obs.phase("serve.chunk.enqueue"):
+            self.cache, self.cur_tok, self.out_buf = self._chunk_fn(
+                self.params, self.cache, self.cur_tok, self.out_buf, *ops)
+        t_ret = time.perf_counter()
+        with self.obs.phase("serve.chunk.wait"):
+            self.cur_tok.block_until_ready()
         t1 = time.perf_counter()
-        packed = int(n_tokens.sum())
-        self.metrics.counter("serving.dispatches.prefill").inc()
-        self.metrics.histogram("serving.wall_s.prefill").record(t1 - t0)
-        self.metrics.histogram("serving.chunk.occupancy").record(
-            packed / (B * C) if B * C else 0.0)
-        if self.obs.enabled:
-            self.obs.span("chunk_dispatch", t0, t1,
-                          packed_tokens=packed,
-                          n_prefill=int((n_tokens > 0).sum()
-                                        - is_decode.sum()),
-                          n_decode=int(is_decode.sum()))
-        # EOS needs the emitted tokens on the host; length-only stopping
-        # stays transfer-free (the readback is explicit so the loop
-        # stays valid under jax.transfer_guard("disallow"))
-        toks = (jax.device_get(self.cur_tok) if self.eos_id is not None
-                else None)
-        prompt_tokens = 0
-        for s, req in enumerate(self.slot_req):
-            if req is None:
-                continue
-            if self.mode[s] == "prefill":
-                n = int(n_tokens[s])
-                prompt_tokens += n
-                self.prompt_off[s] += n
-                self.pos[s] += n
-                if emit[s]:                 # prompt exhausted: first token
-                    self.mode[s] = "decode"
+        self._record_dispatch("chunk", t0, t_call, t_ret, t1)
+        with self.obs.phase("serve.chunk.commit"):
+            packed = int(n_tokens.sum())
+            self.metrics.histogram("serving.chunk.occupancy").record(
+                packed / (B * C) if B * C else 0.0)
+            if self.obs.enabled:
+                self.obs.span("chunk_dispatch", t0, t1,
+                              packed_tokens=packed,
+                              n_prefill=int((n_tokens > 0).sum()
+                                            - is_decode.sum()),
+                              n_decode=int(is_decode.sum()),
+                              step=self._step)
+            # EOS needs the emitted tokens on the host; length-only stopping
+            # stays transfer-free (the readback is explicit so the loop
+            # stays valid under jax.transfer_guard("disallow"))
+            toks = (jax.device_get(self.cur_tok) if self.eos_id is not None
+                    else None)
+            prompt_tokens = 0
+            for s, req in enumerate(self.slot_req):
+                if req is None:
+                    continue
+                if self.mode[s] == "prefill":
+                    n = int(n_tokens[s])
+                    prompt_tokens += n
+                    self.prompt_off[s] += n
+                    self.pos[s] += n
+                    if emit[s]:                 # prompt exhausted: first token
+                        self.mode[s] = "decode"
+                        self.out_len[s] += 1
+                        if self.obs.enabled:
+                            self.obs.first_token(req.rid, step=self._step)
+                        if toks is not None and int(toks[s]) == self.eos_id:
+                            self._mark_done(s)
+                        else:
+                            self._check_done(s)
+                elif self.mode[s] == "decode":
                     self.out_len[s] += 1
-                    if self.obs.enabled:
-                        self.obs.first_token(req.rid)
+                    self.pos[s] += 1
                     if toks is not None and int(toks[s]) == self.eos_id:
                         self._mark_done(s)
                     else:
                         self._check_done(s)
-            elif self.mode[s] == "decode":
-                self.out_len[s] += 1
-                self.pos[s] += 1
-                if toks is not None and int(toks[s]) == self.eos_id:
-                    self._mark_done(s)
-                else:
-                    self._check_done(s)
-        return prompt_tokens
+            return prompt_tokens
 
     def _run_decode_span(self) -> None:
         t0 = time.perf_counter()
-        active = np.array([m == "decode" for m in self.mode])
-        if self.obs.enabled:
-            # pre-span context lengths of the active slots (host mirror
-            # scalars) — the roofline view prices the span's KV traffic
-            # from these
-            kv_lens = tuple(int(p) for p in self.pos[active])
-        max_new = np.array(
-            [r.max_new if r is not None else 0 for r in self.slot_req],
-            np.int32)
-        # deterministic mirror of the on-device span, computed up front
-        # so the paged allocator knows each slot's final frontier before
-        # the device writes to it (EOS may stop a slot earlier than the
-        # sim — that only over-assigns blocks within the reservation)
-        cap = self.max_len - 1
-        sim_pos = self.pos.copy()
-        sim_out = self.out_len.copy()
-        sim_act = active.copy()
-        for _ in range(self.span):
-            for s in np.flatnonzero(sim_act):
-                sim_out[s] += 1
-                sim_pos[s] += 1
-                if (sim_out[s] >= max_new[s] or sim_pos[s] >= cap):
-                    sim_act[s] = False
-        if self.paged:
-            for s in np.flatnonzero(active):
-                self._ensure_blocks(s, int(sim_pos[s]))
-        (self.cache, self.cur_tok, self.out_buf, pos_d, out_d,
-         act_d) = self._span_fn(
-            self.params, self.cache, self.cur_tok, self.out_buf,
-            self._put(self.pos.copy()), self._put(self.out_len.copy()),
-            self._put(active), self._put(max_new),
-            self._put(self.samp_temp.copy()),
-            self._put(self.samp_top_k.copy()),
-            self._put(self.samp_top_p.copy()),
-            self._put(self.samp_seed.copy()),
-            self._put(self._device_block_table()))
-        self.cur_tok.block_until_ready()
+        with self.obs.phase("serve.span.prepare"):
+            active = np.array([m == "decode" for m in self.mode])
+            max_new = np.array(
+                [r.max_new if r is not None else 0
+                 for r in self.slot_req], np.int32)
+            # deterministic mirror of the on-device span, computed up
+            # front so the paged allocator knows each slot's final
+            # frontier before the device writes to it (EOS may stop a
+            # slot earlier than the sim — that only over-assigns blocks
+            # within the reservation)
+            cap = self.max_len - 1
+            sim_pos = self.pos.copy()
+            sim_out = self.out_len.copy()
+            sim_act = active.copy()
+            for _ in range(self.span):
+                for s in np.flatnonzero(sim_act):
+                    sim_out[s] += 1
+                    sim_pos[s] += 1
+                    if (sim_out[s] >= max_new[s] or sim_pos[s] >= cap):
+                        sim_act[s] = False
+            if self.paged:
+                for s in np.flatnonzero(active):
+                    self._ensure_blocks(s, int(sim_pos[s]))
+        with self.obs.phase("serve.span.put"):
+            ops = (self._put(self.pos.copy()),
+                   self._put(self.out_len.copy()),
+                   self._put(active), self._put(max_new),
+                   self._put(self.samp_temp.copy()),
+                   self._put(self.samp_top_k.copy()),
+                   self._put(self.samp_top_p.copy()),
+                   self._put(self.samp_seed.copy()),
+                   self._put(self._device_block_table()))
+        t_call = time.perf_counter()
+        with self.obs.phase("serve.span.enqueue"):
+            (self.cache, self.cur_tok, self.out_buf, pos_d, out_d,
+             act_d) = self._span_fn(self.params, self.cache, self.cur_tok,
+                                    self.out_buf, *ops)
+        t_ret = time.perf_counter()
+        with self.obs.phase("serve.span.wait"):
+            self.cur_tok.block_until_ready()
         t1 = time.perf_counter()
-        prev_out = self.out_len
-        if self.eos_id is None:
-            self.pos = sim_pos
-            self.out_len = sim_out
-            done_now = active & ~sim_act
-        else:
-            # EOS stopping is data-dependent: sync the span's final
-            # bookkeeping instead of trusting the length-only sim
-            self.pos = np.array(jax.device_get(pos_d), np.int32)
-            self.out_len = np.array(jax.device_get(out_d), np.int32)
-            done_now = active & ~jax.device_get(act_d)
-        productive = int((self.out_len - prev_out).sum())
-        self.metrics.counter("serving.dispatches.span").inc()
-        self.metrics.histogram("serving.wall_s.span").record(t1 - t0)
-        self.metrics.histogram("serving.span.utilization").record(
-            productive / (self.B * self.span))
-        if self.obs.enabled:
-            self.obs.span("span_dispatch", t0, t1, steps=self.span,
-                          n_active=int(active.sum()),
-                          emitted=productive, kv_lens=kv_lens)
-        for s in np.flatnonzero(done_now):
-            self._mark_done(s)
+        self._record_dispatch("span", t0, t_call, t_ret, t1)
+        with self.obs.phase("serve.span.commit"):
+            prev_out = self.out_len
+            if self.eos_id is None:
+                self.pos = sim_pos
+                self.out_len = sim_out
+                done_now = active & ~sim_act
+            else:
+                # EOS stopping is data-dependent: sync the span's final
+                # bookkeeping instead of trusting the length-only sim
+                self.pos = np.array(jax.device_get(pos_d), np.int32)
+                self.out_len = np.array(jax.device_get(out_d), np.int32)
+                done_now = active & ~jax.device_get(act_d)
+            productive = int((self.out_len - prev_out).sum())
+            self.metrics.histogram("serving.span.utilization").record(
+                productive / (self.B * self.span))
+            if self.obs.enabled:
+                self.obs.span("span_dispatch", t0, t1, steps=self.span,
+                              n_active=int(active.sum()),
+                              emitted=productive, step=self._step)
+            for s in np.flatnonzero(done_now):
+                self._mark_done(s)
 
     def _run_spec_step(self) -> None:
         """One speculative draft→verify→accept step for every decoding
@@ -1111,66 +1149,95 @@ class ChunkedServer:
         accepted frontier."""
         t0 = time.perf_counter()
         K = self.spec_decode
-        active = np.array([m == "decode" for m in self.mode])
-        if self.obs.enabled:
-            kv_lens = tuple(int(p) for p in self.pos[active])
-        max_new = np.array(
-            [r.max_new if r is not None else 0 for r in self.slot_req],
-            np.int32)
-        cap = self.max_len - 1
-        if self.paged:
-            for s in np.flatnonzero(active):
-                # cover the verify window only up to the slot's emit
-                # budget: the window rows past it can never be accepted
-                # and their writes drop beyond the table, so admission
-                # reservations (computed from max_new) always suffice
-                budget = min(int(max_new[s]) - int(self.out_len[s]),
-                             cap - int(self.pos[s]))
-                self._ensure_blocks(
-                    s, int(self.pos[s]) + min(K + 1, max(budget, 1)))
-        (self.cache, self.ngram_table, self.cur_tok, self.out_buf,
-         pos_d, out_d, act_d, emit_d) = self._verify_fn(
-            self.params, self.cache, self.ngram_table, self.cur_tok,
-            self.out_buf, self._put(self.pos.copy()),
-            self._put(self.out_len.copy()), self._put(active),
-            self._put(max_new),
-            self._put(self.samp_temp.copy()),
-            self._put(self.samp_top_k.copy()),
-            self._put(self.samp_top_p.copy()),
-            self._put(self.samp_seed.copy()),
-            self._put(self._device_block_table()))
-        self.cur_tok.block_until_ready()
-        emit = jax.device_get(emit_d)
-        self.pos = np.array(jax.device_get(pos_d), np.int32)
-        self.out_len = np.array(jax.device_get(out_d), np.int32)
-        done_now = active & ~jax.device_get(act_d)
+        with self.obs.phase("serve.verify.prepare"):
+            active = np.array([m == "decode" for m in self.mode])
+            max_new = np.array(
+                [r.max_new if r is not None else 0
+                 for r in self.slot_req], np.int32)
+            cap = self.max_len - 1
+            if self.paged:
+                for s in np.flatnonzero(active):
+                    # cover the verify window only up to the slot's emit
+                    # budget: the window rows past it can never be
+                    # accepted and their writes drop beyond the table,
+                    # so admission reservations (computed from max_new)
+                    # always suffice
+                    budget = min(int(max_new[s]) - int(self.out_len[s]),
+                                 cap - int(self.pos[s]))
+                    self._ensure_blocks(
+                        s, int(self.pos[s]) + min(K + 1, max(budget, 1)))
+        with self.obs.phase("serve.verify.put"):
+            ops = (self._put(self.pos.copy()),
+                   self._put(self.out_len.copy()), self._put(active),
+                   self._put(max_new),
+                   self._put(self.samp_temp.copy()),
+                   self._put(self.samp_top_k.copy()),
+                   self._put(self.samp_top_p.copy()),
+                   self._put(self.samp_seed.copy()),
+                   self._put(self._device_block_table()))
+        t_call = time.perf_counter()
+        with self.obs.phase("serve.verify.enqueue"):
+            (self.cache, self.ngram_table, self.cur_tok, self.out_buf,
+             pos_d, out_d, act_d, emit_d) = self._verify_fn(
+                self.params, self.cache, self.ngram_table, self.cur_tok,
+                self.out_buf, *ops)
+        t_ret = time.perf_counter()
+        with self.obs.phase("serve.verify.wait"):
+            self.cur_tok.block_until_ready()
+            emit = jax.device_get(emit_d)
+            self.pos = np.array(jax.device_get(pos_d), np.int32)
+            self.out_len = np.array(jax.device_get(out_d), np.int32)
+            done_now = active & ~jax.device_get(act_d)
         t1 = time.perf_counter()
-        if self.paged:
-            # rejected drafts: shrink the block-table frontier back to
-            # the accepted positions (restores the reservation drawn
-            # pre-verify; stale KV beyond it is never read)
-            for s in np.flatnonzero(active):
-                rolled = self._truncate_blocks(s, int(self.pos[s]))
-                if rolled:
-                    self.metrics.counter(
-                        "serving.spec.rollback_blocks").inc(rolled)
-                    if self.obs.enabled:
-                        self.obs.event("spec_rollback", slot=int(s),
-                                       blocks=rolled)
-        for s in np.flatnonzero(done_now):
-            self._mark_done(s)
-        nact = int(active.sum())
-        self.spec_steps += 1
-        self.spec_slot_steps += nact
-        self.spec_drafted += K * nact
-        self.spec_emitted += int(emit.sum())
-        self.spec_accepted += int(np.maximum(emit - 1, 0).sum())
-        spec.record_dispatch(
-            self.metrics, self.obs, t0=t0, t1=t1, k=K, n_active=nact,
-            emitted=int(emit.sum()),
-            accepted=int(np.maximum(emit - 1, 0).sum()),
-            kv_lens=kv_lens if self.obs.enabled else ())
+        self._record_dispatch("verify", t0, t_call, t_ret, t1)
+        with self.obs.phase("serve.verify.commit"):
+            if self.paged:
+                # rejected drafts: shrink the block-table frontier back
+                # to the accepted positions (restores the reservation
+                # drawn pre-verify; stale KV beyond it is never read)
+                for s in np.flatnonzero(active):
+                    rolled = self._truncate_blocks(s, int(self.pos[s]))
+                    if rolled:
+                        self.metrics.counter(
+                            "serving.spec.rollback_blocks").inc(rolled)
+                        if self.obs.enabled:
+                            self.obs.event("spec_rollback", slot=int(s),
+                                           blocks=rolled)
+            for s in np.flatnonzero(done_now):
+                self._mark_done(s)
+            nact = int(active.sum())
+            self.spec_steps += 1
+            self.spec_slot_steps += nact
+            self.spec_drafted += K * nact
+            self.spec_emitted += int(emit.sum())
+            self.spec_accepted += int(np.maximum(emit - 1, 0).sum())
+            spec.record_dispatch(
+                self.metrics, self.obs, t0=t0, t1=t1, k=K, n_active=nact,
+                emitted=int(emit.sum()),
+                accepted=int(np.maximum(emit - 1, 0).sum()),
+                step=self._step)
 
+    def _record_dispatch(self, kind: str, t0: float, t_call: float,
+                         t_ret: float, t1: float) -> None:
+        """One dispatch's host/device split, from the stamps around it:
+        method entry ``t0``, the jitted call's start ``t_call`` and
+        return ``t_ret``, and ``t1`` after ``block_until_ready``.
+        ``host_s + wait_s == wall_s`` holds exactly, and ``gap_s`` runs
+        from the previous dispatch's ready stamp to this call: the
+        device's idle time per step while no dispatch overlaps another
+        (the stamps never enter a jitted body — JX001/AST001)."""
+        m, phase = self.metrics, _PHASE[kind]
+        host, wait = t_ret - t0, t1 - t_ret
+        m.counter(f"serving.dispatches.{phase}").inc()
+        m.histogram(f"serving.wall_s.{phase}").record(host + wait)
+        m.histogram(f"serving.host_s.{phase}").record(host)
+        m.histogram(f"serving.wait_s.{phase}").record(wait)
+        if self._t_ready is not None:
+            m.histogram("serving.gap_s").record(t_call - self._t_ready)
+        self._t_ready = t1
+        self._last_kind = kind
+
+    @_in_phase("serve.harvest")
     def _harvest(self) -> int:
         done_slots = [s for s in range(self.B) if self.mode[s] == "done"]
         if not done_slots:
@@ -1222,6 +1289,9 @@ class ChunkedServer:
         until the caller clears it — warm/measured A/B runs call
         ``tracer.clear()`` between waves)."""
         self.metrics.reset()
+        self._step = 0
+        self._last_kind = None
+        self._t_ready = None
         if self.paged:
             # pool metrics are per run, not per server lifetime
             self.peak_blocks = self._blocks_in_use()
@@ -1250,15 +1320,17 @@ class ChunkedServer:
         served_tokens = 0
         prefill_tokens = 0
         while queue or any(r is not None for r in self.slot_req):
-            self._admit(queue)
-            if any(m == "prefill" for m in self.mode):
-                prefill_tokens += self._run_chunk_step()
-            elif any(m == "decode" for m in self.mode):
-                if self.spec_decode:
-                    self._run_spec_step()
-                else:
-                    self._run_decode_span()
-            served_tokens += self._harvest()
+            with self.obs.phase("serve.step", step_num=self._step):
+                self._admit(queue)
+                if any(m == "prefill" for m in self.mode):
+                    prefill_tokens += self._run_chunk_step()
+                elif any(m == "decode" for m in self.mode):
+                    if self.spec_decode:
+                        self._run_spec_step()
+                    else:
+                        self._run_decode_span()
+                served_tokens += self._harvest()
+            self._step += 1
         dt = time.perf_counter() - t0
         return self._run_stats(requests, dt, served_tokens,
                                prefill_tokens)
@@ -1290,6 +1362,11 @@ class ChunkedServer:
         sleep are host-only), and greedy outputs on a ``closed_stream``
         are bit-identical to ``serve`` on the same requests.
 
+        Each request's record also keeps when the loop released it
+        into the admission queue (``t_seen``) and which dispatch the
+        loop ran last before then (``seen_after``): a request that
+        arrives during a dispatch waits for it to end.
+
         Returns the ``serve`` stats plus online extras: realized
         ``offered_rate_rps``, ``arrival_span_s``, idle/sleep seconds,
         and the peak admission-queue depth (also tracked live in the
@@ -1307,38 +1384,44 @@ class ChunkedServer:
         t0 = time.perf_counter()
         while (next_i < len(arrivals) or queue
                or any(r is not None for r in self.slot_req)):
-            now = time.perf_counter() - t0
-            while (next_i < len(arrivals)
-                   and arrivals[next_i].t_arrival <= now):
-                tr = arrivals[next_i]
-                next_i += 1
-                queue.append(tr.request)
-                if self.obs.enabled:
-                    self.obs.enqueue(tr.request.rid,
-                                     len(tr.request.prompt),
-                                     tr.request.max_new,
-                                     t=t0 + tr.t_arrival)
-            depth = len(queue)
-            peak_queue_depth = max(peak_queue_depth, depth)
-            self.metrics.gauge("serving.queue.depth").set(float(depth))
-            self._admit(queue)
-            if any(m == "prefill" for m in self.mode):
-                prefill_tokens += self._run_chunk_step()
-            elif any(m == "decode" for m in self.mode):
-                if self.spec_decode:
-                    self._run_spec_step()
-                else:
-                    self._run_decode_span()
-            elif not queue and next_i < len(arrivals):
-                # fully drained with arrivals still scheduled: sleep
-                # toward the next stamp instead of busy-spinning
-                wait = (t0 + arrivals[next_i].t_arrival
-                        - time.perf_counter())
-                if wait > 0:
-                    nap = min(wait, max_idle_sleep_s)
-                    time.sleep(nap)
-                    idle_s += nap
-            served_tokens += self._harvest()
+            with self.obs.phase("serve.step", step_num=self._step):
+                with self.obs.phase("serve.poll"):
+                    now = time.perf_counter() - t0
+                    while (next_i < len(arrivals)
+                           and arrivals[next_i].t_arrival <= now):
+                        tr = arrivals[next_i]
+                        next_i += 1
+                        queue.append(tr.request)
+                        if self.obs.enabled:
+                            self.obs.enqueue(
+                                tr.request.rid, len(tr.request.prompt),
+                                tr.request.max_new, t=t0 + tr.t_arrival,
+                                t_seen=t0 + now,
+                                seen_after=self._last_kind)
+                    depth = len(queue)
+                    peak_queue_depth = max(peak_queue_depth, depth)
+                    self.metrics.gauge("serving.queue.depth").set(
+                        float(depth))
+                self._admit(queue)
+                if any(m == "prefill" for m in self.mode):
+                    prefill_tokens += self._run_chunk_step()
+                elif any(m == "decode" for m in self.mode):
+                    if self.spec_decode:
+                        self._run_spec_step()
+                    else:
+                        self._run_decode_span()
+                elif not queue and next_i < len(arrivals):
+                    # fully drained with arrivals still scheduled: sleep
+                    # toward the next stamp instead of busy-spinning
+                    with self.obs.phase("serve.sleep"):
+                        wait = (t0 + arrivals[next_i].t_arrival
+                                - time.perf_counter())
+                        if wait > 0:
+                            nap = min(wait, max_idle_sleep_s)
+                            time.sleep(nap)
+                            idle_s += nap
+                served_tokens += self._harvest()
+            self._step += 1
         dt = time.perf_counter() - t0
         stats = self._run_stats(requests, dt, served_tokens,
                                 prefill_tokens)

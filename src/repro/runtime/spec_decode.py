@@ -245,23 +245,21 @@ def spec_decode_step(cfg, params, cache, table: jax.Array,
 
 def record_dispatch(metrics, tracer, *, t0: float, t1: float, k: int,
                     n_active: int, emitted: int, accepted: int,
-                    kv_lens: Tuple[int, ...] = ()) -> None:
+                    step: int = 0) -> None:
     """Host-side per-dispatch acceptance accounting for one verify
     step (called by the serving loop AFTER block_until_ready + the
     pos/out_len sync — every argument is a python scalar already on
     the host, so this can never add a device transfer).
 
-    Feeds the ``serving.dispatches.verify`` / ``serving.wall_s.verify``
-    instruments the phase breakdown reads, plus the per-dispatch
-    acceptance histograms (``serving.spec.tokens_per_slot`` — mean
-    emitted tokens per active slot, the >1.0 speculative win — and
-    ``serving.spec.accept_rate`` — accepted / drafted for the
-    dispatch, the distribution-match signal under sampling) and a
-    ``verify_dispatch`` trace event carrying the pre-dispatch context
-    lengths for the roofline view.
+    Feeds the per-dispatch acceptance histograms
+    (``serving.spec.tokens_per_slot`` — mean emitted tokens per active
+    slot, the >1.0 speculative win — and ``serving.spec.accept_rate``
+    — accepted / drafted for the dispatch, the distribution-match
+    signal under sampling) and a ``verify_dispatch`` trace event
+    carrying the scheduler loop counter ``step``.  The dispatch count
+    and its wall/host/wait split are the server's
+    (``ChunkedServer._record_dispatch``).
     """
-    metrics.counter("serving.dispatches.verify").inc()
-    metrics.histogram("serving.wall_s.verify").record(t1 - t0)
     metrics.counter("serving.spec.drafted").inc(k * n_active)
     metrics.counter("serving.spec.accepted").inc(accepted)
     metrics.counter("serving.spec.emitted").inc(emitted)
@@ -274,4 +272,4 @@ def record_dispatch(metrics, tracer, *, t0: float, t1: float, k: int,
     if tracer.enabled:
         tracer.span("verify_dispatch", t0, t1, steps=1,
                     n_active=n_active, emitted=emitted,
-                    accepted=accepted, kv_lens=kv_lens)
+                    accepted=accepted, step=step)

@@ -18,8 +18,7 @@ from repro.models import api
 from repro.obs import (MetricsRegistry, NULL_METRICS, NULL_TRACER,
                        Tracer, occupancy_summary, percentiles,
                        phase_summary, request_latency_summary,
-                       roofline_efficiency, summary_table, write_jsonl,
-                       write_chrome_trace)
+                       summary_table, write_jsonl, write_chrome_trace)
 from repro.runtime.prefix_cache import BlockPool, RadixPrefixCache
 from repro.runtime.server import (ChunkedServer, clone_requests,
                                   sharegpt_like_requests)
@@ -231,8 +230,9 @@ def test_prefix_cache_records_lookups_and_evictions():
     assert full == blocks
     m = tr.metrics
     assert m.counter_value("serving.prefix.lookups") == 1
-    assert m.counter_value("serving.prefix.hits") == 1
-    assert m.counter_value("serving.prefix.hit_tokens") == 12
+    # hits are the server's admission's to count, once per request
+    assert m.counter_value("serving.prefix.hits") == 0
+    assert m.counter_value("serving.prefix.hit_tokens") == 0
     assert tree.evict(3) == 3
     assert m.counter_value("serving.prefix.evictions") == 3
     kinds = [k for _, k, _ in tr.events]
@@ -293,9 +293,6 @@ def test_traced_serving_identical_outputs_and_compiles(setup):
     assert 0 < occ["chunk_occupancy_mean"] <= 1.0
     assert occ["peak_blocks_in_use"] > 0
 
-    eff = roofline_efficiency(tracer)
-    assert eff["modeled"] and eff["decode_slot_steps"] > 0
-    assert 0 < eff["bytes_vs_gather"] <= 1.0
     assert "ttft" in summary_table(tracer)
 
     # untraced server still derives its phase split from the registry
