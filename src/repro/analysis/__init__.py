@@ -11,8 +11,8 @@ Three layers, one report (run ``python -m repro.analysis``):
   BlockSpec/grid contracts: oversize tiles, grid coverage, lane /
   sublane alignment, estimated VMEM footprint.
 * ``ast_lint``     — repo-specific AST rules over ``runtime/`` and
-  ``models/``: host transfers in hot-path bodies, dot/einsum in the
-  parity-critical attention bodies, mutable server state captured in
+  ``models/``: host transfers in hot-path bodies, dot/einsum in
+  parity-critical bodies, mutable server state captured in
   jitted closures (the seed SlotServer frozen-``self.pos`` bug class).
 
 The checked invariants, their rule IDs and the suppression mechanism

@@ -15,8 +15,8 @@ Works on source text only (no imports, no tracing):
   ``jax.random`` threefry keyed by (seed, position)
   (models/sampling.py).
 * **AST002** — ``@`` / ``dot`` / ``einsum`` / ``dot_general`` inside
-  the parity-critical attention bodies (contracts.PARITY_BODIES) that
-  must phrase scores and PV as explicit multiply+``jnp.sum``.
+  a parity-critical body (contracts.PARITY_BODIES) that must phrase
+  its contractions as explicit multiply+``jnp.sum``.
 * **AST003** — a ``jax.jit``-ed body (method reference or lambda)
   reading mutable server state through ``self.<attr>``, where mutable
   means "assigned outside ``__init__``" — jit would freeze the value

@@ -7,7 +7,7 @@ themselves stay generic.
 from __future__ import annotations
 
 import itertools
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Set
 
 # ----------------------------------------------------------------------
 # trace hooks (jax.ad_checkpoint.checkpoint_name tags in the model)
@@ -37,13 +37,12 @@ HOT_PATH_ROOTS = [
     ("repro.runtime.server", "SlotServer._prefill_impl"),
 ]
 
-# attention score/PV bodies that must stay explicit multiply+sum (the
-# bitwise agreement of the XLA serving paths: XLA strength-reduces
-# small-M dots data-dependently, so dot/einsum formulations drift
-# ~1 ulp).  path suffix (repo-relative) -> function names.
-PARITY_BODIES = {
-    "models/attention.py": {"decode_attention", "chunk_attention"},
-}
+# function bodies that must phrase their contractions as explicit
+# multiply+sum (AST002), for a bitwise agreement that dot/einsum forms
+# cannot promise.  path suffix (repo-relative) -> function names.  No
+# body needs it today: the serving attention contracts on the MXU and
+# is held to float32 references within stated tolerances.
+PARITY_BODIES: Dict[str, Set[str]] = {}
 
 # packages scanned by ast_lint (plus the PARITY_BODIES files);
 # src/repro/obs is included so instrumentation helpers stay visible to

@@ -44,8 +44,8 @@ RULES: Dict[str, tuple] = {
     # layer 3: AST rules over runtime/ + models/
     "AST001": ("error", "host transfer (.item()/np.asarray/"
                         "jax.device_get/...) inside a hot-path body"),
-    "AST002": ("error", "dot/@/einsum in a parity-critical attention "
-                        "body that must stay explicit multiply+sum"),
+    "AST002": ("error", "dot/@/einsum in a parity-critical body "
+                        "that must stay explicit multiply+sum"),
     "AST003": ("error", "mutable server state read inside a jitted "
                         "body (jit freezes it per-trace: the seed "
                         "SlotServer frozen-self.pos bug class)"),

@@ -27,9 +27,10 @@ Numerics
 Scores and the PV contraction are `dot_general`s with float32
 accumulation (the MXU on a TPU).  Scratch rows past the valid frontier
 are zero-filled, and masked scores carry exactly-zero softmax weight.
-The result is not bitwise equal to the gather path, whose attention is
-an XLA multiply+sum: tests/test_paged_kernel.py compares both against
-a float32 reference within tolerances stated there.
+The result is not bitwise equal to the gather path, whose XLA dots
+sum in another order over the whole gathered view:
+tests/test_paged_kernel.py compares both against a float32 reference
+within tolerances stated there.
 
 FP8 pools
 ---------

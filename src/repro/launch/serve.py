@@ -100,8 +100,9 @@ def main() -> None:
                          "of them in one fixed-shape dispatch, "
                          "accepting the longest prefix that matches "
                          "the model's own greedy argmax — outputs are "
-                         "bit-identical to K=0, only the number of "
-                         "model dispatches per token changes.  "
+                         "K=0's (up to the attention's fp32 summation "
+                         "order), only the number of model dispatches "
+                         "per token changes.  "
                          "Default 0 = off (plain decode spans)")
     ap.add_argument("--kernel", action="store_true",
                     help="read paged KV through the fused Pallas "

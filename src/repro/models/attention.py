@@ -467,40 +467,20 @@ def init_kv_cache(cfg, batch: int, max_len: int, *, layers: int,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      kv_len: jax.Array) -> jax.Array:
-    """One-step decode: q [B,1,H,hd] vs cache [B,T,KH,hd].
+    """One-step decode: q [B,1,H,hd] vs cache [B,T,KH,hd], attending
+    positions t < kv_len (a scalar, or one length per slot [B]).
 
     Memory is linear in T, so no chunking is needed even at T=512k; with
     the cache sequence-sharded ("kv_seq" -> a mesh axis) XLA emits the
     split-K/flash-decode pattern (partial max/sum + small all-reduces).
 
-    The score and PV contractions are explicit broadcast-multiply +
-    `jnp.sum` rather than einsum/dot_general: the contiguous and paged
-    gather paths and the chunk/verify programs are held bitwise equal
-    by the serving tests, and XLA strength-reduces the small-M decode
-    dots (G=1 is a matvec) data-dependently inside larger jitted
-    graphs, so dot-based forms round differently at ~1 ulp.  The
-    mul+reduce form lowers identically everywhere.
+    The C=1 case of `chunk_attention`, its one row at position
+    kv_len - 1; for G=1 XLA lowers its dots to the matrix-vector
+    products they are.
     """
-    B, _, H, hd = q.shape
-    T, KH = k_cache.shape[1], k_cache.shape[2]
-    G = H // KH
-    qg = q.reshape(B, KH, G, hd)
-    kt = k_cache.transpose(0, 2, 1, 3)            # [B,KH,T,hd]
-    vt = v_cache.transpose(0, 2, 1, 3)
-    s = jnp.sum(qg.astype(jnp.float32)[:, :, :, None, :]
-                * kt.astype(jnp.float32)[:, :, None, :, :],
-                axis=-1) * hd ** -0.5             # [B,KH,G,T]
-    kv_len = jnp.asarray(kv_len)
-    bound = kv_len[:, None, None, None] if kv_len.ndim == 1 else kv_len
-    valid = jnp.arange(T)[None, None, None, :] < bound
-    s = jnp.where(valid, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    p = e / jnp.sum(e, axis=-1, keepdims=True)
-    pv = p.astype(v_cache.dtype)
-    o = jnp.sum(pv.astype(jnp.float32)[:, :, :, :, None]
-                * vt.astype(jnp.float32)[:, :, None, :, :], axis=3)
-    return o.reshape(B, 1, H, hd).astype(q.dtype)
+    B = q.shape[0]
+    last = jnp.broadcast_to(jnp.asarray(kv_len)[..., None] - 1, (B, 1))
+    return chunk_attention(q, k_cache, v_cache, last)
 
 
 def chunk_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -518,28 +498,38 @@ def chunk_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     Rows past a slot's valid token count attend garbage but only
     produce garbage in their own output rows, which callers discard.
 
-    Like `decode_attention`, the contractions are broadcast-multiply +
-    `jnp.sum`, for the same bitwise agreement between the XLA serving
-    paths.
+    Both contractions are `dot_general`s on the operands' own dtypes
+    (bf16 on the serving paths) with float32 accumulation, over the
+    [B,T,KH,hd] view as it comes (dimension numbers, no transpose), so
+    on a TPU they run on the MXU; `Precision.HIGHEST` keeps f32
+    operands from being cut to bf16 passes.  The mask, the NEG_INF
+    fill, the max/exp/sum and the normalisation are float32; `p` is
+    cast to the value dtype before PV.  Masked weights are exact
+    zeros, so what sits at a masked position (a contiguous cache's
+    stale rows, a paged view's garbage block) never reaches the
+    output.  The order of the f32 sums is the compiler's: programs of
+    different shapes (a chunk step, a decode step) agree to rounding,
+    not bit for bit.
     """
     B, C, H, hd = q.shape
     T, KH = k_cache.shape[1], k_cache.shape[2]
     G = H // KH
-    qc = q.reshape(B, C, KH, G, hd).transpose(0, 2, 3, 1, 4)  # [B,KH,G,C,hd]
-    kt = k_cache.transpose(0, 2, 1, 3)                        # [B,KH,T,hd]
-    vt = v_cache.transpose(0, 2, 1, 3)
-    s = jnp.sum(qc.astype(jnp.float32)[:, :, :, :, None, :]
-                * kt.astype(jnp.float32)[:, :, None, None, :, :],
-                axis=-1) * hd ** -0.5                         # [B,KH,G,C,T]
+    qg = q.reshape(B, C, KH, G, hd)
+    # [B,C,KH,G,hd] x [B,T,KH,hd] -> [B,KH,C,G,T]
+    s = lax.dot_general(qg, k_cache, (((4,), (3,)), ((0, 2), (0, 2))),
+                        precision=lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32) * hd ** -0.5
     mask = jnp.arange(T)[None, None, :] <= q_positions[:, :, None]  # [B,C,T]
-    s = jnp.where(mask[:, None, None, :, :], s, NEG_INF)
+    s = jnp.where(mask[:, None, :, None, :], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)
     pv = p.astype(v_cache.dtype)
-    o = jnp.sum(pv.astype(jnp.float32)[:, :, :, :, :, None]
-                * vt.astype(jnp.float32)[:, :, None, None, :, :], axis=4)
-    return o.transpose(0, 3, 1, 2, 4).reshape(B, C, H, hd).astype(q.dtype)
+    # [B,KH,C,G,T] x [B,T,KH,hd] -> [B,KH,C,G,hd]
+    o = lax.dot_general(pv, v_cache, (((4,), (1,)), ((0, 1), (0, 2))),
+                        precision=lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)
+    return o.transpose(0, 2, 1, 3, 4).reshape(B, C, H, hd).astype(q.dtype)
 
 
 def update_cache(cache_k: jax.Array, cache_v: jax.Array, k1: jax.Array,
@@ -628,10 +618,12 @@ def gather_paged_cache(ck: jax.Array, cv: jax.Array,
     exclude exactly those positions (masked scores sit at NEG_INF, so
     their softmax weight underflows to an exact 0.0 and, since
     0.0 * x == 0.0 for finite x, the outputs stay bit-identical to a
-    contiguous cache).  A poisoned pool block therefore cannot leak
-    into any slot's output through either this gather path or the
-    in-kernel block-table walk of kernels/paged_attention, which never
-    touches -1 entries at all (its loop bound is ceil(kv_len/bs));
+    contiguous cache of the same length: the same program contracts
+    the same shapes, and the garbage adds exact zeros).  A poisoned
+    pool block therefore cannot leak into any slot's output through
+    either this gather path or the in-kernel block-table walk of
+    kernels/paged_attention, which never touches -1 entries at all
+    (its loop bound is ceil(kv_len/bs));
     tests/test_paged_kernel.py pins the no-leak behaviour on both.
     """
     bt = jnp.maximum(block_table, 0)

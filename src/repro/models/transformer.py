@@ -349,12 +349,16 @@ def decode_step(cfg, params, cache: Params, token: jax.Array,
     cache[:pos+1] after inserting its new k/v at `pos`.  With
     ``block_table`` the cache is a paged block pool and the read/write
     paths go through the table (attention.update_paged_cache /
-    gather_paged_cache); outputs are bit-identical to the contiguous
-    layout.  ``kernel=True`` reads through the fused Pallas block-table
-    kernel instead of materializing the gathered view (within
-    tolerance of it); ``quant`` (te/linear.
-    quantize_serving_params output) routes the linears through
-    pre-quantized fp8 weights.
+    gather_paged_cache); outputs are bit-identical to a contiguous
+    cache of the same length (the same attention program, and masked
+    positions add exact zeros).  Attention contracts on the MXU with
+    float32 accumulation (attention.decode_attention), so a program of
+    another shape (a chunk step) agrees with this one to the order of
+    its float32 sums, not bit for bit.  ``kernel=True`` reads through
+    the fused Pallas block-table kernel instead of materializing the
+    gathered view (within tolerance of it); ``quant``
+    (te/linear.quantize_serving_params output) routes the linears
+    through pre-quantized fp8 weights.
     """
     B = token.shape[0]
     x = params["embed"].astype(jnp.bfloat16)[token][:, None, :]  # [B,1,d]
@@ -438,8 +442,10 @@ def verify_step(cfg, params, cache: Params, tokens: jax.Array,
     (`update_cache` / `update_paged_cache` at [pos, pos+C)), same
     cache-aware causal read (`chunk_attention` over the gathered paged
     view) — so a server running it compiles exactly one extra program
-    and row j's prediction is bit-identical to what a one-token-at-a-
-    time decode of the same prefix would produce.  The caller accepts
+    and row j's prediction is what a one-token-at-a-time decode of the
+    same prefix would produce, up to the order of the attention's
+    float32 sums (the two programs contract different shapes).  The
+    caller accepts
     the longest draft prefix matching the argmax chain and rolls its
     frontier back over the rejected suffix: the rejected rows' KV
     writes land beyond the rolled-back frontier, where the position

@@ -56,7 +56,8 @@ table drafts up to K tokens per slot, one fixed-shape ``verify_step``
 dispatch — the same program shape as a prefill chunk — scores all
 B×(K+1) tokens, and the longest draft prefix matching the greedy
 argmax chain is accepted plus one bonus token.  Acceptance is exact
-for greedy decoding, so outputs stay bit-identical to ``K=0``; the
+for greedy decoding, so outputs are those of ``K=0`` (up to the
+verify and decode programs' fp32 summation order); the
 rejected suffix's cache writes are rolled back host-side by
 truncating the slot's block-table frontier.  One extra compiled
 program total: {chunk_step, decode_span, verify_step}.
@@ -112,9 +113,12 @@ prefill feeds one token per ``decode_step`` through a scan and
 recompiles per distinct prompt length; the decode loop syncs to the
 host every step.  `benchmarks/llm_gen.py` reports both.
 
-Both engines emit identical greedy token sequences: the chunked path's
-per-slot math (bf16 activations, fp32 softmax over the masked cache)
-matches the token-at-a-time decode path bit for bit.  Requests whose
+Both engines emit the same greedy token sequences wherever their
+logits agree: the chunked path's per-slot math (bf16 activations,
+MXU attention dots accumulating in fp32, fp32 softmax over the masked
+cache) is the token-at-a-time decode path's, and the two differ only
+in the order of the attention's fp32 sums (the CPU tests hold the
+tokens equal).  Requests whose
 ``in_len + max_new`` cannot fit below the ``max_len`` position cap are
 flagged ``truncated`` at admission (both engines) instead of silently
 coming back short.
@@ -266,7 +270,7 @@ class ChunkedServer:
       * verify step — with ``spec_decode=K``, decode-only stretches
         instead run one [slots, K+1] speculative window per dispatch:
         n-gram drafts verified against the model's own argmax chain
-        (bit-identical emissions, >= 1 token per slot per dispatch).
+        (the span loop's emissions, >= 1 token per slot per dispatch).
 
     The host mirrors position/emission bookkeeping in numpy — greedy
     decoding with length-only stopping is fully deterministic, so the

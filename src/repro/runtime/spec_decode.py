@@ -12,7 +12,9 @@ tokens per slot, ONE `verify_step` call (the same fixed program shape
 as a prefill chunk, models/transformer.py) scores all B×(K+1) tokens
 against the cache, and the server accepts the longest draft prefix
 that matches the model's own next-token chain — exact-parity rejection
-for greedy decoding, so ``spec_decode=K`` is bit-identical to ``K=0``.
+for greedy decoding, so ``spec_decode=K`` emits the tokens of ``K=0``
+wherever the verify and decode programs' logits agree (they differ
+only in the order of the attention's float32 sums).
 
 **Speculative sampling.** With per-request sampling on, acceptance is
 the standard speculative-sampling rule (Leviathan et al. 2023 /
@@ -56,9 +58,9 @@ Cache semantics: `verify_step` writes KV for ALL K+1 window rows at
 positions [pos, pos+K].  After acceptance the valid frontier is
 ``pos + n_emit``; the rejected suffix rows' writes sit beyond it,
 where the position masks of `chunk_attention`/`decode_attention`
-never read and the next window's writes land first — or, beyond the
-slot's allocated block-table entries, were dropped at scatter time
-(attention.update_paged_cache).  The server additionally rolls the
+give them exact-zero weight and the next window's writes land first —
+or, beyond the slot's allocated block-table entries, were dropped at
+scatter time (attention.update_paged_cache).  The server additionally rolls the
 slot's block-table frontier back host-side (ChunkedServer.
 _truncate_blocks) so over-allocated blocks return to the pool and the
 refcount/copy-on-write invariants of runtime/prefix_cache.py survive
@@ -188,10 +190,10 @@ def spec_decode_step(cfg, params, cache, table: jax.Array,
     the position-keyed sampled chain for sampled slots
     (``samp_temp``/``samp_top_k``/``samp_top_p``/``samp_seed``, all
     ``[B]``, greedy encoded as temp<=0 per models/sampling) — drafts
-    only decide how many rows of it are usable.  Hence bit-parity with
-    the K=0 span loop for greedy slots and exact-match-given-seed for
+    only decide how many rows of it are usable.  Hence the K=0 span
+    loop's tokens for greedy slots and exact-match-given-seed for
     sampled ones (the speculative-sampling argument in the module
-    docstring).
+    docstring), as far as the verify and decode programs' logits agree.
 
     Returns (cache, table, cur_tok', out_buf', pos', out_len',
     active', n_emit) with n_emit zeroed for inactive slots; the host

@@ -1,11 +1,14 @@
 """Chunked-prefill serving runtime: chunk math, output parity with the
 slot baseline, O(1) compilation, and scheduler behavior."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.jaxpr_check import iter_eqns
 from repro.configs import reduced_config
 from repro.models import api, transformer
 from repro.runtime.server import (ChunkedServer, Server, SlotServer,
@@ -25,8 +28,9 @@ def test_default_server_is_chunked():
 
 
 def test_chunk_step_matches_decode_path(setup):
-    """Chunked prefill must be bit-identical to the token-at-a-time
-    decode path (same bf16 activations, same cache contents)."""
+    """Chunked prefill matches the token-at-a-time decode path bit for
+    bit here on the CPU (same bf16 activations, same cache contents;
+    the attention dots' f32 sums round alike at these shapes)."""
     cfg, params = setup
     rng = np.random.default_rng(7)
     B, L, C = 2, 13, 4
@@ -317,3 +321,47 @@ def test_decode_span_serve_is_transfer_free(setup):
         stats = srv.serve(wave)
     assert all(r.done for r in wave)
     assert stats["decode_steps"] > 0
+
+
+@pytest.mark.parametrize("step", ["chunk", "decode"])
+def test_serving_attention_contracts_on_mxu(step):
+    """The gather-path attention of chunk_step and decode_step is two
+    dot_generals (scores and PV) on the bf16 operands as they are, at
+    Precision.HIGHEST with a float32 result — the MXU form — and no
+    float32 value of rank >= 6 (the [B,KH,G,C,T,hd] broadcast product
+    a multiply + jnp.sum form materializes) appears anywhere."""
+    cfg = dataclasses.replace(reduced_config("yi-6b"), num_heads=8,
+                              num_kv_heads=2)            # GQA, G=4
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    B, C, max_len, bs = 2, 4, 32, 8
+    T = max_len
+    cache = api.init_cache(cfg, B, max_len, paged=True, block_size=bs)
+    bt = jnp.arange(B * (max_len // bs), dtype=jnp.int32).reshape(B, -1)
+    pos = jnp.zeros((B,), jnp.int32)
+    if step == "chunk":
+        fn = lambda c: transformer.chunk_step(
+            cfg, params, c, jnp.zeros((B, C), jnp.int32), pos,
+            jnp.full((B,), C, jnp.int32), bt)
+    else:
+        fn = lambda c: transformer.decode_step(
+            cfg, params, c, jnp.zeros((B,), jnp.int32), pos, bt)
+    eqns = list(iter_eqns(jax.make_jaxpr(fn)(cache).jaxpr))
+
+    attn = [e for e in eqns if e.primitive.name == "dot_general"
+            and e.params["precision"] is not None
+            and jax.lax.Precision.HIGHEST in e.params["precision"]]
+    assert len(attn) == 2, [e.params for e in attn]
+    scores, pv = attn
+    for e in attn:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert e.params["preferred_element_type"] == jnp.float32
+        assert e.outvars[0].aval.dtype == jnp.float32
+        assert e.invars[1].aval.shape == (B, T, cfg.num_kv_heads,
+                                          cfg.head_dim)
+    assert scores.outvars[0].aval.shape[-1] == T
+    assert pv.outvars[0].aval.shape[-1] == cfg.head_dim
+
+    wide = [v.aval.str_short() for e in eqns for v in e.outvars
+            if getattr(v.aval, "ndim", 0) >= 6
+            and v.aval.dtype == jnp.float32]
+    assert not wide, wide
