@@ -41,8 +41,8 @@ class ModelConfig:
     # Norm / act / misc
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     activation: str = "swiglu"     # swiglu | gelu
-    use_bias: bool = False
-    qkv_bias: bool = False
+    use_bias: bool = False         # q/k/v projection biases
+    mlp_bias: bool = False         # MLP (and TE MLP linear) biases
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
     sliding_window: int = 0

@@ -23,6 +23,7 @@ CONFIG = ModelConfig(
     norm="layernorm",
     activation="gelu",
     use_bias=True,
+    mlp_bias=True,
     tie_embeddings=True,
     source="arXiv:2212.04356",
 )

@@ -21,7 +21,7 @@ def mlp_specs(cfg, d_model: Optional[int] = None,
     }
     if cfg.activation == "swiglu":
         specs["w_gate"] = ParamSpec((d, f), ("embed", "mlp"))
-    if cfg.use_bias:
+    if cfg.mlp_bias:
         specs["b_up"] = ParamSpec((f,), ("mlp",), init="zeros")
         specs["b_down"] = ParamSpec((d,), ("embed",), init="zeros")
     return specs
@@ -38,7 +38,7 @@ def mlp(cfg, p, x: jax.Array, *, groups: int = 0) -> jax.Array:
     """
     dt = x.dtype
     up = x @ p["w_up"].astype(dt)
-    if cfg.use_bias:
+    if cfg.mlp_bias:
         up = up + p["b_up"].astype(dt)
     gate = x @ p["w_gate"].astype(dt) if "w_gate" in p else None
     h = activation(cfg, up, gate)
@@ -52,7 +52,7 @@ def mlp(cfg, p, x: jax.Array, *, groups: int = 0) -> jax.Array:
         y = fixed_tree_sum(parts, tag="xshard_mlp_down").astype(dt)
     else:
         y = h @ p["w_down"].astype(dt)
-    if cfg.use_bias:
+    if cfg.mlp_bias:
         y = y + p["b_down"].astype(dt)
     return constrain(y, ("batch", "seq", "embed"))
 
@@ -65,14 +65,14 @@ def mlp_fp8(cfg, p, q8, x: jax.Array) -> jax.Array:
     from repro.te import linear as te_linear
     dt = x.dtype
     up = te_linear.fp8_serving_dot(x, q8["w_up"])
-    if cfg.use_bias:
+    if cfg.mlp_bias:
         up = up + p["b_up"].astype(dt)
     gate = te_linear.fp8_serving_dot(x, q8["w_gate"]) \
         if "w_gate" in q8 else None
     h = activation(cfg, up, gate)
     h = constrain(h, ("batch", None, "mlp"))
     y = te_linear.fp8_serving_dot(h, q8["w_down"])
-    if cfg.use_bias:
+    if cfg.mlp_bias:
         y = y + p["b_down"].astype(dt)
     return constrain(y, ("batch", "seq", "embed"))
 

@@ -52,7 +52,8 @@ class RequestRecord:
     __slots__ = ("rid", "t_enqueue", "t_seen", "seen_after", "t_admit",
                  "admit_step", "t_first_token", "first_token_step",
                  "t_done", "n_prompt", "n_out", "max_output",
-                 "cached_tokens", "truncated", "slot")
+                 "cached_tokens", "truncated", "slot", "prefill_steps",
+                 "prefill_s", "decode_steps", "decode_s")
 
     def __init__(self, rid: int):
         self.rid = rid
@@ -75,6 +76,16 @@ class RequestRecord:
         self.cached_tokens = 0
         self.truncated = False
         self.slot = -1
+        # the chunk-step dispatches that computed part of the prompt,
+        # and the sum of their walls (entry to block_until_ready)
+        self.prefill_steps = 0
+        self.prefill_s = 0.0
+        # the decode steps that emitted its tokens after the first (a
+        # decode piggybacked on a chunk step is one step at that step's
+        # wall; a span's wall is split evenly over its steps; a verify
+        # dispatch counts one step per token it emitted, at its wall)
+        self.decode_steps = 0
+        self.decode_s = 0.0
 
     # -- derived latencies (None until the defining events landed) ------
     @property
@@ -113,6 +124,10 @@ class RequestRecord:
                 "n_out": self.n_out, "max_output": self.max_output,
                 "cached_tokens": self.cached_tokens,
                 "truncated": self.truncated, "slot": self.slot,
+                "prefill_steps": self.prefill_steps,
+                "prefill_s": self.prefill_s,
+                "decode_steps": self.decode_steps,
+                "decode_s": self.decode_s,
                 "ttft_s": self.ttft_s, "tpot_s": self.tpot_s,
                 "queue_delay_s": self.queue_delay_s,
                 "e2e_s": self.e2e_s}
@@ -214,6 +229,18 @@ class Tracer:
             r.first_token_step = step
             self.events.append((t, "first_token", {"rid": rid}))
 
+    def prefill_dispatch(self, rid: int, wall_s: float) -> None:
+        """A chunk step of wall ``wall_s`` computed part of the prompt."""
+        r = self._rec(rid)
+        r.prefill_steps += 1
+        r.prefill_s += wall_s
+
+    def decode_dispatch(self, rid: int, steps: int, wall_s: float) -> None:
+        """``steps`` decode steps emitted tokens, at ``wall_s`` in all."""
+        r = self._rec(rid)
+        r.decode_steps += steps
+        r.decode_s += wall_s
+
     def finish(self, rid: int, n_out: int) -> None:
         t = self.clock()
         r = self._rec(rid)
@@ -270,6 +297,12 @@ class NullTracer:
         pass
 
     def first_token(self, rid: int, step: Optional[int] = None) -> None:
+        pass
+
+    def prefill_dispatch(self, rid: int, wall_s: float) -> None:
+        pass
+
+    def decode_dispatch(self, rid: int, steps: int, wall_s: float) -> None:
         pass
 
     def finish(self, rid: int, n_out: int) -> None:

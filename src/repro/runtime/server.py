@@ -1057,6 +1057,8 @@ class ChunkedServer:
                     prompt_tokens += n
                     self.prompt_off[s] += n
                     self.pos[s] += n
+                    if self.obs.enabled:
+                        self.obs.prefill_dispatch(req.rid, t1 - t0)
                     if emit[s]:                 # prompt exhausted: first token
                         self.mode[s] = "decode"
                         self.out_len[s] += 1
@@ -1069,6 +1071,8 @@ class ChunkedServer:
                 elif self.mode[s] == "decode":
                     self.out_len[s] += 1
                     self.pos[s] += 1
+                    if self.obs.enabled:
+                        self.obs.decode_dispatch(req.rid, 1, t1 - t0)
                     if toks is not None and int(toks[s]) == self.eos_id:
                         self._mark_done(s)
                     else:
@@ -1138,6 +1142,11 @@ class ChunkedServer:
                 self.obs.span("span_dispatch", t0, t1, steps=self.span,
                               n_active=int(active.sum()),
                               emitted=productive, step=self._step)
+                step_s = (t1 - t0) / self.span
+                for s in np.flatnonzero(self.out_len != prev_out):
+                    k = int(self.out_len[s] - prev_out[s])
+                    self.obs.decode_dispatch(self.slot_req[s].rid, k,
+                                             k * step_s)
             for s in np.flatnonzero(done_now):
                 self._mark_done(s)
 
@@ -1209,6 +1218,10 @@ class ChunkedServer:
                                            blocks=rolled)
             for s in np.flatnonzero(done_now):
                 self._mark_done(s)
+            if self.obs.enabled:
+                for s in np.flatnonzero(emit):
+                    self.obs.decode_dispatch(self.slot_req[s].rid,
+                                             int(emit[s]), t1 - t0)
             nact = int(active.sum())
             self.spec_steps += 1
             self.spec_slot_steps += nact

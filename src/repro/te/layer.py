@@ -32,8 +32,8 @@ def layernorm_mlp_specs(cfg) -> Params:
     d, f = cfg.d_model, cfg.d_ff
     specs = {
         "ln": norm_spec(cfg, d),
-        "up": te_linear_specs(d, f, bias=cfg.use_bias),
-        "down": te_linear_specs(f, d, bias=cfg.use_bias,
+        "up": te_linear_specs(d, f, bias=cfg.mlp_bias),
+        "down": te_linear_specs(f, d, bias=cfg.mlp_bias,
                                 axes=("mlp", "embed")),
     }
     if cfg.activation == "swiglu":

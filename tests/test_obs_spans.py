@@ -163,6 +163,60 @@ def test_traced_online_wave_is_transfer_free(setup):
         srv.serve_online(poisson_stream(clone_requests(reqs), rate=500.0,
                                         seed=2))
     assert len(tracer.requests) == len(reqs)
+    assert all(r.prefill_steps > 0 and r.decode_steps == r.n_out - 1
+               for r in tracer.request_records())
+
+
+@pytest.mark.parametrize("spec_k", [0, 3])
+def test_requests_record_their_prefill_and_decode_time(setup, spec_k):
+    """Each request's own share of the dispatches: the chunk steps that
+    computed its prompt and the decode steps that emitted its tokens,
+    against an independent count of the same rows."""
+    cfg, params = setup
+    reqs = sharegpt_like_requests(6, cfg.vocab_size, max_input=40,
+                                  max_output=12, seed=11)
+    tracer = Tracer()
+    srv = ChunkedServer(cfg, params, tracer=tracer, spec_decode=spec_k,
+                        **SRV_KW)
+    rows = {}                   # rid -> prompt tokens of each chunk step
+    chunk = srv._run_chunk_step
+
+    def counted():
+        for s, req in enumerate(srv.slot_req):
+            if req is not None and srv.mode[s] == "prefill":
+                off = int(srv.prompt_off[s])
+                rows.setdefault(req.rid, []).append(
+                    min(srv.chunk, len(req.prompt) - off))
+        return chunk()
+
+    srv._run_chunk_step = counted
+    srv.serve_online(poisson_stream(reqs, rate=200.0, seed=6))
+    recs = tracer.request_records()
+    assert len(recs) == len(reqs) and any(r.decode_steps > 1 for r in recs)
+    for r in recs:
+        assert r.cached_tokens == 0
+        assert sum(rows[r.rid]) == r.n_prompt
+        assert r.prefill_steps == len(rows[r.rid])
+        assert r.decode_steps == r.n_out - 1
+        assert 0 < r.prefill_s <= r.ttft_s - r.queue_delay_s + 1e-9
+        assert (r.decode_s > 0) == (r.decode_steps > 0)
+        assert r.prefill_s + r.decode_s <= r.e2e_s - r.queue_delay_s + 1e-9
+        d = r.to_dict()
+        for k in ("prefill_steps", "prefill_s", "decode_steps",
+                  "decode_s"):
+            assert d[k] == getattr(r, k)
+    assert sum(r.prefill_steps for r in recs) >= \
+        srv.metrics.counter_value("serving.dispatches.prefill")
+
+
+def test_null_tracer_keeps_no_step_shares(setup):
+    cfg, params = setup
+    assert NULL_TRACER.prefill_dispatch(0, 1.0) is None
+    assert NULL_TRACER.decode_dispatch(0, 2, 1.0) is None
+    srv = ChunkedServer(cfg, params, **SRV_KW)
+    srv.serve_online(closed_stream(sharegpt_like_requests(
+        3, cfg.vocab_size, max_input=16, max_output=6, seed=12)))
+    assert NULL_TRACER.requests == {} and NULL_TRACER.events == []
 
 
 def test_requests_record_when_they_were_seen(setup):

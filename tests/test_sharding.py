@@ -59,7 +59,8 @@ def test_default_plan_sp_for_tiny_batch_decode():
                                   "whisper-small"])
 def test_cache_pspecs_shapes_match(arch):
     """Every cache leaf gets a spec of matching rank; KV heads shard
-    only when divisible."""
+    only when divisible (CodeQwen1.5-7B's 4 KV heads, like Yi-6B's, do
+    not split 16 ways: its cache shards along the sequence instead)."""
     cfg = get_config(arch)
     shape = SHAPES["decode_32k"]
     plan = plans.default_plan(cfg, shape)
@@ -82,6 +83,12 @@ def test_cache_pspecs_shapes_match(arch):
                 continue
             size = 16 if isinstance(ax, str) else 16 ** len(ax)
             assert dim % size == 0, (arch, sds.shape, spec)
+    if isinstance(cache, dict) and "k" in cache:
+        # [layers, batch, seq, kv_heads, head_dim]
+        k = tuple(specs["k"]) + (None,) * 5
+        assert cache["k"].shape[3] == cfg.num_kv_heads
+        assert (k[3] == "model") == (cfg.num_kv_heads % 16 == 0), \
+            (arch, specs["k"])
 
 
 def test_resolve_with_dims():

@@ -91,20 +91,24 @@ def test_paged_kernels_compile_for_v5e(one_chip, kind, pool):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_serving_steps_fit_one_v5e(one_chip, monkeypatch):
-    """The server's chunk and span work units at full Yi-6B width with
-    bf16 weights: arguments + temporaries - donated aliases fit one
-    chip's HBM.  The server is built over shapes only: weights and the
-    KV pool are never allocated."""
+@pytest.mark.parametrize("arch,max_len", [("yi-6b", MAX_LEN),
+                                          ("codeqwen1.5-7b", 1536)])
+def test_serving_steps_fit_one_v5e(one_chip, monkeypatch, arch, max_len):
+    """The server's chunk and span work units at full width with bf16
+    weights, 8 slots: arguments + temporaries - donated aliases fit one
+    chip's HBM.  Yi-6B at chip_smoke.py's 8 x 2048; CodeQwen1.5-7B
+    (weights 13.5 GiB) at its benchmark cell's 8 x 1536.  The server is
+    built over shapes only: weights and the KV pool are never
+    allocated."""
     from repro.analysis.jaxpr_check import serving_programs
     from repro.runtime.server import ChunkedServer
-    cfg = get_config("yi-6b")
+    cfg = get_config(arch)
     params = jax.eval_shape(
         lambda: api.init(cfg, jax.random.PRNGKey(0), dtype=cfg.dtype))
     real_init_cache = api.init_cache
     monkeypatch.setattr(api, "init_cache", lambda *a, **k: jax.eval_shape(
         lambda: real_init_cache(*a, **k)))
-    srv = ChunkedServer(cfg, params, batch_slots=SLOTS, max_len=MAX_LEN,
+    srv = ChunkedServer(cfg, params, batch_slots=SLOTS, max_len=max_len,
                         chunk=CHUNK, block_size=BS)
     for name, _, jitted, ops in serving_programs(srv):
         mem = jitted.lower(*_on(one_chip, ops)).compile().memory_analysis()
