@@ -16,12 +16,17 @@ and stay inside the transfer-free span contract:
   spec-decode sampling exact-match-given-seed to the non-speculative
   sampled path (and K=0 vs K>0 distributions identical by
   construction).
-* **Always-present operands.** Greedy is encoded in the operand
-  *values* (``temperature=0`` / ``top_k=1``), not the program: the
-  sample head computes both the argmax token (on the original-dtype
-  logits, bit-identical to the historical greedy head) and the sampled
-  token, then selects with ``jnp.where``.  Flipping a request between
-  greedy and sampled therefore never recompiles (JX005).
+* **Always-present operands, work chosen per batch.** Greedy is
+  encoded in the operand *values* (``temperature=0`` / ``top_k=1``),
+  not the program, so flipping a request between greedy and sampled
+  never recompiles (JX005).  The head always computes the argmax token
+  on the original-dtype logits (bit-identical to the historical greedy
+  head); batch-level ``lax.cond`` branches, on whether any row
+  samples and whether any sampled row truncates to its top-k, skip
+  the vocabulary-wide work no row needs.  The serving steps pass the
+  rows whose tokens they discard (slots that emit nothing this step)
+  as greedy, so only the kept rows decide.  The nucleus comes from one
+  value sort, with no permutation gather or scatter (``_nucleus``).
 * **fp32 distribution.** The sampled distribution is always formed in
   float32 — logits are upcast before temperature scaling, the softmax
   runs in fp32, and the gumbel noise is fp32 — so bf16 serving samples
@@ -46,6 +51,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,45 +99,61 @@ class SamplingParams:
 GREEDY = SamplingParams()
 
 
-def _sample_row(logits, temp, top_k, top_p, seed, index):
-    """Sample one token from a single ``[V]`` logits row.
+def row_flags(temp, top_k, vocab):
+    """Which rows sample, and which of those truncate to their top-k.
 
-    ``index`` is the sequence position the token will occupy — the
-    sole per-draw RNG input besides the request seed (see module
-    docstring).  Returns int32.
+    The predicate of the head's batch-level branches.  It is written
+    with operators only, so the scheduler applies the same test to its
+    numpy mirrors (``batch_path``) that the program applies on device.
     """
-    vocab = logits.shape[-1]
-    # greedy token on the ORIGINAL dtype logits: bit-identical to the
-    # historical `jnp.argmax(logits, -1)` head when selected below
-    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled = (temp > 0) & (top_k != 1)
+    return sampled, sampled & (top_k > 0) & (top_k < vocab)
 
-    x = logits.astype(jnp.float32)
-    safe_t = jnp.where(temp > 0.0, temp, jnp.float32(1.0))
-    z = x / safe_t
-    # top-k: threshold at the k-th largest scaled logit (ties at the
-    # threshold all survive; top_k outside (0, V) disables the mask)
-    k_on = (top_k > 0) & (top_k < vocab)
-    sorted_z = jnp.sort(z)[::-1]
-    kth = sorted_z[jnp.clip(top_k - 1, 0, vocab - 1)]
-    keep_k = jnp.where(k_on, z >= kth, True)
-    z = jnp.where(keep_k, z, -jnp.inf)
-    # fp32 softmax of the temperature-scaled, top-k-masked distribution
-    probs = jax.nn.softmax(z)
-    # top-p: keep the smallest prefix of the probability-sorted vocab
-    # whose mass reaches top_p (the head of the nucleus always stays)
-    order = jnp.argsort(-probs)
-    csum = jnp.cumsum(probs[order])
-    keep_sorted = (csum - probs[order]) < top_p
-    keep_p = jnp.zeros((vocab,), bool).at[order].set(keep_sorted)
-    p_on = top_p < 1.0
-    z = jnp.where(p_on & ~keep_p, -jnp.inf, z)
-    # gumbel-max: argmax(z + g) is an exact draw from softmax(z) on
-    # the surviving support, keyed purely by (seed, position)
+
+def batch_path(sampled, k_on, live) -> str:
+    """The branch ``sample_tokens`` takes for a batch whose kept rows
+    are ``live`` (host numpy, over the batch's ``row_flags``)."""
+    if not (sampled & live).any():
+        return "greedy"
+    return "top_k" if (k_on & live).any() else "nucleus"
+
+
+def _top_k_mask(z, top_k, k_on):
+    """``-inf`` below each ``k_on`` row's k-th largest value; ties at
+    the k-th value all survive."""
+    vocab = z.shape[-1]
+    kth = jnp.take_along_axis(
+        jnp.sort(z, axis=-1, stable=False),
+        (vocab - 1 - jnp.clip(top_k - 1, 0, vocab - 1))[:, None], axis=-1)
+    return jnp.where(~k_on[:, None] | (z >= kth), z, -jnp.inf)
+
+
+def _nucleus(probs, top_p):
+    """The smallest prefix of the probability-sorted vocabulary whose
+    mass reaches ``top_p`` (the head always stays), as a ``[B, V]``
+    mask in vocabulary order.
+
+    The prefix is that of a stable ``argsort(-probs)``, found without
+    the permutation: with ``R`` kept ranks ending at value ``t``, it is
+    every entry above ``t`` and the first ``R - #(probs > t)`` entries
+    equal to ``t``, in vocabulary order.
+    """
+    vocab = probs.shape[-1]
+    desc = -jnp.sort(-probs, axis=-1, stable=False)
+    csum = jnp.cumsum(desc, axis=-1)
+    keep_sorted = (csum - desc) < top_p[:, None]
+    run = jnp.where(keep_sorted.all(axis=-1), vocab,
+                    jnp.argmin(keep_sorted, axis=-1))[:, None]
+    t = jnp.take_along_axis(desc, run - 1, axis=-1)
+    above = probs > t
+    tie = probs == t
+    tie_rank = jnp.cumsum(tie, axis=-1, dtype=jnp.int32) - tie
+    return above | (tie & (tie_rank < run - above.sum(-1, keepdims=True)))
+
+
+def _gumbel(seed, index, vocab):
     key = jax.random.fold_in(jax.random.PRNGKey(seed), index)
-    g = jax.random.gumbel(key, (vocab,), jnp.float32)
-    sampled = jnp.argmax(z + g).astype(jnp.int32)
-    return jnp.where((temp <= jnp.float32(0.0)) | (top_k == 1),
-                     greedy_tok, sampled)
+    return jax.random.gumbel(key, (vocab,), jnp.float32)
 
 
 def sample_tokens(logits, temp, top_k, top_p, seed, index):
@@ -141,14 +163,42 @@ def sample_tokens(logits, temp, top_k, top_p, seed, index):
     (verify head); ``temp``/``top_p`` are f32 ``[B]``,
     ``top_k``/``seed`` int32 ``[B]``; ``index`` holds the emission
     positions, shaped ``[B]`` or ``[B, C]`` to match.  Returns int32
-    tokens shaped like ``index``.
+    tokens shaped like ``index``.  Every sampled row decides the work
+    of the batch, so a caller that discards some rows' tokens passes
+    them as greedy (``temp`` 0): see ``batch_path``.
     """
-    if logits.ndim == 2:
-        return jax.vmap(_sample_row)(logits, temp, top_k, top_p, seed,
-                                     index)
-    row = jax.vmap(_sample_row,
-                   in_axes=(0, None, None, None, None, 0))
-    return jax.vmap(row)(logits, temp, top_k, top_p, seed, index)
+    if logits.ndim == 3:
+        B, C, V = logits.shape
+        rep = lambda a: jnp.repeat(a, C)  # noqa: E731
+        return sample_tokens(
+            logits.reshape(B * C, V), rep(temp), rep(top_k), rep(top_p),
+            rep(seed), index.reshape(B * C)).reshape(B, C)
+    vocab = logits.shape[-1]
+    # greedy token on the ORIGINAL dtype logits: bit-identical to the
+    # historical `jnp.argmax(logits, -1)` head
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled, k_on = row_flags(temp, top_k, vocab)
+
+    def draw():
+        z = logits.astype(jnp.float32) / jnp.where(
+            temp > 0.0, temp, jnp.float32(1.0))[:, None]
+        z = lax.cond(jnp.any(k_on),
+                     lambda z: _top_k_mask(z, top_k, k_on),
+                     lambda z: z, z)
+        # fp32 softmax of the temperature-scaled, top-k-masked
+        # distribution; top-p masks outside its nucleus
+        keep_p = _nucleus(jax.nn.softmax(z, axis=-1), top_p)
+        z = jnp.where((top_p < 1.0)[:, None] & ~keep_p, -jnp.inf, z)
+        # gumbel-max: argmax(z + g) is an exact draw from softmax(z) on
+        # the surviving support, keyed purely by (seed, position)
+        g = jax.vmap(_gumbel, in_axes=(0, 0, None))(seed, index, vocab)
+        tok = jnp.argmax(z + g, axis=-1).astype(jnp.int32)
+        return jnp.where(sampled, tok, greedy_tok)
+
+    # batch-level branches, outside any vmap (under vmap a cond becomes
+    # a select that runs both sides): an all-greedy batch does no
+    # vocabulary-wide work beyond its argmax
+    return lax.cond(jnp.any(sampled), draw, lambda: greedy_tok)
 
 
 def ks_two_sample(a, b):

@@ -304,6 +304,10 @@ class ChunkedServer:
     ``serving.host_s.<phase>`` (entry to the jitted call's return) and
     ``serving.wait_s.<phase>`` (that return to ``block_until_ready``),
     and ``serving.gap_s`` holds the host time between dispatches.
+    ``serving.sampling.path.{greedy,nucleus,top_k}`` count each chunk
+    step and each step of a span by the branch its sample head takes
+    (``sampling.batch_path`` over the mirrors; a span's live rows are
+    its mirror simulation's, which EOS can only narrow on device).
     """
 
     def __init__(self, cfg: ModelConfig, params: Params, *,
@@ -591,10 +595,12 @@ class ChunkedServer:
             # the emitted token will sit at sequence position
             # pos + n_tokens — the position key that makes this draw
             # identical to the span/verify paths' draw for the same
-            # position (models/sampling, greedy when temp<=0)
-            nxt = sampling.sample_tokens(logits, samp_temp, samp_top_k,
-                                         samp_top_p, samp_seed,
-                                         pos + n_tokens)
+            # position (models/sampling, greedy when temp<=0); rows
+            # that emit nothing pass as greedy, so that only the
+            # emitting rows choose the head's work
+            nxt = sampling.sample_tokens(
+                logits, jnp.where(emit, samp_temp, 0.0), samp_top_k,
+                samp_top_p, samp_seed, pos + n_tokens)
             cur_tok = jnp.where(emit, nxt, cur_tok)
             row = jnp.arange(B)
             idx = jnp.clip(out_len, 0, out_buf.shape[1] - 1)
@@ -625,9 +631,11 @@ class ChunkedServer:
                 **self._fwd_kw())
             # emission position pos + 1 (pre-increment), matching the
             # chunk path's pos + n_tokens and verify row j's
-            # pos + 1 + j — same (seed, position) -> same draw
-            nxt = sampling.sample_tokens(logits, samp_temp, samp_top_k,
-                                         samp_top_p, samp_seed, pos + 1)
+            # pos + 1 + j — same (seed, position) -> same draw; idle
+            # rows pass as greedy, as in the chunk step
+            nxt = sampling.sample_tokens(
+                logits, jnp.where(active, samp_temp, 0.0), samp_top_k,
+                samp_top_p, samp_seed, pos + 1)
             idx = jnp.clip(out_len, 0, out_buf.shape[1] - 1)
             out_buf = out_buf.at[row, idx].set(
                 jnp.where(active, nxt, out_buf[row, idx]))
@@ -988,6 +996,15 @@ class ChunkedServer:
             if req is not None:
                 self.obs.finish(req.rid, int(self.out_len[s]))
 
+    def _count_sampling_paths(self, lives: List[np.ndarray]) -> None:
+        """One ``serving.sampling.path.*`` count per step, by the branch
+        the sample head takes for the step's live rows."""
+        flags = sampling.row_flags(self.samp_temp, self.samp_top_k,
+                                   self.cfg.vocab_size)
+        for live in lives:
+            self.metrics.counter("serving.sampling.path."
+                                 + sampling.batch_path(*flags, live)).inc()
+
     def _run_chunk_step(self) -> int:
         """One packed step: prefill chunks + piggybacked decodes."""
         t0 = time.perf_counter()
@@ -1014,6 +1031,7 @@ class ChunkedServer:
                     emit[s] = True
                     if self.paged:
                         self._ensure_blocks(s, int(self.pos[s]) + 1)
+            self._count_sampling_paths([emit])
         with self.obs.phase("serve.chunk.put"):
             ops = (self._put(tokens_host), self._put(self.pos.copy()),
                    self._put(n_tokens), self._put(is_decode),
@@ -1095,12 +1113,15 @@ class ChunkedServer:
             sim_pos = self.pos.copy()
             sim_out = self.out_len.copy()
             sim_act = active.copy()
+            lives = []
             for _ in range(self.span):
+                lives.append(sim_act.copy())
                 for s in np.flatnonzero(sim_act):
                     sim_out[s] += 1
                     sim_pos[s] += 1
                     if (sim_out[s] >= max_new[s] or sim_pos[s] >= cap):
                         sim_act[s] = False
+            self._count_sampling_paths(lives)
             if self.paged:
                 for s in np.flatnonzero(active):
                     self._ensure_blocks(s, int(sim_pos[s]))

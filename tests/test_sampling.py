@@ -128,6 +128,123 @@ def test_gumbel_max_matches_categorical_distribution():
     assert pval > 0.01, (d, pval)
 
 
+# ----------------------------------------------------------------------
+# the head against the per-row head it replaced
+# ----------------------------------------------------------------------
+
+def _reference_row(logits, temp, top_k, top_p, seed, index):
+    """The head before batch-level branching: every row computes the
+    top-k sort, the nucleus by argsort, gather and scatter, and the
+    gumbel draw, then keeps the argmax where it is greedy."""
+    vocab = logits.shape[-1]
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    x = logits.astype(jnp.float32)
+    safe_t = jnp.where(temp > 0.0, temp, jnp.float32(1.0))
+    z = x / safe_t
+    k_on = (top_k > 0) & (top_k < vocab)
+    sorted_z = jnp.sort(z)[::-1]
+    kth = sorted_z[jnp.clip(top_k - 1, 0, vocab - 1)]
+    keep_k = jnp.where(k_on, z >= kth, True)
+    z = jnp.where(keep_k, z, -jnp.inf)
+    probs = jax.nn.softmax(z)
+    order = jnp.argsort(-probs)
+    csum = jnp.cumsum(probs[order])
+    keep_sorted = (csum - probs[order]) < top_p
+    keep_p = jnp.zeros((vocab,), bool).at[order].set(keep_sorted)
+    z = jnp.where((top_p < 1.0) & ~keep_p, -jnp.inf, z)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), index)
+    g = jax.random.gumbel(key, (vocab,), jnp.float32)
+    sampled = jnp.argmax(z + g).astype(jnp.int32)
+    return jnp.where((temp <= 0.0) | (top_k == 1), greedy_tok, sampled)
+
+
+@jax.jit
+def _reference_head(logits, temp, top_k, top_p, seed, index):
+    if logits.ndim == 2:
+        return jax.vmap(_reference_row)(logits, temp, top_k, top_p, seed,
+                                        index)
+    row = jax.vmap(_reference_row, in_axes=(0, None, None, None, None, 0))
+    return jax.vmap(row)(logits, temp, top_k, top_p, seed, index)
+
+
+HEAD_B, HEAD_V, HEAD_C = 24, 97, 3
+TEMPS = (0.5, 0.7, 1.0, 1.3)
+TOP_KS = (0, 1, 5, 50)
+TOP_PS = (0.5, 0.9, 0.95, 1.0)
+HEAD_BATCHES = ("all_greedy", "top_p_no_top_k", "mixed", "live_greedy",
+                "live_top_p")
+
+
+def _head_logits(rng, lead):
+    """Three families of rows: continuous logits; logits on a coarse
+    grid, so the k-th value and the nucleus boundary fall inside runs
+    of exact ties; and probabilities 0.4, 0.2, 0.2, 0.2 over a far
+    tail, whose 0.5-nucleus ends inside the tie."""
+    x = 3.0 * rng.normal(size=lead + (HEAD_V,))
+    grid = np.round(2.0 * rng.normal(size=x.shape)) / 2.0
+    x = np.where((np.arange(HEAD_B) % 3 == 1).reshape(
+        (-1,) + (1,) * (len(lead))), grid, x)
+    tied = np.full(HEAD_V, -30.0)
+    tied[[7, 3, 50, 11]] = np.log([0.4, 0.2, 0.2, 0.2])
+    x[2::3] = np.where(rng.random(x[2::3].shape[:-1] + (1,)) < 0.5,
+                       tied, x[2::3])
+    return x
+
+
+def _head_batch(batch, rng):
+    """Per-row sampling operands and the live mask of one batch kind."""
+    B = HEAD_B
+    temp = rng.choice(TEMPS, B)
+    top_k = rng.choice(TOP_KS, B)
+    top_p = rng.choice(TOP_PS, B)
+    live = rng.random(B) < 0.6
+    greedy = rng.random(B) < 0.3
+    if batch == "all_greedy":
+        temp = np.where(rng.random(B) < 0.5, 0.0, temp)
+        top_k = np.where(temp > 0, 1, top_k)
+        live[:] = True
+    elif batch == "top_p_no_top_k":
+        top_k[:] = 0
+        temp = np.where(greedy, 0.0, temp)
+        live[:] = True
+    elif batch == "mixed":
+        temp = np.where(greedy, 0.0, temp)
+        live[:] = True
+    elif batch == "live_greedy":
+        # the live rows are greedy, the others sample with top-k
+        temp = np.where(live, 0.0, temp)
+        top_k = np.where(live, top_k, 5)
+    elif batch == "live_top_p":
+        # top-k only on rows whose tokens are thrown away
+        top_k = np.where(live, 0, 5)
+        temp = np.where(live & greedy, 0.0, temp)
+    return (temp.astype(np.float32), top_k.astype(np.int32),
+            top_p.astype(np.float32), live)
+
+
+@pytest.mark.parametrize("shape", ["rows", "verify"])
+@pytest.mark.parametrize("batch", HEAD_BATCHES)
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_head_matches_per_row_reference(dtype, batch, shape):
+    """The batch-level head gives the reference head's token on every
+    kept row: greedy rows bit for bit, sampled rows given the seed."""
+    rng = np.random.default_rng([HEAD_BATCHES.index(batch),
+                                 int(shape == "verify"),
+                                 int(dtype == "float32")])
+    lead = (HEAD_B,) if shape == "rows" else (HEAD_B, HEAD_C)
+    logits = jnp.asarray(_head_logits(rng, lead), jnp.dtype(dtype))
+    temp, top_k, top_p, live = _head_batch(batch, rng)
+    seed = rng.integers(0, 2**31 - 1, HEAD_B).astype(np.int32)
+    index = rng.integers(0, 4096, lead).astype(np.int32)
+    ops = tuple(map(jnp.asarray, (temp, top_k, top_p, seed, index)))
+    want = np.asarray(_reference_head(logits, *ops))
+    # the serving steps pass the rows whose tokens they discard as
+    # greedy
+    kept = jnp.asarray(np.where(live, temp, 0.0).astype(np.float32))
+    got = np.asarray(jax.jit(sample_tokens)(logits, kept, *ops[1:]))
+    assert np.array_equal(got[live], want[live])
+
+
 def test_ks_two_sample_sanity():
     same = np.arange(500) % 7
     d, p = ks_two_sample(same, same)
@@ -289,3 +406,37 @@ def test_greedy_sampled_flips_add_zero_programs(setup):
     wave(lambda i: SamplingParams(temperature=1.2, seed=77 + i))
     assert g2 == g1                     # greedy unchanged by traffic
     assert prog_counts() == counts
+
+
+def test_sampling_path_counter(setup):
+    """``serving.sampling.path.*`` counts every chunk step and span step
+    once, by the head's branch: all greedy, then one top-p request
+    among greedy ones, on one server whose programs do not grow."""
+    cfg, params = setup
+    reqs = _mixes(cfg)["sharegpt"]
+    srv = ChunkedServer(cfg, params, **BASE_KW)
+
+    def paths(per_req=lambda i: None):
+        rs = clone_requests(reqs)
+        for i, r in enumerate(rs):
+            r.sampling = per_req(i)
+        srv.serve(rs)
+        m = srv.metrics
+        steps = (m.counter_value("serving.dispatches.prefill")
+                 + srv.span * m.counter_value("serving.dispatches.span"))
+        got = {p: m.counter_value("serving.sampling.path." + p)
+               for p in ("greedy", "nucleus", "top_k")}
+        assert sum(got.values()) == steps
+        return got, steps
+
+    def programs():
+        counts = srv.compile_counts()
+        return counts["chunk_step"], counts["decode_span"]
+
+    got, steps = paths()
+    assert got == {"greedy": steps, "nucleus": 0, "top_k": 0}
+    before = programs()
+    got, _ = paths(lambda i: SamplingParams(temperature=0.8, top_p=0.9,
+                                            seed=3) if i == 2 else None)
+    assert got["nucleus"] > 0 and got["greedy"] > 0 and got["top_k"] == 0
+    assert programs() == before == (1, 1)

@@ -117,6 +117,37 @@ def test_serving_steps_fit_one_v5e(one_chip, monkeypatch, arch, max_len):
         assert need < hw.TPU_V5E.hbm_bytes, (name, need)
 
 
+def test_sample_head_compiles_without_vocab_gather_or_scatter(one_chip):
+    """The sample head at Yi-6B's 16 slots x 64000 in bf16: a batch-level
+    conditional, at most two vocabulary sorts (top-k, nucleus), and no
+    vocabulary-sized gather or scatter (the nucleus is found without
+    the permutation)."""
+    import re
+    from repro.models import sampling
+    B, V = 16, get_config("yi-6b").vocab_size
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    f32, i32 = (sds((B,), d) for d in (jnp.float32, jnp.int32))
+    hlo = jax.jit(sampling.sample_tokens).lower(
+        sds((B, V), jnp.bfloat16), f32, i32, f32, i32, i32
+    ).compile().as_text()
+
+    def ops(name):
+        """Output shapes of every ``name`` instruction."""
+        return [m.group(1) for m in re.finditer(
+            r"= \(?([^=]*?)\)? %s\(" % name, hlo)]
+
+    def elements(shape):
+        return max((int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"\[([\d,]*)\]", shape)),
+                   default=1)
+
+    assert ops("conditional")
+    assert not ops("scatter")
+    assert all(elements(s) < V for s in ops("gather")), ops("gather")
+    assert 1 <= sum(elements(s) >= V for s in ops("sort")) <= 2
+
+
 def test_chip_smoke_phases_on_cpu():
     """chip_smoke.py's serving phases end to end at reduced size on the
     CPU (its platform check lives in main(), which this skips)."""
