@@ -34,7 +34,6 @@ HOT_PATH_ROOTS = [
     ("repro.runtime.server", "ChunkedServer._chunk_impl"),
     ("repro.runtime.server", "ChunkedServer._span_impl"),
     ("repro.runtime.server", "ChunkedServer._spec_impl"),
-    ("repro.runtime.server", "SlotServer._prefill_impl"),
 ]
 
 # function bodies that must phrase their contractions as explicit
@@ -69,18 +68,16 @@ def serving_combos(device_count: int = 1,
                    max_combos: Optional[int] = None
                    ) -> List[Dict[str, Any]]:
     """Valid ChunkedServer flag combos, honoring the constructor's own
-    constraints (kernel/fp8_kv need paged; fp8_linear is tp=1 dense;
-    spec_decode < chunk off-paged; tp needs devices).  Paired-down but
-    covering every flag both ways and the interesting interactions."""
+    constraints (fp8_linear is tp=1 dense; tp needs devices).
+    Paired-down but covering every flag both ways and the interesting
+    interactions."""
     from repro.models.sampling import SamplingParams
     sampled = SamplingParams(temperature=0.8, top_k=40, top_p=0.95,
                              seed=7)
     combos: List[Dict[str, Any]] = [
-        {},                                         # paged + prefix (defaults)
+        {},                                         # defaults
         {"prefix_cache": False},
-        {"paged": False, "prefix_cache": False},
         {"spec_decode": 3},
-        {"paged": False, "prefix_cache": False, "spec_decode": 3},
         {"eos_id": 5},
         {"spec_decode": 3, "eos_id": 5},
         {"kernel": True},
@@ -111,7 +108,7 @@ def serving_combos(device_count: int = 1,
 
 
 def combo_label(combo: Dict[str, Any]) -> str:
-    base = {"paged": True, "prefix_cache": True, "spec_decode": 0,
+    base = {"prefix_cache": True, "spec_decode": 0,
             "kernel": False, "fp8_kv": False, "fp8_linear": False,
             "tp": 1, "eos_id": None, "sampling": None}
     base.update(combo)
@@ -126,10 +123,9 @@ def combo_label(combo: Dict[str, Any]) -> str:
 
 def signature_class(combo: Dict[str, Any]) -> str:
     """Combos agreeing on this key MUST produce identical abstract
-    signatures per program (JX005): only the cache layout (paged) and
-    its dtype (fp8_kv) may change operand shapes/dtypes."""
-    return (f"paged={int(combo.get('paged', True))},"
-            f"fp8_kv={int(combo.get('fp8_kv', False))}")
+    signatures per program (JX005): only the cache dtype (fp8_kv) may
+    change operand shapes/dtypes."""
+    return f"fp8_kv={int(combo.get('fp8_kv', False))}"
 
 
 def iter_pairs(items):

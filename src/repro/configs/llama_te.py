@@ -2,7 +2,7 @@
 
 hidden sizes {1024, 2048, 4096, 5120, 8192} with the paper's
 ffn_hidden_size and head counts; SwiGLU + RMSNorm per §III-C-2.
-Used by benchmarks/te_layer.py and benchmarks/llm_gen.py.
+Used by benchmarks/te_layer.py and examples/serve_llm.py.
 """
 
 from repro.configs.base import ModelConfig

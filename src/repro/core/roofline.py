@@ -247,8 +247,8 @@ def paged_decode_kv_bytes(kv_len: int, *, block_size: int,
     them).
 
     The factor-of-3 gather overhead and the valid-block-only kernel
-    traffic are what BENCH_serving.json's `modeled_decode_speedup`
-    reports; tests/test_roofline.py pins the ratios.
+    traffic are what `paged_decode_speedup` compares;
+    tests/test_roofline.py pins the ratios.
     """
     row = kv_heads * head_dim
     if mode == "gather":

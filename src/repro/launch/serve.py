@@ -52,8 +52,7 @@ from repro.obs import (SLOSpec, Tracer, gap_summary, phase_summary,
                        slo_report, summary_table, window_series,
                        window_summary, write_chrome_trace, write_jsonl)
 from repro.runtime.arrivals import poisson_stream
-from repro.runtime.server import (ChunkedServer, SlotServer,
-                                  repetitive_requests,
+from repro.runtime.server import (ChunkedServer, repetitive_requests,
                                   sharegpt_like_requests,
                                   sysprompt_sharegpt_requests)
 from repro.sharding import plans
@@ -66,20 +65,12 @@ def main() -> None:
                     help="serve the same-family reduced config (2 "
                          "layers, d_model 64, vocab 256) instead of the "
                          "published one — for CPU runs")
-    ap.add_argument("--engine", default="chunked",
-                    choices=("chunked", "slot"),
-                    help="chunked-prefill scheduler (default) or the "
-                         "legacy slot baseline")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--chunk", type=int, default=16,
-                    help="prefill chunk size (chunked engine)")
+                    help="prefill chunk size")
     ap.add_argument("--span", type=int, default=8,
-                    help="device-resident decode steps per dispatch "
-                         "(chunked engine)")
-    ap.add_argument("--contiguous", action="store_true",
-                    help="use the contiguous per-slot KV layout instead "
-                         "of the paged block pool (chunked engine)")
+                    help="device-resident decode steps per dispatch")
     ap.add_argument("--block-size", type=int, default=16,
                     help="KV tokens per paged-cache block")
     ap.add_argument("--pool-blocks", type=int, default=None,
@@ -91,10 +82,10 @@ def main() -> None:
                          "bit-identical either way)")
     ap.add_argument("--eos-id", type=int, default=None,
                     help="stop a request when it emits this token id "
-                         "(device-side, both engines); default: "
+                         "(device-side); default: "
                          "length-only stopping")
     ap.add_argument("--spec-decode", type=int, default=0, metavar="K",
-                    help="speculative decoding (chunked engine): draft "
+                    help="speculative decoding: draft "
                          "up to K tokens per slot from a device-"
                          "resident n-gram suffix table and verify all "
                          "of them in one fixed-shape dispatch, "
@@ -108,8 +99,7 @@ def main() -> None:
                     help="read paged KV through the fused Pallas "
                          "block-table kernels (kernels/paged_attention"
                          ") instead of the gather path — outputs "
-                         "agree within the kernel tests' tolerance "
-                         "(chunked engine, paged pool)")
+                         "agree within the kernel tests' tolerance")
     ap.add_argument("--fp8-kv", action="store_true",
                     help="store the paged KV pool as fp8 e4m3 codes + "
                          "per-row f32 scales (~0.53x pool bytes at "
@@ -119,7 +109,7 @@ def main() -> None:
                          "fp8-quantized weights (te/linear; tp=1, "
                          "dense only)")
     ap.add_argument("--tp", type=int, default=1, metavar="N",
-                    help="tensor-parallel degree (chunked engine): "
+                    help="tensor-parallel degree: "
                          "shard the weights head-wise/column-row-wise "
                          "and the paged KV pool along its KV-head axis "
                          "over an N-device mesh "
@@ -145,7 +135,7 @@ def main() -> None:
                     metavar="RPS",
                     help="serve open-loop: Poisson request arrivals at "
                          "RPS req/s against the monotonic clock "
-                         "(chunked engine; queue delay and TTFT are "
+                         "(queue delay and TTFT are "
                          "charged from arrival).  Default: closed "
                          "batch, all requests at t=0")
     ap.add_argument("--duration", type=float, default=None, metavar="S",
@@ -163,8 +153,7 @@ def main() -> None:
                     help="record per-request lifecycle events + "
                          "dispatch timings (repro.obs) and print the "
                          "latency/phase/occupancy summary table; "
-                         "host-side only, outputs stay bit-identical "
-                         "(chunked engine)")
+                         "host-side only, outputs stay bit-identical")
     ap.add_argument("--trace-out", metavar="PREFIX", default=None,
                     help="with --trace, also write PREFIX.jsonl "
                          "(structured events) and PREFIX.trace.json "
@@ -178,9 +167,6 @@ def main() -> None:
     if args.duration is not None and args.arrival_rate is None:
         raise SystemExit("--duration needs --arrival-rate (it sizes "
                          "the open-loop stream)")
-    if args.arrival_rate is not None and args.engine != "chunked":
-        raise SystemExit("--arrival-rate needs the chunked engine "
-                         "(the slot baseline has no open-loop path)")
     if args.slo_ttft_ms is not None:
         args.trace = True   # attainment is scored off the tracer
     if args.duration is not None:
@@ -190,7 +176,7 @@ def main() -> None:
     cfg = (reduced_config if args.reduced else get_config)(args.arch)
     if cfg.family not in ("dense", "moe", "vlm"):
         raise SystemExit(
-            f"{args.arch} ({cfg.family}): the serving engines currently "
+            f"{args.arch} ({cfg.family}): the serving engine currently "
             "drive the transformer decode path; SSM/hybrid/enc-dec "
             "decode is exercised via api.decode_step (see tests).")
     # serving weights: cfg.dtype, built on the device, and under a tp
@@ -201,36 +187,17 @@ def main() -> None:
         shardings=(plans.serving_plan(mesh).param_shardings(cfg)
                    if mesh is not None else None))
     max_len = args.max_input + args.max_output + 8
-    tracer = None
-    if args.trace:
-        if args.engine != "chunked":
-            raise SystemExit("--trace needs the chunked engine (the "
-                             "slot baseline is not instrumented)")
-        tracer = Tracer()
-    if args.engine == "chunked":
-        srv = ChunkedServer(cfg, params, batch_slots=args.slots,
-                            max_len=max_len, chunk=args.chunk,
-                            span=args.span, paged=not args.contiguous,
-                            block_size=args.block_size,
-                            num_blocks=args.pool_blocks,
-                            prefix_cache=not args.no_prefix_cache,
-                            eos_id=args.eos_id,
-                            spec_decode=args.spec_decode,
-                            kernel=args.kernel, fp8_kv=args.fp8_kv,
-                            fp8_linear=args.fp8_linear,
-                            mesh=mesh, tracer=tracer)
-    else:
-        if args.spec_decode:
-            raise SystemExit("--spec-decode needs the chunked engine "
-                             "(the slot baseline has no verify path)")
-        if args.tp > 1:
-            raise SystemExit("--tp needs the chunked engine (the slot "
-                             "baseline is single-device)")
-        if args.kernel or args.fp8_kv or args.fp8_linear:
-            raise SystemExit("--kernel/--fp8-kv/--fp8-linear need the "
-                             "chunked engine's paged pool")
-        srv = SlotServer(cfg, params, batch_slots=args.slots,
-                         max_len=max_len, eos_id=args.eos_id)
+    tracer = Tracer() if args.trace else None
+    srv = ChunkedServer(cfg, params, batch_slots=args.slots,
+                        max_len=max_len, chunk=args.chunk,
+                        span=args.span, block_size=args.block_size,
+                        num_blocks=args.pool_blocks,
+                        prefix_cache=not args.no_prefix_cache,
+                        eos_id=args.eos_id,
+                        spec_decode=args.spec_decode,
+                        kernel=args.kernel, fp8_kv=args.fp8_kv,
+                        fp8_linear=args.fp8_linear,
+                        mesh=mesh, tracer=tracer)
     if args.tp > 1:
         import jax.tree_util as jtu
         param_bytes = sum(x.nbytes for x in jtu.tree_leaves(srv.params))
@@ -277,8 +244,7 @@ def main() -> None:
         stats = srv.serve_online(stream)
     else:
         stats = srv.serve(reqs)
-    print(f"arch={args.arch} engine={args.engine} "
-          f"workload={args.workload} "
+    print(f"arch={args.arch} workload={args.workload} "
           f"requests={int(stats['requests'])} "
           f"tokens={int(stats['tokens'])} "
           f"throughput={stats['tokens_per_s']:.1f} tok/s "
@@ -292,34 +258,25 @@ def main() -> None:
     counts = srv.compile_counts()
     per_program = " ".join(f"{name}={max(n, 0)}"
                            for name, n in sorted(counts.items()))
-    if args.engine == "chunked":
-        # per-phase dispatch counts + wall-time breakdown from the
-        # metrics registry the dispatch methods feed (repro.obs) —
-        # live with or without --trace
-        phases = phase_summary(srv.metrics)
-        gap = gap_summary(srv.metrics)
-        breakdown = " ".join(
-            f"{name}={d['wall_s']:.2f}s/{d['dispatches']}d"
-            f"(host={d['host_s']:.2f}s,wait={d['wait_s']:.2f}s)"
-            for name, d in phases.items() if d["dispatches"])
-        print(f"  phases: {breakdown} gap={gap['gap_s']:.2f}s "
-              f"compiled_programs="
-              f"{sum(max(v, 0) for v in counts.values())} "
-              f"({per_program})")
-    else:
-        print(f"  prefill={stats['prefill_seconds']:.2f}s "
-              f"decode={stats['decode_seconds']:.2f}s "
-              f"compiled_programs="
-              f"{sum(max(v, 0) for v in counts.values())} "
-              f"({per_program})")
-    if "pool_blocks" in stats:
-        print(f"  paged-kv: {int(stats['peak_blocks_in_use'])}/"
-              f"{int(stats['pool_blocks'])} blocks peak "
-              f"(x{int(stats['block_size'])} tokens, "
-              f"utilization={stats['pool_utilization']:.2f}, "
-              f"stalls={int(stats['admission_stalls'])}, "
-              f"capacity {int(stats['kv_tokens_capacity'])} vs "
-              f"{int(stats['kv_tokens_contiguous'])} contiguous tokens)")
+    # per-phase dispatch counts + wall-time breakdown from the metrics
+    # registry the dispatch methods feed (repro.obs) — live with or
+    # without --trace
+    phases = phase_summary(srv.metrics)
+    gap = gap_summary(srv.metrics)
+    breakdown = " ".join(
+        f"{name}={d['wall_s']:.2f}s/{d['dispatches']}d"
+        f"(host={d['host_s']:.2f}s,wait={d['wait_s']:.2f}s)"
+        for name, d in phases.items() if d["dispatches"])
+    print(f"  phases: {breakdown} gap={gap['gap_s']:.2f}s "
+          f"compiled_programs="
+          f"{sum(max(v, 0) for v in counts.values())} "
+          f"({per_program})")
+    print(f"  paged-kv: {int(stats['peak_blocks_in_use'])}/"
+          f"{int(stats['pool_blocks'])} blocks peak "
+          f"(x{int(stats['block_size'])} tokens, "
+          f"utilization={stats['pool_utilization']:.2f}, "
+          f"stalls={int(stats['admission_stalls'])}, "
+          f"capacity {int(stats['kv_tokens_capacity'])} tokens)")
     if "spec_k" in stats:
         print(f"  spec-decode: K={int(stats['spec_k'])} "
               f"accepted={int(stats['spec_accepted_tokens'])}/"

@@ -39,8 +39,9 @@ so no inverse-CDF search is needed and top-k/top-p masking composes as
 plain ``-inf`` writes.
 
 ``ks_two_sample`` is a scipy-free two-sample Kolmogorov–Smirnov test
-(asymptotic p-value, Numerical-Recipes series) used by the BENCH
-``sampling`` section to check the K>0 token distribution against K=0.
+(asymptotic p-value, Numerical-Recipes series) that
+tests/test_sampling.py uses to check the K>0 token distribution
+against K=0.
 """
 
 from __future__ import annotations

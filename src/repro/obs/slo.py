@@ -1,8 +1,8 @@
 """SLO attainment, goodput, and max-sustainable-rate search.
 
-These are the CI-asserted quantities (the ``online`` section of
-``BENCH_serving.json`` records them and ``benchmarks/check_regression``
-gates on them), so their definitions are fixed here precisely:
+``launch/serve.py --slo-ttft-ms/--slo-tpot-ms`` reports these
+quantities and tests/test_online.py pins them, so their definitions
+are fixed here precisely:
 
 **SLO spec.**  ``SLOSpec(ttft_s, tpot_s)`` — deadlines on time-to-
 first-token and time-per-output-token, in seconds.
@@ -24,10 +24,9 @@ flight at trace time have no verdict and are excluded from plain
 and charged as misses by ``attainment_strict`` =
 ``met / (finished + unfinished)``, because a totally overloaded run
 that finishes 2 of 200 requests must not report attainment 1.0 from
-the two that squeaked through.  ``attainment_strict`` (NaN only when
-nothing was issued at all) is what the ``online`` BENCH section and
-``check_regression`` gate on; on the benchmarked run-to-completion
-streams unfinished == 0 and the two metrics coincide.
+the two that squeaked through.  ``attainment_strict`` is NaN only
+when nothing was issued at all; on run-to-completion streams
+unfinished == 0 and the two metrics coincide.
 
 **Goodput.**  Output tokens from SLO-met requests per wall-second:
 
@@ -136,8 +135,8 @@ def goodput(tracer: Tracer, slo: SLOSpec,
 
 def slo_report(tracer: Tracer, slo: SLOSpec,
                wall_s: float) -> Dict[str, float]:
-    """attainment + goodput in one flat dict (the per-rate record the
-    ``online`` BENCH section stores)."""
+    """attainment + goodput in one flat dict (the per-rate record of a
+    rate sweep)."""
     out = {"slo_ttft_s": slo.ttft_s, "slo_tpot_s": slo.tpot_s}
     out.update(attainment(tracer, slo))
     out.update(goodput(tracer, slo, wall_s))

@@ -1,12 +1,11 @@
 """LLM serving loop with continuous batching (paper §III-C-3 analog).
 
 The paper measures generation throughput on Llama with ShareGPT-derived
-request lengths (Table XII).  Two engines reproduce the setup:
-
-``ChunkedServer`` (default, exported as ``Server``) — Sarathi-style
-chunked prefill: prompts are bucketed into fixed C-token chunks and
-packed, together with the single-token decodes of ongoing requests,
-into ONE fixed-shape jitted step (`models.transformer.chunk_step`).
+request lengths (Table XII).  One engine serves it here:
+``ChunkedServer`` (also exported as ``Server``), Sarathi-style chunked
+prefill: prompts are bucketed into fixed C-token chunks and packed,
+together with the single-token decodes of ongoing requests, into ONE
+fixed-shape jitted step (`models.transformer.chunk_step`).
 Decode-only stretches run a device-resident K-step `lax.scan` span:
 greedy argmax, position advance, active-slot masking and stop detection
 all happen on device; the host only mirrors the (deterministic)
@@ -15,15 +14,15 @@ Because every compiled program has a shape fixed by (slots, chunk,
 span), the engine compiles O(1) programs no matter how prompt lengths
 are distributed (probe: ``compile_counts()``).
 
-KV memory is **paged** by default (vLLM-style): instead of reserving a
-contiguous ``max_len + chunk`` region per slot up front, the cache is a
-shared pool of fixed-size blocks ([num_blocks, KH, block_size, hd] per
-layer) addressed through a per-slot block table — a fixed-shape
+KV memory is **paged** (vLLM-style): instead of reserving a contiguous
+``max_len`` region per slot up front, the cache is a shared pool of
+fixed-size blocks ([num_blocks, KH, block_size, hd] per layer)
+addressed through a per-slot block table — a fixed-shape
 [slots, max_blocks] int32 jit operand, so the compiled programs are
-unchanged in number.  ``paged=False`` restores the contiguous layout
-for A/B; greedy outputs are bit-identical either way (masked positions
-carry exactly-zero softmax weight, so the virtual view through the
-table matches the contiguous cache).
+unchanged in number.  Masked positions carry exactly-zero softmax
+weight, so the virtual view through the table reads like a contiguous
+cache (the model layer's own contiguous ``decode_step`` path is the
+tests' reference).
 
 On top of the paged pool sits a **radix-tree prefix cache**
 (``prefix_cache=True``, runtime/prefix_cache.py): finished requests
@@ -45,8 +44,8 @@ steps on the same token/position inputs).
 
 With ``eos_id`` set, generation also stops when the model emits that
 token: the device-side stop mask of the decode span folds in
-``tok == eos_id`` alongside the length checks (both engines), at the
-cost of syncing the span's final position/stop state back to the host.
+``tok == eos_id`` alongside the length checks, at the cost of syncing
+the span's final position/stop state back to the host.
 ``eos_id=None`` (default) preserves the length-only behavior, where
 the host mirror never reads device state.
 
@@ -75,18 +74,17 @@ speculative sampling is exact-match-given-seed to ``K=0`` sampling
 (the point-mass speculative-sampling rule — see
 runtime/spec_decode.py).
 
-``kernel=True`` (default off; requires ``paged``) reads the KV pool
-through the fused Pallas block-table kernels of
-kernels/paged_attention.py instead of materializing each slot's
-gathered view: the block-table walk happens inside the kernel, only
-the ``ceil(kv_len/block_size)`` valid blocks move, and the
-contractions run on the MXU; results agree with ``kernel=False`` within
-the tolerances of tests/test_paged_kernel.py — the gather path stays
-as the always-on A/B oracle.
-``fp8_kv=True`` (requires ``paged``) stores the pool as e4m3 codes
-plus per-token-row f32 scales (halving per-device KV bytes + scale
-overhead; see core/roofline.paged_decode_kv_bytes for the modeled
-bytes/step), and ``fp8_linear=True`` (tp=1, non-MoE) pre-quantizes
+``kernel=True`` (default off) reads the KV pool through the fused
+Pallas block-table kernels of kernels/paged_attention.py instead of
+materializing each slot's gathered view: the block-table walk happens
+inside the kernel, only the ``ceil(kv_len/block_size)`` valid blocks
+move, and the contractions run on the MXU; results agree with
+``kernel=False`` within the tolerances of tests/test_paged_kernel.py —
+the gather path stays as the always-on A/B oracle.
+``fp8_kv=True`` stores the pool as e4m3 codes plus per-token-row f32
+scales (halving per-device KV bytes + scale overhead; see
+core/roofline.paged_decode_kv_bytes for the modeled bytes/step), and
+``fp8_linear=True`` (tp=1, non-MoE) pre-quantizes
 the layer weights once at init and serves every matmul through
 te/linear.fp8_serving_dot.  The fp8 options change numerics within
 documented tolerance (tests/test_paged_kernel.py).
@@ -108,20 +106,14 @@ at Yi-6B width 5 of 16 greedy requests came out identical at tp=4,
 with prompt logits 0.019 relative RMS apart (chip_smoke.py --tp 4
 holds them to its bf16 tolerance instead).
 
-``SlotServer`` — the original engine, kept as the measured baseline:
-prefill feeds one token per ``decode_step`` through a scan and
-recompiles per distinct prompt length; the decode loop syncs to the
-host every step.  `benchmarks/llm_gen.py` reports both.
-
-Both engines emit the same greedy token sequences wherever their
-logits agree: the chunked path's per-slot math (bf16 activations,
-MXU attention dots accumulating in fp32, fp32 softmax over the masked
-cache) is the token-at-a-time decode path's, and the two differ only
-in the order of the attention's fp32 sums (the CPU tests hold the
-tokens equal).  Requests whose
-``in_len + max_new`` cannot fit below the ``max_len`` position cap are
-flagged ``truncated`` at admission (both engines) instead of silently
-coming back short.
+The chunked path's per-slot math (bf16 activations, MXU attention dots
+accumulating in fp32, fp32 softmax over the masked cache) is the
+token-at-a-time decode path's, and the two differ only in the order of
+the attention's fp32 sums, so greedy tokens match a plain one-request
+``api.decode_step`` loop wherever their logits agree (the CPU tests
+hold them equal).  Requests whose ``in_len + max_new`` cannot fit
+below the ``max_len`` position cap are flagged ``truncated`` at
+admission instead of silently coming back short.
 """
 
 from __future__ import annotations
@@ -255,7 +247,7 @@ def clone_requests(reqs: List[Request]) -> List[Request]:
 
 
 # ----------------------------------------------------------------------
-# Chunked-prefill engine (default)
+# Chunked-prefill engine
 # ----------------------------------------------------------------------
 
 class ChunkedServer:
@@ -281,8 +273,7 @@ class ChunkedServer:
     arrays are int32 (matching the jit operands) so operand dtypes
     never drift between calls.
 
-    With ``paged=True`` (default) the KV cache is a shared block pool
-    plus per-slot block tables; `_ensure_blocks` assigns physical
+    The KV cache is a shared block pool plus per-slot block tables; `_ensure_blocks` assigns physical
     blocks as a slot's frontier advances and `_harvest` drops the
     slot's references.  ``_admit`` reserves the request's worst case
     *minus its prefix-cache hit* against the pool and backpressures
@@ -310,9 +301,12 @@ class ChunkedServer:
     its mirror simulation's, which EOS can only narrow on device).
     """
 
+    # the KV cache is always the paged pool; bench/harness.warm reads this
+    paged = True
+
     def __init__(self, cfg: ModelConfig, params: Params, *,
                  batch_slots: int = 8, max_len: int = 512,
-                 chunk: int = 16, span: int = 8, paged: bool = True,
+                 chunk: int = 16, span: int = 8,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  prefix_cache: bool = True,
                  eos_id: Optional[int] = None,
@@ -329,7 +323,6 @@ class ChunkedServer:
         self.max_len = max_len
         self.chunk = chunk
         self.span = span
-        self.paged = paged
         self.eos_id = eos_id
         # -- stochastic sampling (models/sampling): the server default
         # for requests without their own SamplingParams.  Greedy is a
@@ -359,9 +352,6 @@ class ChunkedServer:
         self.kernel = bool(kernel)
         self.fp8_kv = bool(fp8_kv)
         self.fp8_linear = bool(fp8_linear)
-        if self.kernel or self.fp8_kv:
-            assert paged, \
-                "kernel=/fp8_kv= require the paged KV pool (paged=True)"
         # -- tensor-parallel mesh (sharding/plans.ServingPlan contract):
         # weights head-wise/column-row-wise, KV cache along the KV-head
         # axis, every scheduler operand (tokens, positions, block
@@ -392,7 +382,7 @@ class ChunkedServer:
                  f"output parity")
         if self._plan is not None:
             self._param_sh = self._plan.param_shardings(cfg)
-            self._cache_sh = self._plan.cache_sharding(cfg, paged=paged)
+            self._cache_sh = self._plan.cache_sharding(cfg)
             self._repl = self._plan.replicated
             params = jax.device_put(params, self._param_sh)
         self.params = params
@@ -406,56 +396,42 @@ class ChunkedServer:
             self._quant = te_linear.quantize_serving_params(self.params)
         self.spec_decode = int(spec_decode)
         assert self.spec_decode >= 0
-        if self.spec_decode and not paged:
-            # the contiguous cache's + chunk headroom must absorb the
-            # verify window's beyond-frontier writes (paged scatters
-            # simply drop them past the block table)
-            assert self.spec_decode < chunk, \
-                "spec_decode window (K+1) must fit the chunk headroom"
         self.prefix_cache: Optional[RadixPrefixCache] = None
-        if paged:
-            self.block_size = block_size
-            # virtual blocks per slot; real writes never pass max_len
-            self.max_blocks = -(-max_len // block_size)
-            self.num_blocks = (batch_slots * self.max_blocks
-                               if num_blocks is None else num_blocks)
-            self.cache = api.init_cache(
-                cfg, batch_slots, max_len, paged=True,
-                block_size=block_size, num_blocks=self.num_blocks,
-                sharding=(self._cache_sh if self._plan is not None
-                          else None),
-                fp8_kv=self.fp8_kv)
-            self.block_table = np.full((batch_slots, self.max_blocks),
-                                       -1, np.int32)
-            self.pool = BlockPool(self.num_blocks)
-            if prefix_cache:
-                self.prefix_cache = RadixPrefixCache(self.pool, block_size,
-                                                     tracer=self.obs,
-                                                     metrics=self.metrics)
-            self._slot_blocks: List[List[int]] = [[] for _ in range(batch_slots)]
-            self._num_shared = np.zeros(batch_slots, np.int32)
-            self._cow_pending = [False] * batch_slots
-            self._reserved = np.zeros(batch_slots, np.int32)
-            self._reserved_total = 0
-            self.peak_blocks = 0
-            self.admission_stalls = 0
-            self.total_prompt_tokens = 0
-            self.cached_prompt_tokens = 0
-            self.prefix_hits = 0
-            # donating the cache keeps the COW copy in place — without
-            # it, XLA materializes a second full pool to update 1 block
-            self._cow_fn = jax.jit(
-                lambda cache, src, dst: api.cow_copy_block(cfg, cache,
-                                                           src, dst),
-                donate_argnums=(0,),
-                **self._sharding_kw(n_ops=2, with_params=False))
-        else:
-            # + chunk headroom: chunk writes start at the valid frontier
-            # and must never clamp (see attention.update_cache)
-            self.cache = api.init_cache(
-                cfg, batch_slots, max_len + chunk,
-                sharding=(self._cache_sh if self._plan is not None
-                          else None))
+        self.block_size = block_size
+        # virtual blocks per slot; real writes never pass max_len
+        self.max_blocks = -(-max_len // block_size)
+        self.num_blocks = (batch_slots * self.max_blocks
+                           if num_blocks is None else num_blocks)
+        self.cache = api.init_cache(
+            cfg, batch_slots, max_len, paged=True,
+            block_size=block_size, num_blocks=self.num_blocks,
+            sharding=(self._cache_sh if self._plan is not None
+                      else None),
+            fp8_kv=self.fp8_kv)
+        self.block_table = np.full((batch_slots, self.max_blocks),
+                                   -1, np.int32)
+        self.pool = BlockPool(self.num_blocks)
+        if prefix_cache:
+            self.prefix_cache = RadixPrefixCache(self.pool, block_size,
+                                                 tracer=self.obs,
+                                                 metrics=self.metrics)
+        self._slot_blocks: List[List[int]] = [[] for _ in range(batch_slots)]
+        self._num_shared = np.zeros(batch_slots, np.int32)
+        self._cow_pending = [False] * batch_slots
+        self._reserved = np.zeros(batch_slots, np.int32)
+        self._reserved_total = 0
+        self.peak_blocks = 0
+        self.admission_stalls = 0
+        self.total_prompt_tokens = 0
+        self.cached_prompt_tokens = 0
+        self.prefix_hits = 0
+        # donating the cache keeps the COW copy in place — without
+        # it, XLA materializes a second full pool to update 1 block
+        self._cow_fn = jax.jit(
+            lambda cache, src, dst: api.cow_copy_block(cfg, cache,
+                                                       src, dst),
+            donate_argnums=(0,),
+            **self._sharding_kw(n_ops=2, with_params=False))
         self.cur_tok = jnp.zeros((batch_slots,), jnp.int32)
         self.out_buf = jnp.zeros((batch_slots, max_len), jnp.int32)
         if self._plan is not None:
@@ -512,9 +488,7 @@ class ChunkedServer:
             self.obs.meta.update(
                 batch_slots=self.B, chunk=self.chunk, span=self.span,
                 max_len=self.max_len, spec_decode=self.spec_decode,
-                tp=self.tp, paged=self.paged)
-            if self.paged:
-                self.obs.meta.update(num_blocks=self.num_blocks)
+                tp=self.tp, num_blocks=self.num_blocks)
 
     def _sharding_kw(self, *, n_ops: int, n_out: Optional[int] = None,
                      with_params: bool = True) -> Dict[str, Any]:
@@ -559,11 +533,8 @@ class ChunkedServer:
         return kw
 
     def _device_block_table(self) -> np.ndarray:
-        """Snapshot of the block table as a jit operand (fixed shape;
-        a dummy for the contiguous layout so signatures don't vary)."""
-        if self.paged:
-            return self.block_table.copy()
-        return np.zeros((self.B, 1), np.int32)
+        """Snapshot of the block table as a jit operand (fixed shape)."""
+        return self.block_table.copy()
 
     def _put(self, x):
         """EXPLICIT host->device transfer for a scheduler operand.
@@ -591,7 +562,7 @@ class ChunkedServer:
                                cur_tok[:, None], tokens_host)
             logits, cache = transformer.chunk_step(
                 self.cfg, params, cache, tokens, pos, n_tokens,
-                block_table if self.paged else None, **self._fwd_kw())
+                block_table, **self._fwd_kw())
             # the emitted token will sit at sequence position
             # pos + n_tokens — the position key that makes this draw
             # identical to the span/verify paths' draw for the same
@@ -622,12 +593,11 @@ class ChunkedServer:
                    samp_seed, block_table):
         row = jnp.arange(self.B)
         cap = self.max_len - 1
-        bt = block_table if self.paged else None
 
         def step(carry, _):
             cache, tok, pos, out_buf, out_len, active = carry
             logits, cache = transformer.decode_step(
-                self.cfg, params, cache, tok, pos, bt,
+                self.cfg, params, cache, tok, pos, block_table,
                 **self._fwd_kw())
             # emission position pos + 1 (pre-increment), matching the
             # chunk path's pos + n_tokens and verify row j's
@@ -662,17 +632,15 @@ class ChunkedServer:
             return spec.spec_decode_step(
                 self.cfg, params, cache, table, cur_tok, out_buf, pos,
                 out_len, active, max_new, samp_temp, samp_top_k,
-                samp_top_p, samp_seed,
-                block_table if self.paged else None,
+                samp_top_p, samp_seed, block_table,
                 max_len=self.max_len, eos_id=self.eos_id,
                 fwd_kw=self._fwd_kw())
 
     def compile_counts(self) -> Dict[str, int]:
         """Programs compiled per work unit — O(1) by construction."""
         counts = {"chunk_step": api.compile_count(self._chunk_fn),
-                  "decode_span": api.compile_count(self._span_fn)}
-        if self.paged:
-            counts["cow_copy"] = max(api.compile_count(self._cow_fn), 0)
+                  "decode_span": api.compile_count(self._span_fn),
+                  "cow_copy": max(api.compile_count(self._cow_fn), 0)}
         if self.spec_decode:
             counts["verify_step"] = api.compile_count(self._verify_fn)
         return counts
@@ -685,7 +653,6 @@ class ChunkedServer:
         radix tree are untouched.  These are the logits a request's
         first token is drawn from; checks compare them with a
         reference (api.prefill).  The serving loop never calls this."""
-        assert self.paged, "prompt_logits reads through the paged pool"
         n, C, mb = len(prompts), self.chunk, self.max_blocks
         cache = api.init_cache(
             self.cfg, n, self.max_len, paged=True,
@@ -717,7 +684,7 @@ class ChunkedServer:
                                        np.float32)[last]
         return out
 
-    # -- host-side refcounted block allocator (paged) ---------------------
+    # -- host-side refcounted block allocator -----------------------------
     def _blocks_needed(self, req: Request) -> int:
         """Worst-case block demand: the frontier never passes
         min(in_len + max_new, max_len)."""
@@ -869,87 +836,85 @@ class ChunkedServer:
             if self.slot_req[s] is None and queue:
                 req = queue[0]
                 if len(req.prompt) > self.max_len:
-                    # out-of-range cache writes would clamp and silently
-                    # corrupt the slot's tail (see attention.update_cache)
+                    # its positions would run past the slot's block table
+                    # (max_len tokens) and be served silently cut
                     queue.pop(0)
                     raise ValueError(
                         f"request {req.rid}: prompt length "
                         f"{len(req.prompt)} exceeds max_len {self.max_len}")
                 matched = 0
-                if self.paged:
-                    shared: List[int] = []
-                    cow, cow_len = None, 0
-                    # cheap lower bound first: when even a fully-cached
-                    # prompt could not admit, skip the radix walk and
-                    # pin/rollback churn that a stalled queue head
-                    # would otherwise replay every serve-loop iteration
-                    best_shared = (max((len(req.prompt) - 1)
-                                       // self.block_size, 0)
-                                   if self.prefix_cache is not None else 0)
-                    fail_fast = (self._blocks_needed(req) - best_shared
-                                 > self._available_blocks())
-                    if not fail_fast and self.prefix_cache is not None:
-                        shared, cow, cow_len = self._match_prefix(req.prompt)
-                        # pin the hit before the supply check; matched
-                        # blocks are mapped, not drawn from the pool
-                        for b in shared:
-                            self.pool.incref(b)
-                        if cow is not None:
-                            self.pool.incref(cow)
-                        matched = len(shared) * self.block_size + cow_len
-                    # worst case minus the cache-covered prefix: a
-                    # fully-cached prompt admits even when the free
-                    # pool alone couldn't hold its unshared footprint
-                    needed = self._blocks_needed(req) - len(shared)
-                    if (cow is not None
-                            and needed > self._available_blocks()):
-                        # tight supply: the COW pin holds an evictable
-                        # block hostage without reducing demand (the
-                        # private copy still needs a fresh block), so
-                        # drop the partial match and recompute its
-                        # < block_size tokens rather than stall/fail
-                        self.pool.decref(cow)
-                        cow, cow_len = None, 0
-                        matched = len(shared) * self.block_size
-                    if fail_fast or needed > self._available_blocks():
-                        for b in shared:        # roll the pin back
-                            self.pool.decref(b)
-                        if cow is not None:
-                            self.pool.decref(cow)
-                        if not any(r is not None for r in self.slot_req):
-                            # nothing in flight to free up blocks
-                            raise ValueError(
-                                f"request {req.rid}: needs "
-                                f"{self._blocks_needed(req)} KV blocks "
-                                f"but the pool has {self.num_blocks}; "
-                                f"grow num_blocks")
-                        # backpressure: wait for a harvest to free blocks
-                        self.admission_stalls += 1
-                        self.metrics.counter(
-                            "serving.admission.stalls").inc()
-                        if self.obs.enabled:
-                            self.obs.event("stall", rid=req.rid,
-                                           needed_blocks=needed)
-                        break
-                    self._reserved[s] = needed
-                    self._reserved_total += needed
-                    self._slot_blocks[s] = list(shared)
-                    for i, b in enumerate(shared):
-                        self.block_table[s, i] = b
-                    self._num_shared[s] = len(shared)
-                    self._cow_pending[s] = cow is not None
+                shared: List[int] = []
+                cow, cow_len = None, 0
+                # cheap lower bound first: when even a fully-cached
+                # prompt could not admit, skip the radix walk and
+                # pin/rollback churn that a stalled queue head
+                # would otherwise replay every serve-loop iteration
+                best_shared = (max((len(req.prompt) - 1)
+                                   // self.block_size, 0)
+                               if self.prefix_cache is not None else 0)
+                fail_fast = (self._blocks_needed(req) - best_shared
+                             > self._available_blocks())
+                if not fail_fast and self.prefix_cache is not None:
+                    shared, cow, cow_len = self._match_prefix(req.prompt)
+                    # pin the hit before the supply check; matched
+                    # blocks are mapped, not drawn from the pool
+                    for b in shared:
+                        self.pool.incref(b)
                     if cow is not None:
-                        self.block_table[s, len(shared)] = cow
-                        self._slot_blocks[s].append(cow)
-                    self.total_prompt_tokens += len(req.prompt)
-                    self.cached_prompt_tokens += matched
-                    self.prefix_hits += matched > 0
-                    if matched:
-                        # once per admitted request (a stalled queue
-                        # head is looked up again on every retry)
-                        self.metrics.counter("serving.prefix.hits").inc()
-                        self.metrics.counter(
-                            "serving.prefix.hit_tokens").inc(matched)
+                        self.pool.incref(cow)
+                    matched = len(shared) * self.block_size + cow_len
+                # worst case minus the cache-covered prefix: a
+                # fully-cached prompt admits even when the free
+                # pool alone couldn't hold its unshared footprint
+                needed = self._blocks_needed(req) - len(shared)
+                if cow is not None and needed > self._available_blocks():
+                    # tight supply: the COW pin holds an evictable
+                    # block hostage without reducing demand (the
+                    # private copy still needs a fresh block), so
+                    # drop the partial match and recompute its
+                    # < block_size tokens rather than stall/fail
+                    self.pool.decref(cow)
+                    cow, cow_len = None, 0
+                    matched = len(shared) * self.block_size
+                if fail_fast or needed > self._available_blocks():
+                    for b in shared:        # roll the pin back
+                        self.pool.decref(b)
+                    if cow is not None:
+                        self.pool.decref(cow)
+                    if not any(r is not None for r in self.slot_req):
+                        # nothing in flight to free up blocks
+                        raise ValueError(
+                            f"request {req.rid}: needs "
+                            f"{self._blocks_needed(req)} KV blocks "
+                            f"but the pool has {self.num_blocks}; "
+                            f"grow num_blocks")
+                    # backpressure: wait for a harvest to free blocks
+                    self.admission_stalls += 1
+                    self.metrics.counter(
+                        "serving.admission.stalls").inc()
+                    if self.obs.enabled:
+                        self.obs.event("stall", rid=req.rid,
+                                       needed_blocks=needed)
+                    break
+                self._reserved[s] = needed
+                self._reserved_total += needed
+                self._slot_blocks[s] = list(shared)
+                for i, b in enumerate(shared):
+                    self.block_table[s, i] = b
+                self._num_shared[s] = len(shared)
+                self._cow_pending[s] = cow is not None
+                if cow is not None:
+                    self.block_table[s, len(shared)] = cow
+                    self._slot_blocks[s].append(cow)
+                self.total_prompt_tokens += len(req.prompt)
+                self.cached_prompt_tokens += matched
+                self.prefix_hits += matched > 0
+                if matched:
+                    # once per admitted request (a stalled queue
+                    # head is looked up again on every retry)
+                    self.metrics.counter("serving.prefix.hits").inc()
+                    self.metrics.counter(
+                        "serving.prefix.hit_tokens").inc(matched)
                 queue.pop(0)
                 # the pos cap stops generation at max_len - in_len tokens;
                 # flag the shortfall instead of harvesting silently short
@@ -978,8 +943,7 @@ class ChunkedServer:
 
     def _check_done(self, s: int) -> None:
         # stop rule, applied after every emit (including the first token
-        # from the final prefill chunk, so max_new=1 yields one token;
-        # SlotServer applies the same post-admission check)
+        # from the final prefill chunk, so max_new=1 yields one token)
         req = self.slot_req[s]
         if (self.out_len[s] >= req.max_new
                 or self.pos[s] >= self.max_len - 1):
@@ -1023,14 +987,12 @@ class ChunkedServer:
                     tokens_host[s, :n] = req.prompt[off:off + n]
                     n_tokens[s] = n
                     emit[s] = off + n == len(req.prompt)
-                    if self.paged:
-                        self._ensure_blocks(s, int(self.pos[s]) + n)
+                    self._ensure_blocks(s, int(self.pos[s]) + n)
                 elif self.mode[s] == "decode":
                     n_tokens[s] = 1
                     is_decode[s] = True
                     emit[s] = True
-                    if self.paged:
-                        self._ensure_blocks(s, int(self.pos[s]) + 1)
+                    self._ensure_blocks(s, int(self.pos[s]) + 1)
             self._count_sampling_paths([emit])
         with self.obs.phase("serve.chunk.put"):
             ops = (self._put(tokens_host), self._put(self.pos.copy()),
@@ -1122,9 +1084,8 @@ class ChunkedServer:
                     if (sim_out[s] >= max_new[s] or sim_pos[s] >= cap):
                         sim_act[s] = False
             self._count_sampling_paths(lives)
-            if self.paged:
-                for s in np.flatnonzero(active):
-                    self._ensure_blocks(s, int(sim_pos[s]))
+            for s in np.flatnonzero(active):
+                self._ensure_blocks(s, int(sim_pos[s]))
         with self.obs.phase("serve.span.put"):
             ops = (self._put(self.pos.copy()),
                    self._put(self.out_len.copy()),
@@ -1189,17 +1150,16 @@ class ChunkedServer:
                 [r.max_new if r is not None else 0
                  for r in self.slot_req], np.int32)
             cap = self.max_len - 1
-            if self.paged:
-                for s in np.flatnonzero(active):
-                    # cover the verify window only up to the slot's emit
-                    # budget: the window rows past it can never be
-                    # accepted and their writes drop beyond the table,
-                    # so admission reservations (computed from max_new)
-                    # always suffice
-                    budget = min(int(max_new[s]) - int(self.out_len[s]),
-                                 cap - int(self.pos[s]))
-                    self._ensure_blocks(
-                        s, int(self.pos[s]) + min(K + 1, max(budget, 1)))
+            for s in np.flatnonzero(active):
+                # cover the verify window only up to the slot's emit
+                # budget: the window rows past it can never be
+                # accepted and their writes drop beyond the table,
+                # so admission reservations (computed from max_new)
+                # always suffice
+                budget = min(int(max_new[s]) - int(self.out_len[s]),
+                             cap - int(self.pos[s]))
+                self._ensure_blocks(
+                    s, int(self.pos[s]) + min(K + 1, max(budget, 1)))
         with self.obs.phase("serve.verify.put"):
             ops = (self._put(self.pos.copy()),
                    self._put(self.out_len.copy()), self._put(active),
@@ -1225,18 +1185,17 @@ class ChunkedServer:
         t1 = time.perf_counter()
         self._record_dispatch("verify", t0, t_call, t_ret, t1)
         with self.obs.phase("serve.verify.commit"):
-            if self.paged:
-                # rejected drafts: shrink the block-table frontier back
-                # to the accepted positions (restores the reservation
-                # drawn pre-verify; stale KV beyond it is never read)
-                for s in np.flatnonzero(active):
-                    rolled = self._truncate_blocks(s, int(self.pos[s]))
-                    if rolled:
-                        self.metrics.counter(
-                            "serving.spec.rollback_blocks").inc(rolled)
-                        if self.obs.enabled:
-                            self.obs.event("spec_rollback", slot=int(s),
-                                           blocks=rolled)
+            # rejected drafts: shrink the block-table frontier back to
+            # the accepted positions (restores the reservation drawn
+            # pre-verify; stale KV beyond it is never read)
+            for s in np.flatnonzero(active):
+                rolled = self._truncate_blocks(s, int(self.pos[s]))
+                if rolled:
+                    self.metrics.counter(
+                        "serving.spec.rollback_blocks").inc(rolled)
+                    if self.obs.enabled:
+                        self.obs.event("spec_rollback", slot=int(s),
+                                       blocks=rolled)
             for s in np.flatnonzero(done_now):
                 self._mark_done(s)
             if self.obs.enabled:
@@ -1299,10 +1258,9 @@ class ChunkedServer:
                                n_out=len(req.output))
             self.slot_req[s] = None
             self.mode[s] = "idle"
-            if self.paged:
-                if self.prefix_cache is not None:
-                    self._insert_prefix(s, req)
-                self._free_slot_blocks(s)
+            if self.prefix_cache is not None:
+                self._insert_prefix(s, req)
+            self._free_slot_blocks(s)
         return served
 
     def _insert_prefix(self, s: int, req: Request) -> None:
@@ -1330,15 +1288,14 @@ class ChunkedServer:
         self._step = 0
         self._last_kind = None
         self._t_ready = None
-        if self.paged:
-            # pool metrics are per run, not per server lifetime
-            self.peak_blocks = self._blocks_in_use()
-            self.admission_stalls = 0
-            self.total_prompt_tokens = 0
-            self.cached_prompt_tokens = 0
-            self.prefix_hits = 0
-            self._evict0 = (self.prefix_cache.evicted_blocks
-                            if self.prefix_cache is not None else 0)
+        # pool metrics are per run, not per server lifetime
+        self.peak_blocks = self._blocks_in_use()
+        self.admission_stalls = 0
+        self.total_prompt_tokens = 0
+        self.cached_prompt_tokens = 0
+        self.prefix_hits = 0
+        self._evict0 = (self.prefix_cache.evicted_blocks
+                        if self.prefix_cache is not None else 0)
         if self.spec_decode:
             # spec metrics are per run too (the n-gram table persists
             # across runs — warm drafts are a feature)
@@ -1520,187 +1477,41 @@ class ChunkedServer:
                     self.spec_emitted / self.spec_slot_steps
                     if self.spec_slot_steps else 0.0),
             })
-        if self.paged:
-            contiguous_tokens = self.B * (self.max_len + self.chunk)
-            kv_bytes = sum(int(leaf.nbytes) for leaf in
-                           jax.tree_util.tree_leaves(self.cache))
+        kv_bytes = sum(int(leaf.nbytes) for leaf in
+                       jax.tree_util.tree_leaves(self.cache))
+        stats.update({
+            "pool_blocks": float(self.num_blocks),
+            "block_size": float(self.block_size),
+            "peak_blocks_in_use": float(self.peak_blocks),
+            "pool_utilization": (self.peak_blocks / self.num_blocks
+                                 if self.num_blocks else 0.0),
+            "kv_tokens_capacity": float(self.num_blocks
+                                        * self.block_size),
+            "admission_stalls": float(self.admission_stalls),
+            # the pool shards its KV-head dim over the tp mesh, so
+            # every device holds all blocks but only KH/tp heads
+            "kv_bytes_per_device": float(kv_bytes // self.tp),
+        })
+        if self.prefix_cache is not None:
+            total = self.total_prompt_tokens
             stats.update({
-                "pool_blocks": float(self.num_blocks),
-                "block_size": float(self.block_size),
-                "peak_blocks_in_use": float(self.peak_blocks),
-                "pool_utilization": (self.peak_blocks / self.num_blocks
-                                     if self.num_blocks else 0.0),
-                "kv_tokens_capacity": float(self.num_blocks
-                                            * self.block_size),
-                "kv_tokens_contiguous": float(contiguous_tokens),
-                "admission_stalls": float(self.admission_stalls),
-                # the pool shards its KV-head dim over the tp mesh, so
-                # every device holds all blocks but only KH/tp heads
-                "kv_bytes_per_device": float(kv_bytes // self.tp),
+                "prefix_cache_enabled": 1.0,
+                "prompt_tokens_total": float(total),
+                "prefix_cached_tokens": float(
+                    self.cached_prompt_tokens),
+                "cached_token_fraction": (
+                    self.cached_prompt_tokens / total if total
+                    else 0.0),
+                "prefix_hit_requests": float(self.prefix_hits),
+                "prefix_hit_rate": (self.prefix_hits / len(requests)
+                                    if requests else 0.0),
+                "cache_evictions": float(
+                    self.prefix_cache.evicted_blocks - self._evict0),
+                "cached_blocks": float(
+                    self.prefix_cache.cached_block_count()),
             })
-            if self.prefix_cache is not None:
-                total = self.total_prompt_tokens
-                stats.update({
-                    "prefix_cache_enabled": 1.0,
-                    "prompt_tokens_total": float(total),
-                    "prefix_cached_tokens": float(
-                        self.cached_prompt_tokens),
-                    "cached_token_fraction": (
-                        self.cached_prompt_tokens / total if total
-                        else 0.0),
-                    "prefix_hit_requests": float(self.prefix_hits),
-                    "prefix_hit_rate": (self.prefix_hits / len(requests)
-                                        if requests else 0.0),
-                    "cache_evictions": float(
-                        self.prefix_cache.evicted_blocks - self._evict0),
-                    "cached_blocks": float(
-                        self.prefix_cache.cached_block_count()),
-                })
         return stats
 
 
-# ----------------------------------------------------------------------
-# Baseline slot engine (the original implementation, kept for A/B)
-# ----------------------------------------------------------------------
-
-class SlotServer:
-    """Slot-based continuous-batching decode server — seed baseline.
-
-    Prefill steps one token at a time through `decode_step` and jit-
-    recompiles per distinct prompt length; the decode loop syncs
-    argmax/slot bookkeeping to the host every step.  Kept as the
-    reference implementation and benchmark baseline for ChunkedServer
-    (identical greedy outputs, measured speedup), with two correctness
-    fixes over the seed: `pos0` is a real prefill argument (see
-    `_prefill_impl`) and the first emitted token is stop-checked so
-    max_new is honored even at 1.  ``eos_id`` stops a slot after it
-    emits that token (same rule as ChunkedServer).
-    """
-
-    def __init__(self, cfg: ModelConfig, params: Params, *,
-                 batch_slots: int = 8, max_len: int = 512,
-                 eos_id: Optional[int] = None):
-        assert cfg.family in ("dense", "moe", "vlm")
-        self.cfg = cfg
-        self.params = params
-        self.B = batch_slots
-        self.max_len = max_len
-        self.eos_id = eos_id
-        self.cache = api.init_cache(cfg, batch_slots, max_len)
-        self.pos = jnp.zeros((batch_slots,), jnp.int32)
-        self.slot_req: List[Optional[Request]] = [None] * batch_slots
-        self._decode = jax.jit(
-            lambda p, c, t, pos: transformer.decode_step(cfg, p, c, t, pos))
-        self._prefill_one = jax.jit(self._prefill_impl,
-                                    static_argnames=("in_len",))
-
-    # -- admission -------------------------------------------------------
-    def _prefill_impl(self, params, cache, prompt, slot_onehot, pos0,
-                      in_len):
-        """Prefill one prompt into one slot by stepping tokens (simple,
-        shape-stable; ChunkedServer runs the batched chunk path).
-
-        `pos0` (the per-slot positions at admission) must be a real
-        argument: the seed version closed over `self.pos`, which jit
-        froze as a constant per in_len — every later admission with an
-        already-seen prompt length replayed the stale positions and
-        garbage-wrote position 0 of the other slots' caches, so outputs
-        depended on what else was in flight.
-        """
-        def body(carry, tok):
-            cache, pos = carry
-            token_b = jnp.where(slot_onehot > 0, tok, 0)
-            logits, cache = transformer.decode_step(
-                self.cfg, params, cache, token_b, pos)
-            return (cache, pos + slot_onehot), logits
-
-        (cache, _), logits = jax.lax.scan(
-            body, (cache, pos0), prompt[:in_len])
-        return cache, logits[-1]
-
-    def admit(self, req: Request, slot: int) -> jax.Array:
-        if len(req.prompt) > self.max_len:
-            raise ValueError(
-                f"request {req.rid}: prompt length {len(req.prompt)} "
-                f"exceeds max_len {self.max_len}")
-        # same truncation rule as ChunkedServer._admit: the pos cap
-        # limits generation to max_len - in_len tokens
-        req.truncated = len(req.prompt) + req.max_new > self.max_len
-        onehot = jnp.zeros((self.B,), jnp.int32).at[slot].set(1)
-        self.pos = self.pos.at[slot].set(0)
-        self.cache, last_logits = self._prefill_one(
-            self.params, self.cache, jnp.asarray(req.prompt), onehot,
-            self.pos, in_len=len(req.prompt))
-        self.pos = self.pos.at[slot].set(len(req.prompt))
-        self.slot_req[slot] = req
-        return last_logits[slot]
-
-    def compile_counts(self) -> Dict[str, int]:
-        """One decode program + one prefill program per distinct length."""
-        return {"decode_step": api.compile_count(self._decode),
-                "prefill_one": api.compile_count(self._prefill_one)}
-
-    def _stopped(self, req: Request, slot: int, tok: int) -> bool:
-        return (len(req.output) >= req.max_new
-                or int(self.pos[slot]) >= self.max_len - 1
-                or (self.eos_id is not None and tok == self.eos_id))
-
-    # -- main loop ---------------------------------------------------------
-    def serve(self, requests: List[Request]) -> Dict[str, float]:
-        queue = list(requests)
-        next_tok = jnp.zeros((self.B,), jnp.int32)
-        t0 = time.perf_counter()
-        served_tokens = 0
-        prefill_s = decode_s = 0.0
-        while queue or any(r is not None for r in self.slot_req):
-            # refill free slots
-            for s in range(self.B):
-                if self.slot_req[s] is None and queue:
-                    req = queue.pop(0)
-                    tc = time.perf_counter()
-                    logits = self.admit(req, s)
-                    tok = int(jnp.argmax(logits))
-                    prefill_s += time.perf_counter() - tc
-                    req.output.append(tok)
-                    next_tok = next_tok.at[s].set(tok)
-                    if self._stopped(req, s, tok):
-                        req.done = True
-                        served_tokens += len(req.prompt) + len(req.output)
-                        self.slot_req[s] = None
-            if not any(r is not None for r in self.slot_req):
-                # every admitted request stopped on its first prefill
-                # token (max_new=1 or an immediate EOS): go back to
-                # admission — a `break` here dropped the queued rest
-                continue
-            # one lockstep decode step for all active slots
-            tc = time.perf_counter()
-            logits, self.cache = self._decode(
-                self.params, self.cache, next_tok, self.pos)
-            active = jnp.asarray(
-                [1 if r is not None else 0 for r in self.slot_req],
-                jnp.int32)
-            self.pos = self.pos + active
-            toks = np.asarray(jnp.argmax(logits, axis=-1))
-            decode_s += time.perf_counter() - tc
-            for s, req in enumerate(self.slot_req):
-                if req is None:
-                    continue
-                req.output.append(int(toks[s]))
-                next_tok = next_tok.at[s].set(int(toks[s]))
-                if self._stopped(req, s, int(toks[s])):
-                    req.done = True
-                    served_tokens += len(req.prompt) + len(req.output)
-                    self.slot_req[s] = None
-        dt = time.perf_counter() - t0
-        return {
-            "requests": float(len(requests)),
-            "tokens": float(served_tokens),
-            "seconds": dt,
-            "tokens_per_s": served_tokens / dt if dt > 0 else 0.0,
-            "prefill_seconds": prefill_s,
-            "decode_seconds": decode_s,
-        }
-
-
-# Default engine: the chunked-prefill scheduler.
+# the engine under its short name (examples and tests import it)
 Server = ChunkedServer

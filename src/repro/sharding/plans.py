@@ -151,10 +151,9 @@ class ServingPlan:
         embedding/unembedding over ``vocab``.  Logical dims that do not
         divide the mesh fall back to replicated
         (models/common.partition_specs).
-      * The KV cache — paged pool ``[L, num_blocks, KH, block_size,
-        hd]`` (fp8 scales ``[L, num_blocks, KH, block_size]``) or
-        contiguous ``[L, B, T, KH, hd]`` — shards its KV-head dim over
-        ``tp``; each device holds every pool block but only ``KH / tp``
+      * The KV cache — the paged pool ``[L, num_blocks, KH,
+        block_size, hd]`` (fp8 scales ``[L, num_blocks, KH,
+        block_size]``) — shards its KV-head dim over ``tp``; each device holds every pool block but only ``KH / tp``
         heads of it, so the per-device KV bytes shrink by the TP degree
         while the host allocator keeps addressing whole logical blocks.
         Requires ``KH % tp == 0`` (the server asserts).
@@ -205,15 +204,13 @@ class ServingPlan:
             lambda s: NamedSharding(self.mesh, s), pspecs,
             is_leaf=lambda x: isinstance(x, P))
 
-    def cache_sharding(self, cfg, *, paged: bool) -> NamedSharding:
-        """KV cache sharding over the KV-head dim: index 2 of every
-        paged leaf ([L, num_blocks, KH, block_size, ...]), index 3 of
-        the contiguous [L, B, T, KH, hd].  Falls back to replicated when
-        KH doesn't divide."""
+    def cache_sharding(self, cfg) -> NamedSharding:
+        """Paged KV pool sharding over the KV-head dim: index 2 of every
+        leaf ([L, num_blocks, KH, block_size, ...]).  Falls back to
+        replicated when KH doesn't divide."""
         ax = self.axis if (cfg.num_kv_heads
                            and cfg.num_kv_heads % self.tp == 0) else None
-        return NamedSharding(self.mesh, P(None, None, ax) if paged
-                             else P(None, None, None, ax, None))
+        return NamedSharding(self.mesh, P(None, None, ax))
 
 
 def serving_plan(mesh: Mesh, axis: str = SERVING_TP_AXIS) -> ServingPlan:

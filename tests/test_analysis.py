@@ -107,10 +107,10 @@ def test_jx005_signature_drift_fires():
     rep = Report()
     registry = {}
     jaxpr_check.register_signature(
-        registry, "chunk_step", "paged=1,fp8_kv=0", "combo-a",
+        registry, "chunk_step", "fp8_kv=0", "combo-a",
         (jax.ShapeDtypeStruct((2, 8), jnp.int32),), rep)
     jaxpr_check.register_signature(
-        registry, "chunk_step", "paged=1,fp8_kv=0", "combo-b",
+        registry, "chunk_step", "fp8_kv=0", "combo-b",
         (jax.ShapeDtypeStruct((2, 16), jnp.int32),), rep)
     assert rep.count("JX005") == 1
     assert len(rep.findings) == 1
@@ -383,9 +383,9 @@ def test_jaxpr_layer_clean_across_serving_combos():
     assert len(rep.extras["combos"]) >= 14
     regs = rep.extras["signatures"]
     assert set(regs) == {"chunk_step", "decode_span", "verify_step"}
-    # 8 single-device combos share the default paged/bf16 layout —
+    # 8 single-device combos share the default bf16 pool layout —
     # kernel/fp8_linear/spec/eos switches all hash identical
-    assert len(regs["chunk_step"]["paged=1,fp8_kv=0"]["combos"]) >= 8
+    assert len(regs["chunk_step"]["fp8_kv=0"]["combos"]) >= 8
 
 
 # ----------------------------------------------------------------------

@@ -21,7 +21,9 @@ from repro.obs import (MetricsRegistry, NULL_METRICS, NULL_TRACER,
                        summary_table, write_jsonl, write_chrome_trace)
 from repro.runtime.prefix_cache import BlockPool, RadixPrefixCache
 from repro.runtime.server import (ChunkedServer, clone_requests,
-                                  sharegpt_like_requests)
+                                  repetitive_requests,
+                                  sharegpt_like_requests,
+                                  sysprompt_sharegpt_requests)
 
 
 # ----------------------------------------------------------------------
@@ -250,7 +252,7 @@ def setup():
     return cfg, params
 
 
-SRV_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4, paged=True,
+SRV_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4,
               block_size=8, prefix_cache=True, spec_decode=3)
 
 
@@ -297,6 +299,40 @@ def test_traced_serving_identical_outputs_and_compiles(setup):
 
     # untraced server still derives its phase split from the registry
     assert plain.metrics.counter_value("serving.dispatches.prefill") > 0
+
+
+@pytest.fixture(scope="module")
+def traced_server(setup):
+    cfg, params = setup
+    tracer = Tracer()
+    return ChunkedServer(cfg, params, tracer=tracer, **SRV_KW), tracer
+
+
+@pytest.mark.parametrize("mix", ["sharegpt", "sysprompt", "repetitive"])
+def test_latency_percentiles_ordered_on_every_mix(setup, traced_server,
+                                                  mix):
+    """Each mix served through one traced server (one wave after
+    another, as the tracer is cleared between them) yields a record per
+    request and, for TTFT, TPOT, queue delay and end-to-end latency,
+    nearest-rank percentiles with p50 <= p95 <= p99 over every
+    request."""
+    cfg, _ = setup
+    srv, tracer = traced_server
+    V = cfg.vocab_size
+    reqs = {"sharegpt": lambda: sharegpt_like_requests(
+                6, V, max_input=16, max_output=8, seed=3),
+            "sysprompt": lambda: sysprompt_sharegpt_requests(
+                6, V, num_templates=2, template_len=12, max_input=20,
+                max_output=6, seed=4),
+            "repetitive": lambda: repetitive_requests(
+                4, V, motif_len=4, reps=3, max_output=10, seed=5)}[mix]()
+    tracer.clear()
+    srv.serve(reqs)
+    lat = request_latency_summary(tracer)
+    for k in ("ttft_s", "tpot_s", "queue_delay_s", "e2e_s"):
+        p = lat[k]
+        assert p["count"] == len(reqs), (k, p)
+        assert 0.0 <= p["p50"] <= p["p95"] <= p["p99"], (k, p)
 
 
 def test_traced_steady_state_wave_is_transfer_free(setup):

@@ -27,7 +27,7 @@ def setup():
     return cfg, params
 
 
-SRV_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4, paged=True,
+SRV_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4,
               block_size=8, prefix_cache=True)
 KINDS = {"chunk": "prefill", "span": "span", "verify": "verify"}
 PHASES = ("prepare", "put", "enqueue", "wait", "commit")

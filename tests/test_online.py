@@ -1,7 +1,6 @@
 """Online-serving observatory: open-loop arrivals (runtime/arrivals),
-``ChunkedServer.serve_online``, windowed telemetry (obs/windows),
-SLO/goodput accounting (obs/slo), and the bench-regression gate
-(benchmarks/check_regression).
+``ChunkedServer.serve_online``, windowed telemetry (obs/windows), and
+SLO/goodput accounting with the rate sweep (obs/slo).
 
 The load-bearing contract: ``serve_online`` on a closed stream (every
 request at t=0) is a *free refactor* of ``serve`` — same admission
@@ -18,17 +17,18 @@ import jax
 import numpy as np
 import pytest
 
-from benchmarks.check_regression import compare
 from repro.configs import reduced_config
 from repro.models import api
 from repro.obs import (SLOSpec, Tracer, attainment, goodput,
-                       max_sustainable_rate, percentiles, request_met,
+                       max_sustainable_rate, percentiles,
+                       request_latency_summary, request_met,
                        slo_report, window_series, window_summary,
                        write_chrome_trace)
 from repro.runtime.arrivals import (closed_stream, offered_rate,
                                     poisson_stream, trace_stream)
 from repro.runtime.server import (ChunkedServer, clone_requests,
-                                  sharegpt_like_requests)
+                                  sharegpt_like_requests,
+                                  sysprompt_sharegpt_requests)
 
 # ----------------------------------------------------------------------
 # arrival streams (pure host-side math)
@@ -121,7 +121,7 @@ def setup():
     return cfg, params
 
 
-SRV_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4, paged=True,
+SRV_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4,
               block_size=8, prefix_cache=True, spec_decode=3)
 
 
@@ -441,107 +441,63 @@ def test_max_sustainable_rate_nan_attainment_is_a_miss():
 
 
 # ----------------------------------------------------------------------
-# bench-regression gate
+# a rate sweep through the real engine
 # ----------------------------------------------------------------------
 
-_BASE = {
-    "float32": {
-        "chunked_tokens_per_s": 100.0,
-        "outputs_identical": True,
-        "compile_counts": {"chunk_step": 1, "decode_span": 1},
-        "latency": {"sharegpt": {"ttft_s": {"p50": 0.1, "p99": 0.2,
-                                            "count": 8}}},
-        "online": {"sharegpt": {"max_sustainable_rps": 4.0}},
-    },
-}
+@pytest.mark.parametrize("mix", ["sharegpt", "sysprompt"])
+def test_rate_sweep_through_the_engine(setup, mix):
+    """Poisson streams at 0.5x, 1x and 3x the engine's closed-loop
+    completion rate, scored against an SLO calibrated from an unloaded
+    wave (2x its p99 TTFT/TPOT, so the sweep means the same on any
+    host): every rate serves the closed batch's greedy tokens, every
+    request finishes, and each point's attainment, goodput, latency
+    percentiles and windows are well formed."""
+    cfg, params = setup
+    if mix == "sharegpt":
+        reqs = sharegpt_like_requests(6, cfg.vocab_size, max_input=16,
+                                      max_output=8, seed=3)
+    else:
+        reqs = sysprompt_sharegpt_requests(
+            6, cfg.vocab_size, num_templates=2, template_len=12,
+            max_input=20, max_output=6, seed=4)
+    tracer = Tracer()
+    srv = ChunkedServer(cfg, params, tracer=tracer, **SRV_KW)
+    ref = clone_requests(reqs)
+    srv.serve(ref)                              # compile + tree warm
+    closed = srv.serve(clone_requests(reqs))
+    closed_rps = closed["requests"] / closed["seconds"]
+    want = [r.output for r in ref]
 
+    tracer.clear()
+    cal = clone_requests(reqs)
+    cal_stats = srv.serve_online(poisson_stream(cal, 0.5 * closed_rps,
+                                                seed=4))
+    cal_lat = request_latency_summary(tracer)
+    slo = SLOSpec(ttft_s=max(2.0 * cal_lat["ttft_s"]["p99"], 1e-3),
+                  tpot_s=max(2.0 * cal_lat["tpot_s"]["p99"], 1e-3))
+    window_s = max(cal_stats["seconds"] / 8.0, 0.02)
 
-def _mutated(**changes):
-    import copy
-    cand = copy.deepcopy(_BASE)
-    sec = cand["float32"]
-    for k, v in changes.items():
-        if k == "ttft_p99":
-            sec["latency"]["sharegpt"]["ttft_s"]["p99"] = v
-        elif k == "compiles":
-            sec["compile_counts"]["chunk_step"] = v
-        else:
-            sec[k] = v
-    return cand
+    def run_at(rate):
+        tracer.clear()
+        run = clone_requests(reqs)
+        stats = srv.serve_online(poisson_stream(run, rate, seed=5))
+        assert [r.output for r in run] == want, rate
+        rep = slo_report(tracer, slo, stats["seconds"])
+        rep.update(request_latency_summary(tracer))
+        rep["windows"] = window_summary(window_series(tracer, window_s))
+        return rep
 
-
-def test_gate_passes_identical_and_small_wobble():
-    _, failures = compare(_BASE, _BASE, tolerance=0.10)
-    assert failures == []
-    wob = _mutated(chunked_tokens_per_s=95.0, ttft_p99=0.21)
-    _, failures = compare(_BASE, wob, tolerance=0.10)
-    assert failures == []
-
-
-def test_gate_fails_throughput_and_percentile_regressions():
-    _, fail_tps = compare(_BASE, _mutated(chunked_tokens_per_s=80.0))
-    assert [".".join(f["path"]) for f in fail_tps] == \
-        ["float32.chunked_tokens_per_s"]
-    _, fail_lat = compare(_BASE, _mutated(ttft_p99=0.3))
-    assert [".".join(f["path"]) for f in fail_lat] == \
-        ["float32.latency.sharegpt.ttft_s.p99"]
-
-
-def test_gate_fails_flipped_invariants_and_compile_growth():
-    _, f1 = compare(_BASE, _mutated(outputs_identical=False))
-    assert f1 and f1[0]["rule"] == "invariant"
-    _, f2 = compare(_BASE, _mutated(compiles=2))
-    assert f2 and f2[0]["rule"] == "compile-count"
-    # improvements are allowed at any size
-    _, f3 = compare(_BASE, _mutated(chunked_tokens_per_s=500.0,
-                                    ttft_p99=0.01, compiles=0))
-    assert f3 == []
-
-
-def test_gate_fails_dropped_metric_allows_additions():
-    import copy
-    cand = copy.deepcopy(_BASE)
-    del cand["float32"]["online"]
-    _, failures = compare(_BASE, cand)
-    assert failures and failures[0]["status"] == "MISSING"
-    grown = copy.deepcopy(_BASE)
-    grown["float32"]["new_section"] = {"whatever": 1.0}
-    _, failures = compare(_BASE, grown)
-    assert failures == []
-
-
-def test_gate_pvalue_floor_and_strict_attainment():
-    base = {"sampling": {"ks_pvalue": 0.9},
-            "online": {"attainment_strict": 1.0, "unfinished": 0}}
-    # p-values have no baseline ratio: a candidate anywhere above the
-    # 0.01 floor passes even if far "below" the baseline draw
-    ok = {"sampling": {"ks_pvalue": 0.02},
-          "online": {"attainment_strict": 0.95, "unfinished": 0}}
-    rows, failures = compare(base, ok, tolerance=0.10)
-    assert failures == []
-    assert any(r["rule"] == "p-value-floor" for r in rows)
-    low = {"sampling": {"ks_pvalue": 0.005},
-           "online": {"attainment_strict": 1.0, "unfinished": 0}}
-    _, failures = compare(base, low)
-    assert [f["rule"] for f in failures] == ["p-value-floor"]
-    # attainment_strict is gated higher-is-better
-    drop = {"sampling": {"ks_pvalue": 0.9},
-            "online": {"attainment_strict": 0.5, "unfinished": 3}}
-    _, failures = compare(base, drop)
-    assert [f["path"][-1] for f in failures] == ["attainment_strict"]
-
-
-def test_gate_skips_nan_and_negative_baselines_compare_sanely():
-    nan_base = _mutated(chunked_tokens_per_s=float("nan"))
-    rows, failures = compare(nan_base, _BASE)
-    assert failures == []
-    assert any(r["status"] == "SKIP" for r in rows)
-    # negative overhead_frac baseline (tracer measured faster): a
-    # candidate near zero is within tolerance of the noise floor
-    base = {"latency": {"obs_overhead": {"overhead_frac": -0.015}}}
-    cand = {"latency": {"obs_overhead": {"overhead_frac": -0.0149}}}
-    _, failures = compare(base, cand, tolerance=0.10)
-    assert failures == []
-    worse = {"latency": {"obs_overhead": {"overhead_frac": 0.05}}}
-    _, failures = compare(base, worse, tolerance=0.10)
-    assert failures != []
+    rates = [closed_rps * m for m in (0.5, 1.0, 3.0)]
+    knee = max_sustainable_rate(run_at, rates, target_attainment=0.9)
+    assert len(knee["sweep"]) == 3
+    for point in knee["sweep"]:
+        assert point["finished"] == len(reqs) and point["unfinished"] == 0
+        assert 0.0 <= point["attainment"] <= 1.0
+        assert point["goodput_tok_s"] <= point["throughput_tok_s"] + 1e-9
+        for k in ("ttft_s", "tpot_s", "queue_delay_s", "e2e_s"):
+            p = point[k]
+            assert p["count"] > 0, k
+            assert p["p50"] <= p["p95"] <= p["p99"], (k, p)
+        assert point["windows"]["n_windows"] > 0
+    assert (math.isnan(knee["max_sustainable_rps"])
+            or knee["max_sustainable_rps"] in rates)

@@ -374,7 +374,7 @@ from repro.runtime.server import (ChunkedServer, clone_requests,  # noqa: E402
                                   sharegpt_like_requests,
                                   sysprompt_sharegpt_requests)
 
-KW = dict(batch_slots=3, max_len=64, chunk=8, span=4, paged=True,
+KW = dict(batch_slots=3, max_len=64, chunk=8, span=4,
           block_size=8, prefix_cache=True, spec_decode=2)
 
 
@@ -476,14 +476,24 @@ def test_e2e_fp8_linear_serves(setup):
     assert [len(o) for o in outs] == [len(o) for o in base]
 
 
-def test_kernel_requires_paged(setup):
-    cfg, params, _ = setup
-    with pytest.raises(AssertionError):
-        ChunkedServer(cfg, params, batch_slots=2, max_len=32, chunk=8,
-                      span=4, paged=False, kernel=True)
-    with pytest.raises(AssertionError):
-        ChunkedServer(cfg, params, batch_slots=2, max_len=32, chunk=8,
-                      span=4, paged=False, fp8_kv=True)
+def test_e2e_fp8_kernel_spec_compiles_once(setup):
+    """The fp8 pool read through the kernel with speculation on keeps
+    O(1) programs: every mix in turn through one server (radix hits,
+    COW copies and rollbacks included) compiles each program at most
+    once, chunk_step and verify_step exactly once, and serving the
+    first mix again compiles nothing new."""
+    cfg, params, mixes = setup
+    srv = ChunkedServer(cfg, params, **{**KW, "spec_decode": 3},
+                        kernel=True, fp8_kv=True)
+    for name in list(mixes) + ["sharegpt"]:
+        rs = clone_requests(mixes[name])
+        srv.serve(rs)
+        assert all(r.done for r in rs), name
+        counts = srv.compile_counts()
+        assert all(v <= 1 for v in counts.values()), (name, counts)
+    assert counts["chunk_step"] == 1, counts
+    assert counts["verify_step"] == 1, counts
+    assert counts["decode_span"] in (0, 1), counts
 
 
 # ----------------------------------------------------------------------
@@ -507,7 +517,7 @@ cfg = reduced_config("yi-6b")
 params = api.init(cfg, jax.random.PRNGKey(0))
 reqs = sharegpt_like_requests(6, cfg.vocab_size, max_input=16,
                               max_output=8, seed=3)
-KW = dict(batch_slots=3, max_len=64, chunk=8, span=4, paged=True,
+KW = dict(batch_slots=3, max_len=64, chunk=8, span=4,
           block_size=8, prefix_cache=True, spec_decode=2)
 
 outs = {}

@@ -6,12 +6,12 @@ under pool pressure, and cache-aware admission."""
 import jax
 import numpy as np
 import pytest
+from conftest import greedy_reference
 
 from repro.configs import reduced_config
 from repro.models import api
 from repro.runtime.prefix_cache import BlockPool, RadixPrefixCache
-from repro.runtime.server import (ChunkedServer, Request, SlotServer,
-                                  clone_requests,
+from repro.runtime.server import (ChunkedServer, Request, clone_requests,
                                   sysprompt_sharegpt_requests)
 
 BS = 4  # block size for the unit tests
@@ -199,10 +199,10 @@ def test_shared_prefix_outputs_bit_identical(setup):
                                        max_output=8, seed=3)
     base = clone_requests(reqs)
     ChunkedServer(cfg, params, batch_slots=3, max_len=64, chunk=8,
-                  span=4, paged=True, block_size=8,
+                  span=4, block_size=8,
                   prefix_cache=False).serve(base)
     srv = ChunkedServer(cfg, params, batch_slots=3, max_len=64, chunk=8,
-                        span=4, paged=True, block_size=8,
+                        span=4, block_size=8,
                         prefix_cache=True)
     cold = clone_requests(reqs)
     stats = srv.serve(cold)
@@ -232,7 +232,7 @@ def test_cow_divergence_no_cross_request_corruption(setup):
     ra, rb = (Request(rid=0, prompt=base, max_new=6),
               Request(rid=1, prompt=div, max_new=6))
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                        span=4, paged=True, block_size=8)
+                        span=4, block_size=8)
     srv.serve([clone_requests([ra])[0]])          # cache the base prefix
     got_a, got_b = clone_requests([ra])[0], clone_requests([rb])[0]
     stats = srv.serve([got_b, got_a])
@@ -242,7 +242,7 @@ def test_cow_divergence_no_cross_request_corruption(setup):
     for req in (clone_requests([ra])[0], clone_requests([rb])[0]):
         ref = clone_requests([req])[0]
         ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                      span=4, paged=True, block_size=8,
+                      span=4, block_size=8,
                       prefix_cache=False).serve([ref])
         got = got_a if req.rid == 0 else got_b
         assert got.output == ref.output, req.rid
@@ -254,7 +254,7 @@ def test_refcount_invariants_across_waves(setup):
     every block either free or tree-resident, partition intact."""
     cfg, params = setup
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                        span=4, paged=True, block_size=8, num_blocks=10)
+                        span=4, block_size=8, num_blocks=10)
     for seed in range(4):
         reqs = sysprompt_sharegpt_requests(
             4, cfg.vocab_size, num_templates=2, template_len=16,
@@ -274,7 +274,7 @@ def test_lru_eviction_under_pool_pressure(setup):
     serving bit-identical outputs by evicting refcount-0 blocks."""
     cfg, params = setup
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                        span=4, paged=True, block_size=8, num_blocks=8)
+                        span=4, block_size=8, num_blocks=8)
     evictions = 0.0
     for seed in range(4):
         wave = sysprompt_sharegpt_requests(
@@ -284,7 +284,7 @@ def test_lru_eviction_under_pool_pressure(setup):
         evictions += stats["cache_evictions"]
         fresh = clone_requests(wave)
         ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                      span=4, paged=True, block_size=8,
+                      span=4, block_size=8,
                       prefix_cache=False).serve(fresh)
         for rw, rf in zip(wave, fresh):
             assert rw.output == rf.output, (seed, rw.rid)
@@ -300,7 +300,7 @@ def test_fully_cached_prompt_admits_under_memory_pressure(setup):
     rng = np.random.default_rng(7)
     prompt = rng.integers(0, cfg.vocab_size, 32).astype(np.int32)
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                        span=4, paged=True, block_size=8, num_blocks=6)
+                        span=4, block_size=8, num_blocks=6)
     first = Request(rid=0, prompt=prompt, max_new=8)
     srv.serve([first])
     # total worst case is 5 blocks but the free list holds fewer: only
@@ -323,7 +323,7 @@ def test_cow_pin_does_not_starve_tight_pool(setup):
     rng = np.random.default_rng(13)
     prompt = rng.integers(0, cfg.vocab_size, 28).astype(np.int32)
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                        span=4, paged=True, block_size=8, num_blocks=5)
+                        span=4, block_size=8, num_blocks=5)
     first = Request(rid=0, prompt=prompt, max_new=6)
     srv.serve([first])                    # tree retains 4 of 5 blocks
     again = Request(rid=1, prompt=prompt.copy(), max_new=6)
@@ -337,7 +337,7 @@ def test_empty_prompt_serves_with_prefix_cache(setup):
     of tripping the prefix-match index math."""
     cfg, params = setup
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=32, chunk=4,
-                        span=2, paged=True, block_size=8)
+                        span=2, block_size=8)
     reqs = [Request(rid=0, prompt=np.zeros(0, np.int32), max_new=3),
             Request(rid=1, prompt=np.zeros(0, np.int32), max_new=3)]
     srv.serve(reqs)                      # second request re-matches the
@@ -351,7 +351,7 @@ def test_peak_blocks_measures_working_set_not_residency(setup):
     inflate the peak/pool-utilization footprint metrics."""
     cfg, params = setup
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                        span=4, paged=True, block_size=8)
+                        span=4, block_size=8)
     prompt = np.arange(24, dtype=np.int32) % cfg.vocab_size
     stats1 = srv.serve([Request(rid=0, prompt=prompt, max_new=6)])
     assert srv.prefix_cache.cached_block_count() > 0   # residue retained
@@ -363,53 +363,68 @@ def test_peak_blocks_measures_working_set_not_residency(setup):
 
 
 # ----------------------------------------------------------------------
-# EOS stopping (both engines)
+# EOS stopping
 # ----------------------------------------------------------------------
 
 def test_eos_stopping_matches_both_engines(setup):
     """Device-side tok == eos_id folds into the stop mask: outputs are
-    the no-eos outputs truncated at (and including) the first EOS, and
-    both engines agree bit for bit."""
+    the no-eos outputs truncated at (and including) the first EOS, as
+    the scheduler-free reference stops them."""
     cfg, params = setup
     reqs = sysprompt_sharegpt_requests(5, cfg.vocab_size, num_templates=2,
                                        template_len=8, max_input=16,
                                        max_output=10, seed=3)
-    ref = clone_requests(reqs)
-    ChunkedServer(cfg, params, batch_slots=3, max_len=64, chunk=8,
-                  span=4).serve(ref)
+    ref, _ = greedy_reference(cfg, params, reqs, max_len=64)
     # pick an eos that provably fires mid-stream for some request
-    donor = max(ref, key=lambda r: len(r.output))
-    eos = donor.output[len(donor.output) // 2]
+    donor = max(ref, key=len)
+    eos = donor[len(donor) // 2]
 
     def truncated(out):
         return out[:out.index(eos) + 1] if eos in out else out
 
-    chunked, slot = clone_requests(reqs), clone_requests(reqs)
+    ref_eos, _ = greedy_reference(cfg, params, reqs, max_len=64,
+                                  eos_id=eos)
+    chunked = clone_requests(reqs)
     ChunkedServer(cfg, params, batch_slots=3, max_len=64, chunk=8,
                   span=4, eos_id=eos).serve(chunked)
-    SlotServer(cfg, params, batch_slots=3, max_len=64,
-               eos_id=eos).serve(slot)
     stopped_early = 0
-    for rr, rc, rs in zip(ref, chunked, slot):
-        want = truncated(rr.output)
-        assert rc.output == want, rr.rid
-        assert rs.output == want, rr.rid
-        stopped_early += len(want) < len(rr.output)
+    for rc, full, want in zip(chunked, ref, ref_eos):
+        assert want == truncated(full), rc.rid
+        assert rc.output == want, rc.rid
+        stopped_early += len(want) < len(full)
     assert stopped_early > 0
 
 
 def test_slot_server_serves_full_queue_on_instant_stops(setup):
     """Every admitted request stopping on its first token (max_new=1,
-    or an immediate EOS) must not abandon the still-queued rest."""
+    or an immediate EOS) must not abandon the still-queued rest: five
+    such requests through two slots all come back, each with the
+    reference's one token."""
     cfg, params = setup
     rng = np.random.default_rng(17)
     reqs = [Request(rid=i, prompt=rng.integers(
                 0, cfg.vocab_size, 6).astype(np.int32), max_new=1)
             for i in range(5)]
-    stats = SlotServer(cfg, params, batch_slots=2, max_len=32).serve(reqs)
+    ref, _ = greedy_reference(cfg, params, reqs, max_len=32)
+    stats = ChunkedServer(cfg, params, batch_slots=2, max_len=32,
+                          chunk=4, span=2).serve(reqs)
     assert all(r.done for r in reqs)
+    assert [r.output for r in reqs] == ref
     assert all(len(r.output) == 1 for r in reqs)
     assert stats["tokens"] == sum(len(r.prompt) + 1 for r in reqs)
+
+    # the same prompts stopped by EOS instead: request 0's first token
+    # is the eos, so it stops at once and the rest stop where they
+    # emit it (or at max_new)
+    eos = ref[0][0]
+    again = [Request(rid=r.rid, prompt=r.prompt.copy(), max_new=4)
+             for r in reqs]
+    want, _ = greedy_reference(cfg, params, again, max_len=32, eos_id=eos)
+    ChunkedServer(cfg, params, batch_slots=2, max_len=32, chunk=4,
+                  span=2, eos_id=eos).serve(again)
+    assert all(r.done for r in again)
+    assert [r.output for r in again] == want
+    assert want[0] == [eos]
 
 
 def test_eos_none_preserves_length_only_stopping(setup):

@@ -129,8 +129,8 @@ def test_roofline_row_format():
 
 def test_paged_decode_kv_bytes_ratios():
     """Pin the modeled byte ratios of the three paged decode read
-    paths (core/roofline.paged_decode_kv_bytes) that BENCH_serving's
-    `modeled_decode_speedup` reports."""
+    paths (core/roofline.paged_decode_kv_bytes): the kernel moves
+    fewer bytes than the gather, and the fp8 kernel fewer still."""
     from repro.core import roofline
     kw = dict(block_size=16, max_blocks=8, kv_heads=4, head_dim=64)
     full = 16 * 8

@@ -266,7 +266,7 @@ def setup():
     return cfg, params
 
 
-BASE_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4, paged=True,
+BASE_KW = dict(batch_slots=3, max_len=64, chunk=8, span=4,
                block_size=8, prefix_cache=True)
 
 
@@ -314,7 +314,7 @@ def test_degenerate_sampling_is_bitwise_greedy_on_every_mix(setup):
 @pytest.mark.parametrize("combo", [
     {"spec_decode": 3},
     {"kernel": True},
-    {"paged": False, "prefix_cache": False},
+    {"prefix_cache": False},
 ], ids=["spec", "kernel", "dense"])
 def test_degenerate_sampling_is_bitwise_greedy_across_combos(
         setup, combo):

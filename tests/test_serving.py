@@ -1,5 +1,6 @@
-"""Chunked-prefill serving runtime: chunk math, output parity with the
-slot baseline, O(1) compilation, and scheduler behavior."""
+"""Chunked-prefill serving runtime: chunk math, greedy token parity
+with a scheduler-free reference (``conftest.greedy_reference``), O(1)
+compilation, and scheduler behavior."""
 
 import dataclasses
 
@@ -7,13 +8,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import greedy_reference
 
 from repro.analysis.jaxpr_check import iter_eqns
 from repro.configs import reduced_config
 from repro.models import api, transformer
-from repro.runtime.server import (ChunkedServer, Server, SlotServer,
-                                  Request, clone_requests,
-                                  sharegpt_like_requests)
+from repro.runtime.server import (ChunkedServer, Server, Request,
+                                  clone_requests, repetitive_requests,
+                                  sharegpt_like_requests,
+                                  sysprompt_sharegpt_requests)
 
 
 @pytest.fixture(scope="module")
@@ -21,6 +24,45 @@ def setup():
     cfg = reduced_config("yi-6b")
     params = api.init(cfg, jax.random.PRNGKey(0))
     return cfg, params
+
+
+MIXES = {
+    "sharegpt": lambda V: sharegpt_like_requests(
+        6, V, max_input=16, max_output=8, seed=3),
+    "sysprompt": lambda V: sysprompt_sharegpt_requests(
+        8, V, num_templates=2, template_len=24, max_input=40,
+        max_output=8, seed=3),
+    "repetitive": lambda V: repetitive_requests(
+        4, V, motif_len=8, reps=3, max_output=24, seed=0),
+}
+VARIANTS = {
+    "defaults": {},
+    "no_prefix_cache": {"prefix_cache": False},
+    "spec_decode": {"spec_decode": 4},
+}
+PARITY_MAX_LEN = 64
+
+
+@pytest.fixture(scope="module")
+def parity(setup):
+    """One server per variant and one reference per mix, built once
+    for the module; every mix is served through each variant in turn,
+    so later mixes also meet the radix tree and n-gram table the
+    earlier ones left behind."""
+    cfg, params = setup
+    servers = {name: ChunkedServer(cfg, params, batch_slots=3,
+                                   max_len=PARITY_MAX_LEN, chunk=8,
+                                   span=4, **kw)
+               for name, kw in VARIANTS.items()}
+    refs = {}
+
+    def reference(mix):
+        if mix not in refs:
+            reqs = MIXES[mix](cfg.vocab_size)
+            refs[mix] = (reqs, greedy_reference(cfg, params, reqs,
+                                                max_len=PARITY_MAX_LEN))
+        return refs[mix]
+    return servers, reference
 
 
 def test_default_server_is_chunked():
@@ -64,25 +106,31 @@ def test_chunk_step_matches_decode_path(setup):
         np.asarray(ref_cache["k"][:, :, :L], jnp.float32))
 
 
-def test_chunked_matches_slot_server_outputs(setup):
-    """Greedy token parity on a fixed ShareGPT-like request set."""
-    cfg, params = setup
-    reqs = sharegpt_like_requests(6, cfg.vocab_size, max_input=16,
-                                  max_output=8, seed=3)
-    a, b = clone_requests(reqs), clone_requests(reqs)
-    SlotServer(cfg, params, batch_slots=3, max_len=64).serve(a)
-    ChunkedServer(cfg, params, batch_slots=3, max_len=64,
-                  chunk=8, span=4).serve(b)
-    assert all(r.done for r in a) and all(r.done for r in b)
-    for ra, rb in zip(a, b):
-        assert ra.output == rb.output, (ra.rid, ra.output, rb.output)
+@pytest.mark.parametrize("variant", list(VARIANTS))
+@pytest.mark.parametrize("mix", list(MIXES))
+def test_chunked_matches_greedy_reference(parity, mix, variant):
+    """Greedy tokens of every mix, through the default server, the one
+    without a prefix cache and the speculative one, equal the
+    scheduler-free reference token for token; each program compiles at
+    most once and the pool's peak stays inside the pool."""
+    servers, reference = parity
+    reqs, (ref, ref_trunc) = reference(mix)
+    srv = servers[variant]
+    got = clone_requests(reqs)
+    stats = srv.serve(got)
+    assert all(r.done for r in got)
+    for r, want, trunc in zip(got, ref, ref_trunc):
+        assert r.output == want, (r.rid, r.output, want)
+        assert r.truncated == trunc
+    counts = srv.compile_counts()
+    assert all(0 <= v <= 1 for v in counts.values()), counts
+    assert stats["peak_blocks_in_use"] <= stats["pool_blocks"]
 
 
 def test_compile_count_independent_of_prompt_lengths(setup):
     """8 prompts of 8 distinct lengths -> a bounded number of compiled
     programs; a second batch with 8 MORE distinct lengths compiles
-    nothing new.  (The slot baseline compiles one prefill program per
-    distinct length.)"""
+    nothing new."""
     cfg, params = setup
     rng = np.random.default_rng(0)
 
@@ -122,27 +170,26 @@ def test_chunked_server_respects_limits(setup):
 
 
 def test_max_new_one_and_oversized_prompt(setup):
-    """max_new=1 yields exactly one token (both engines, in lockstep);
-    prompts longer than max_len are rejected loudly instead of
-    clamp-corrupting the cache tail."""
+    """max_new=1 yields exactly one token, the reference's; prompts
+    longer than max_len are rejected loudly instead of being served
+    cut."""
     cfg, params = setup
     rng = np.random.default_rng(1)
     reqs = [Request(rid=0, prompt=rng.integers(
         0, cfg.vocab_size, 5).astype(np.int32), max_new=1)]
-    a, b = clone_requests(reqs), clone_requests(reqs)
-    SlotServer(cfg, params, batch_slots=2, max_len=32).serve(a)
+    ref, _ = greedy_reference(cfg, params, reqs, max_len=32)
+    b = clone_requests(reqs)
     ChunkedServer(cfg, params, batch_slots=2, max_len=32,
                   chunk=4, span=2).serve(b)
-    assert len(a[0].output) == 1
-    assert a[0].output == b[0].output
+    assert len(b[0].output) == 1
+    assert b[0].output == ref[0]
 
     too_long = [Request(rid=1, prompt=rng.integers(
         0, cfg.vocab_size, 40).astype(np.int32), max_new=4)]
-    for srv in (SlotServer(cfg, params, batch_slots=2, max_len=32),
-                ChunkedServer(cfg, params, batch_slots=2, max_len=32,
-                              chunk=4, span=2)):
-        with pytest.raises(ValueError, match="exceeds max_len"):
-            srv.serve(clone_requests(too_long))
+    srv = ChunkedServer(cfg, params, batch_slots=2, max_len=32,
+                        chunk=4, span=2)
+    with pytest.raises(ValueError, match="exceeds max_len"):
+        srv.serve(clone_requests(too_long))
 
 
 def test_chunk_larger_than_longest_prompt(setup):
@@ -161,32 +208,6 @@ def test_chunk_larger_than_longest_prompt(setup):
 # paged KV cache
 # ----------------------------------------------------------------------
 
-def test_paged_matches_contiguous_outputs(setup):
-    """Paged and contiguous ChunkedServer must be greedy bit-identical
-    on the Table XII-style mix, both with O(1) compiled programs."""
-    cfg, params = setup
-    reqs = sharegpt_like_requests(8, cfg.vocab_size, max_input=16,
-                                  max_output=8, seed=11)
-    a, b = clone_requests(reqs), clone_requests(reqs)
-    contiguous = ChunkedServer(cfg, params, batch_slots=3, max_len=64,
-                               chunk=8, span=4, paged=False)
-    paged = ChunkedServer(cfg, params, batch_slots=3, max_len=64,
-                          chunk=8, span=4, paged=True, block_size=8)
-    contiguous.serve(a)
-    stats = paged.serve(b)
-    assert all(r.done for r in a) and all(r.done for r in b)
-    for ra, rb in zip(a, b):
-        assert ra.output == rb.output, (ra.rid, ra.output, rb.output)
-    for srv in (contiguous, paged):
-        counts = srv.compile_counts()
-        assert all(v >= 0 for v in counts.values()), counts
-        assert sum(counts.values()) <= 3, counts
-    # pool metrics come back with the stats and the default pool already
-    # undercuts the contiguous layout's + chunk headroom
-    assert stats["peak_blocks_in_use"] <= stats["pool_blocks"]
-    assert stats["kv_tokens_capacity"] < stats["kv_tokens_contiguous"]
-
-
 def test_paged_block_reuse_no_stale_kv(setup):
     """Two request waves through the same pool: wave 2 decodes on
     recycled physical blocks and must match a fresh server bit for bit
@@ -201,7 +222,7 @@ def test_paged_block_reuse_no_stale_kv(setup):
     wave2 = sharegpt_like_requests(5, cfg.vocab_size, max_input=16,
                                    max_output=8, seed=22)
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64,
-                        chunk=8, span=4, paged=True, block_size=8)
+                        chunk=8, span=4, block_size=8)
     srv.serve(wave1)
     # every block reference dropped at harvest; blocks are either free
     # or cached-and-evictable, never leaked
@@ -213,7 +234,7 @@ def test_paged_block_reuse_no_stale_kv(setup):
     srv.serve(reused)
     fresh = clone_requests(wave2)
     ChunkedServer(cfg, params, batch_slots=2, max_len=64,
-                  chunk=8, span=4, paged=True, block_size=8).serve(fresh)
+                  chunk=8, span=4, block_size=8).serve(fresh)
     for ra, rb in zip(reused, fresh):
         assert ra.output == rb.output, (ra.rid, ra.output, rb.output)
 
@@ -227,7 +248,7 @@ def test_paged_pool_backpressure(setup):
     # each request reserves at most ceil(24/8)=3 blocks; 4 blocks force
     # one-at-a-time admission even though 3 slots exist
     srv = ChunkedServer(cfg, params, batch_slots=3, max_len=64,
-                        chunk=8, span=4, paged=True, block_size=8,
+                        chunk=8, span=4, block_size=8,
                         num_blocks=4)
     stats = srv.serve(clone_requests(reqs))
     assert stats["admission_stalls"] > 0
@@ -235,12 +256,12 @@ def test_paged_pool_backpressure(setup):
     # throttled admission must not change the greedy outputs
     throttled = clone_requests(reqs)
     srv2 = ChunkedServer(cfg, params, batch_slots=3, max_len=64,
-                         chunk=8, span=4, paged=True, block_size=8,
+                         chunk=8, span=4, block_size=8,
                          num_blocks=4)
     srv2.serve(throttled)
     roomy = clone_requests(reqs)
     ChunkedServer(cfg, params, batch_slots=3, max_len=64,
-                  chunk=8, span=4, paged=True, block_size=8).serve(roomy)
+                  chunk=8, span=4, block_size=8).serve(roomy)
     for ra, rb in zip(throttled, roomy):
         assert ra.output == rb.output, (ra.rid, ra.output, rb.output)
 
@@ -250,7 +271,7 @@ def test_paged_pool_too_small_raises(setup):
     cfg, params = setup
     rng = np.random.default_rng(4)
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=64,
-                        chunk=8, span=4, paged=True, block_size=8,
+                        chunk=8, span=4, block_size=8,
                         num_blocks=2)
     req = Request(rid=0, prompt=rng.integers(
         0, cfg.vocab_size, 30).astype(np.int32), max_new=8)
@@ -261,24 +282,22 @@ def test_paged_pool_too_small_raises(setup):
 def test_truncation_flagged_both_engines(setup):
     """in_len + max_new past the pos cap is no longer a silent short
     harvest: the request is flagged truncated at admission and capped at
-    max_len - in_len tokens (both engines, identical tokens)."""
+    max_len - in_len tokens, with the reference's tokens and flags."""
     cfg, params = setup
     rng = np.random.default_rng(9)
     in_len, max_len = 28, 32
     prompt = rng.integers(0, cfg.vocab_size, in_len).astype(np.int32)
     reqs = [Request(rid=0, prompt=prompt, max_new=16),
             Request(rid=1, prompt=prompt.copy(), max_new=2)]
-    a, b = clone_requests(reqs), clone_requests(reqs)
-    SlotServer(cfg, params, batch_slots=2, max_len=max_len).serve(a)
+    ref, ref_trunc = greedy_reference(cfg, params, reqs, max_len=max_len)
+    b = clone_requests(reqs)
     ChunkedServer(cfg, params, batch_slots=2, max_len=max_len,
                   chunk=8, span=4).serve(b)
-    for served in (a, b):
-        assert served[0].truncated
-        assert len(served[0].output) == max_len - in_len
-        assert not served[1].truncated
-        assert len(served[1].output) == 2
-    for ra, rb in zip(a, b):
-        assert ra.output == rb.output
+    assert b[0].truncated and ref_trunc[0]
+    assert len(b[0].output) == max_len - in_len
+    assert not b[1].truncated and not ref_trunc[1]
+    assert len(b[1].output) == 2
+    assert [r.output for r in b] == ref
 
 
 def test_host_mirror_dtypes_are_int32(setup):

@@ -114,20 +114,6 @@ def test_spec_matches_span_on_sysprompt_mix(setup):
     srv.prefix_cache.check_invariants()
 
 
-def test_spec_parity_contiguous_layout(setup):
-    """paged=False still supports spec decode: rejected rows land in
-    the chunk headroom and are overwritten before becoming visible."""
-    cfg, params = setup
-    reqs = sharegpt_like_requests(4, cfg.vocab_size, max_input=12,
-                                  max_output=8, seed=8)
-    a, b = clone_requests(reqs), clone_requests(reqs)
-    ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                  span=4, paged=False).serve(a)
-    ChunkedServer(cfg, params, batch_slots=2, max_len=64, chunk=8,
-                  span=4, paged=False, spec_decode=4).serve(b)
-    _outputs_match(a, b)
-
-
 def test_spec_off_by_default_keeps_span_path(setup):
     cfg, params = setup
     srv = ChunkedServer(cfg, params, batch_slots=2, max_len=32,

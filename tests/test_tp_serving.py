@@ -44,7 +44,7 @@ mixes = {
     "repetitive": repetitive_requests(
         4, cfg.vocab_size, motif_len=4, reps=3, max_output=10, seed=5),
 }
-KW = dict(batch_slots=3, max_len=64, chunk=8, span=4, paged=True,
+KW = dict(batch_slots=3, max_len=64, chunk=8, span=4,
           block_size=8, prefix_cache=True, spec_decode=2)
 
 results = {}
