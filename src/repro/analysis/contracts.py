@@ -13,9 +13,10 @@ from typing import Any, Dict, List, Optional, Set
 # trace hooks (jax.ad_checkpoint.checkpoint_name tags in the model)
 # ----------------------------------------------------------------------
 
-# every cross-shard grouped reduction tags its fp32 partials with this
-# prefix (common.fixed_tree_sum(tag=...)); JX004 asserts the tagged
-# aval is float32, JX006 that serving traces carry at least one tag
+# every cross-shard reduction (the row-parallel out_project / mlp
+# down-projection dots) tags its accumulator with this prefix; JX004
+# asserts the tagged aval is float32, JX006 that serving traces carry
+# at least one tag
 XSHARD_TAG_PREFIX = "xshard_"
 
 # the serving forward tags its final hidden state; a serving program

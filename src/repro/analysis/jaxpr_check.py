@@ -16,13 +16,13 @@ Rules:
   must carry one ``tf.aliasing_output`` per cache leaf; without
   donation XLA materializes a second pool per step).
 * **JX004** — a ``checkpoint_name`` tag starting with ``xshard_``
-  (the grouped cross-shard reduction hooks) whose aval is not fp32.
+  (the cross-shard reduction hooks) whose aval is not fp32.
 * **JX005** — abstract-signature drift: combos sharing a cache layout
   (contracts.signature_class) must present identical operand
   signatures per program, or the switch recompiles.
 * **JX006** — a serving trace missing its hooks: no ``serving_hot_path``
   tag (the forward didn't go through ``_serving_scan``), or no
-  ``xshard_`` tag when the combo uses the grouped-reduction linears.
+  ``xshard_`` tag when the combo uses the bf16 (not fp8) linears.
 """
 
 from __future__ import annotations
@@ -120,7 +120,7 @@ def _check_jaxpr(label: str, program: str, jaxpr, combo: Dict[str, Any],
         report.add(Finding(
             "JX006",
             f"{program} [{label}]: no `{contracts.XSHARD_TAG_PREFIX}*` "
-            f"reduction tags — the grouped fixed-tree reductions are "
+            f"reduction tags — the fp32 row-parallel projections are "
             f"not in the trace",
             detail={"program": program, "combo": label,
                     "missing": contracts.XSHARD_TAG_PREFIX + "*"}))

@@ -23,8 +23,8 @@ RULES: Dict[str, tuple] = {
                        "program"),
     "JX003": ("error", "KV cache operand is not donated to the serving "
                        "step (a second pool would be materialized)"),
-    "JX004": ("error", "cross-shard grouped reduction does not "
-                       "accumulate in fp32 (tp-vs-1 parity contract)"),
+    "JX004": ("error", "cross-shard reduction does not accumulate in "
+                       "fp32 (tp-vs-1 agreement contract)"),
     "JX005": ("error", "abstract signature drift between flag combos "
                        "sharing a cache layout (would recompile)"),
     "JX006": ("error", "serving program traced without its trace hooks "

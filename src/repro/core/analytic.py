@@ -214,9 +214,18 @@ def analyze_cell(cfg: ModelConfig, shape: ShapeConfig,
         # pod axis: pure-DP gradient all-reduce (2x for ring AR)
         add("pod", "all-reduce", 2.0 * n_total / (data_n * tp) * BF16)
     if tp > 1:
-        # TP: 2 all-reduces per block fwd (+2 bwd if train), ring AR = 2x
+        # TP: 2 all-reduces per block fwd (+2 bwd if train), ring AR = 2x.
+        # The serving forward (decode of the transformer families) sums
+        # the out-projection's and the dense MLP's shard partials in fp32
+        # (models/attention.out_project, mlp.mlp with fp32_reduce=True)
+        if decode and cfg.family in ("dense", "vlm"):
+            ar_bytes = FP32
+        elif decode and cfg.family == "moe":
+            ar_bytes = (FP32 + BF16) / 2
+        else:
+            ar_bytes = BF16
         n_ar = _n_blocks(cfg) * (4.0 if train else 2.0)
-        add("model", "all-reduce", 2.0 * n_ar * tokens_dev * d * BF16)
+        add("model", "all-reduce", 2.0 * n_ar * tokens_dev * d * ar_bytes)
         if cfg.family == "moe":
             a2a = 2.0 * tokens_dev * cfg.top_k * d * BF16 \
                 * (2.0 if train else 1.0)

@@ -18,8 +18,9 @@ turns on speculative decoding (n-gram drafts + one-dispatch verify,
 bit-identical outputs); pair it with ``--workload repetitive`` to see
 the accepted-tokens-per-step climb above 1.  ``--tp N`` serves
 tensor-parallel over an N-device mesh (weights head-wise/column-row,
-KV pool along the KV-head axis; token-identical outputs on the CPU) and
-prints the per-device sharding stats:
+KV pool along the KV-head axis; outputs agree with tp=1 to the order
+of the projections' fp32 sums) and prints the per-device sharding
+stats:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
         PYTHONPATH=src python -m repro.launch.serve --reduced --tp 4
@@ -113,8 +114,8 @@ def main() -> None:
                          "shard the weights head-wise/column-row-wise "
                          "and the paged KV pool along its KV-head axis "
                          "over an N-device mesh "
-                         "(sharding/plans.ServingPlan); on the CPU "
-                         "greedy outputs are token-identical to tp=1.  "
+                         "(sharding/plans.ServingPlan); outputs agree "
+                         "with tp=1 to the order of fp32 sums.  "
                          "On CPU fan "
                          "devices out first: XLA_FLAGS=--xla_force_"
                          "host_platform_device_count=N")

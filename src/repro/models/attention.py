@@ -15,8 +15,9 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-from repro.models.common import ParamSpec, apply_rope, fixed_tree_sum
+from repro.models.common import ParamSpec, apply_rope
 from repro.sharding.axes import constrain
 
 NEG_INF = -1e30
@@ -65,26 +66,22 @@ def qkv_project(cfg, p, x: jax.Array
     return q, k, v
 
 
-def out_project(p, o: jax.Array, *, groups: int = 0) -> jax.Array:
-    """o [B,S,H,hd] -> [B,S,d] through the row-parallel wo.
+def out_project(p, o: jax.Array, *, fp32_reduce: bool = False
+                ) -> jax.Array:
+    """o [B,S,H,hd] -> [B,S,d] through the row-parallel wo, one
+    contraction over (heads, hd).
 
-    With ``groups > 1`` (serving: transformer.serving_det_groups) the
-    head contraction is restructured as `groups` partial einsums in
-    fp32 reduced by ``common.fixed_tree_sum`` — an addition order fixed
-    by the group count alone, so a tensor-parallel mesh sharding the
-    head axis over any tp dividing `groups` yields bitwise-identical
-    outputs to tp=1 (a plain einsum would psum per-device partials in
-    a layout-dependent order).  ``groups=0`` keeps the single-einsum
-    training path.
+    ``fp32_reduce`` (the serving forward, transformer._serving_scan):
+    the output stays fp32 until one cast to the activation dtype
+    (tagged for the analyzer's JX004/JX006), so under a tensor-parallel
+    mesh the psum of the head shards runs in fp32.  Training keeps the
+    bf16 output, which remat="dots" saves and a tp plan all-reduces.
     """
     wo = p["wo"].astype(o.dtype)
-    if groups > 1:
-        B, S, H, hd = o.shape
-        og = o.reshape(B, S, groups, H // groups, hd)
-        wg = wo.reshape(groups, H // groups, hd, wo.shape[-1])
-        parts = jnp.einsum("bsghk,ghkd->gbsd", og, wg,
-                           preferred_element_type=jnp.float32)
-        y = fixed_tree_sum(parts, tag="xshard_attn_out").astype(o.dtype)
+    if fp32_reduce:
+        y = jnp.einsum("bshk,hkd->bsd", o, wo,
+                       preferred_element_type=jnp.float32)
+        y = checkpoint_name(y, "xshard_attn_out").astype(o.dtype)
     else:
         y = jnp.einsum("bshk,hkd->bsd", o, wo)
     return constrain(y, ("batch", "seq", "embed"))
@@ -95,8 +92,7 @@ def qkv_project_fp8(cfg, p, q8, x: jax.Array
     """fp8 serving variant of `qkv_project`: x through weights
     pre-quantized to e4m3 by te/linear.quantize_serving_params, with a
     fresh per-call activation scale (te/linear.fp8_serving_dot).
-    Biases, if any, stay in the bf16 params `p`.  tp=1 serving only —
-    there is no grouped/deterministic-reduction structure here."""
+    Biases, if any, stay in the bf16 params `p`.  tp=1 serving only."""
     from repro.te import linear as te_linear
     q = te_linear.fp8_serving_dot(x, q8["wq"])
     k = te_linear.fp8_serving_dot(x, q8["wk"])

@@ -15,7 +15,6 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 # ----------------------------------------------------------------------
@@ -115,41 +114,6 @@ def partition_specs(specs, rules: Dict[str, Optional[str]],
         return P(*out)
 
     return jax.tree_util.tree_map(resolve, specs, is_leaf=is_spec)
-
-
-def fixed_tree_sum(parts: jax.Array, *,
-                   tag: Optional[str] = None) -> jax.Array:
-    """Sum over the leading axis with a FIXED halving tree.
-
-    Pads the axis to a power of two with zeros, then repeatedly adds
-    the upper half onto the lower half.  The floating-point addition
-    order therefore depends only on the (padded) group count — never on
-    how the axis is laid out over a device mesh — so a contraction
-    restructured as per-group partials + ``fixed_tree_sum`` produces
-    bitwise-identical results whether the group axis lives on one
-    device or is sharded tensor-parallel over any degree that divides
-    it.  This is what makes tp>1 serving token-identical to tp=1
-    (sharding/plans.ServingPlan): a plain sharded einsum would psum
-    per-device partials in a data-layout-dependent order.
-
-    ``tag`` (convention: ``xshard_<site>``) marks the partials with a
-    ``checkpoint_name`` so the static analyzer (repro.analysis, rule
-    JX004) can find every cross-shard reduction in a serving jaxpr and
-    verify it accumulates in fp32.
-    """
-    if tag is not None:
-        parts = checkpoint_name(parts, tag)
-    n = parts.shape[0]
-    p2 = 1
-    while p2 < n:
-        p2 *= 2
-    if p2 != n:
-        parts = jnp.pad(parts,
-                        [(0, p2 - n)] + [(0, 0)] * (parts.ndim - 1))
-    while parts.shape[0] > 1:
-        h = parts.shape[0] // 2
-        parts = parts[:h] + parts[h:]
-    return parts[0]
 
 
 def count_params(specs) -> int:

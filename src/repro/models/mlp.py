@@ -6,8 +6,9 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from repro.models.common import ParamSpec, activation, fixed_tree_sum
+from repro.models.common import ParamSpec, activation
 from repro.sharding.axes import constrain
 
 
@@ -27,14 +28,16 @@ def mlp_specs(cfg, d_model: Optional[int] = None,
     return specs
 
 
-def mlp(cfg, p, x: jax.Array, *, groups: int = 0) -> jax.Array:
+def mlp(cfg, p, x: jax.Array, *, fp32_reduce: bool = False) -> jax.Array:
     """x: [..., d] -> [..., d].
 
-    ``groups > 1`` (serving, [B,S,d] inputs only) restructures the
-    row-parallel w_down contraction as per-group fp32 partials reduced
-    by a fixed halving tree — the same order-deterministic reduction as
-    attention.out_project, so tensor-parallel sharding of the hidden
-    dim over any tp dividing `groups` is bitwise-identical to tp=1.
+    ``fp32_reduce`` (the serving forward, transformer._serving_scan):
+    the row-parallel w_down contraction is one dot whose output stays
+    fp32 until one cast to the activation dtype (tagged for the
+    analyzer's JX004/JX006), so under a tensor-parallel mesh the psum of
+    the hidden-dim shards runs in fp32.  Training keeps the dot's bf16
+    output: remat="dots" saves it as a residual, and a tp plan
+    all-reduces it, at half the bytes of fp32.
     """
     dt = x.dtype
     up = x @ p["w_up"].astype(dt)
@@ -43,13 +46,10 @@ def mlp(cfg, p, x: jax.Array, *, groups: int = 0) -> jax.Array:
     gate = x @ p["w_gate"].astype(dt) if "w_gate" in p else None
     h = activation(cfg, up, gate)
     h = constrain(h, ("batch", None, "mlp"))
-    if groups > 1 and h.ndim == 3:
-        B, S, f = h.shape
-        hg = h.reshape(B, S, groups, f // groups)
-        wg = p["w_down"].astype(dt).reshape(groups, f // groups, -1)
-        parts = jnp.einsum("bsgf,gfd->gbsd", hg, wg,
-                           preferred_element_type=jnp.float32)
-        y = fixed_tree_sum(parts, tag="xshard_mlp_down").astype(dt)
+    if fp32_reduce:
+        y = jnp.einsum("...f,fd->...d", h, p["w_down"].astype(dt),
+                       preferred_element_type=jnp.float32)
+        y = checkpoint_name(y, "xshard_mlp_down").astype(dt)
     else:
         y = h @ p["w_down"].astype(dt)
     if cfg.mlp_bias:
@@ -60,8 +60,7 @@ def mlp(cfg, p, x: jax.Array, *, groups: int = 0) -> jax.Array:
 def mlp_fp8(cfg, p, q8, x: jax.Array) -> jax.Array:
     """fp8 serving variant of `mlp`: matmuls through weights
     pre-quantized by te/linear.quantize_serving_params with per-call
-    activation scales.  Biases stay bf16 in `p`.  tp=1 serving only,
-    so there is no grouped-reduction path here."""
+    activation scales.  Biases stay bf16 in `p`.  tp=1 serving only."""
     from repro.te import linear as te_linear
     dt = x.dtype
     up = te_linear.fp8_serving_dot(x, q8["w_up"])

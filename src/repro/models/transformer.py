@@ -176,23 +176,6 @@ def loss_fn(cfg, params, batch: Dict[str, jax.Array]) -> jax.Array:
 # serving: prefill + single-token decode over a KV cache
 # ----------------------------------------------------------------------
 
-def serving_det_groups(cfg) -> Tuple[int, int]:
-    """(attention, mlp) group counts for the order-deterministic
-    grouped reductions of the serving forward (out_project / mlp with
-    ``groups=``): the largest power of two ≤ 16 dividing the head count
-    / hidden width.  Any tensor-parallel degree dividing these groups
-    produces bitwise-identical serving outputs to tp=1, because the
-    only cross-shard float reductions run through
-    ``common.fixed_tree_sum`` whose addition order is fixed by the
-    group count alone."""
-    def pow2_div(n: int, cap: int = 16) -> int:
-        g = 1
-        while g < cap and n % (g * 2) == 0:
-            g *= 2
-        return g
-    return pow2_div(cfg.num_heads), pow2_div(cfg.d_ff)
-
-
 def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, *,
                paged: bool = False, block_size: int = 16,
                num_blocks: Optional[int] = None,
@@ -279,9 +262,9 @@ def _stored_layout(tree):
     more than once (the decode span), the TPU compiler is otherwise free
     to pick another layout for the loop-invariant stack — the one its
     small-M decode dots prefer — and copies every stacked weight once
-    up front: 6.4 GB more for Yi-6B, past one v5e's HBM.  Pinned, it
-    relayouts one layer's slice at a time, as a single decode step
-    does."""
+    up front: 6.4 GB more for Yi-6B, past one v5e's HBM.  Pinned, each
+    projection's dot reads its layer's slice of the stack in place
+    (tests/test_tpu_compile.py holds both programs to that)."""
     return jax.tree_util.tree_map(
         lambda x: with_layout_constraint(x, Layout(tuple(range(x.ndim)))),
         tree)
@@ -293,8 +276,6 @@ def _serving_scan(cfg, params, cache, x, pos, positions, block_table, *,
     pre-quantized fp8 weight slices) for the serving steps.  The cache
     travels as a pytree dict through scan's xs, so the same scan serves
     the contiguous, paged-bf16 and paged-fp8 layouts."""
-    ga, gm = serving_det_groups(cfg)
-
     def body(x, inp):
         if quant is None:
             lp, cl = inp
@@ -315,14 +296,14 @@ def _serving_scan(cfg, params, cache, x, pos, positions, block_table, *,
                               decode=decode, kernel=kernel, mesh=mesh,
                               mesh_axis=mesh_axis)
         if qlp is None:
-            x = x + attn.out_project(lp["attn"], o, groups=ga)
+            x = x + attn.out_project(lp["attn"], o, fp32_reduce=True)
         else:
             x = x + attn.out_project_fp8(lp["attn"], qlp["attn"], o)
         h = apply_norm(cfg, x, lp["ln2"])
         if cfg.family == "moe":
             y, _ = moe_mod.moe_mlp(cfg, lp["moe"], h)
         elif qlp is None:
-            y = mlp_mod.mlp(cfg, lp["mlp"], h, groups=gm)
+            y = mlp_mod.mlp(cfg, lp["mlp"], h, fp32_reduce=True)
         else:
             y = mlp_mod.mlp_fp8(cfg, lp["mlp"], qlp["mlp"], h)
         return x + y, cl

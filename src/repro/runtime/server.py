@@ -98,13 +98,13 @@ counts stay {chunk_step, decode_span, verify_step}.  Block tables, the
 refcounted allocator and the radix tree stay host-side and replicated,
 so paging, prefix caching and spec decode compose with TP unchanged.
 The only cross-shard float reductions (attention out-projection, MLP
-down-projection) run through order-deterministic fixed-tree grouped
-sums (models.transformer.serving_det_groups), so on the CPU greedy
-outputs at any supported ``tp`` are token-identical to ``tp=1``.  On a
-TPU v5e the sharded programs do not round bitwise like the whole one:
-at Yi-6B width 5 of 16 greedy requests came out identical at tp=4,
-with prompt logits 0.019 relative RMS apart (chip_smoke.py --tp 4
-holds them to its bf16 tolerance instead).
+down-projection) are single dots accumulating in fp32 whose shard
+partials are summed in fp32 before the cast to bf16, so ``tp>1``
+agrees with ``tp=1`` to the order of those fp32 sums.  At reduced size
+on the CPU that moves no greedy token (tests/test_tp_serving.py); at
+Yi-6B width on a TPU v5e, 5 of 16 greedy requests came out identical
+at tp=4, with prompt logits 0.019 relative RMS apart (chip_smoke.py
+--tp 4 holds the logits to its bf16 tolerance).
 
 The chunked path's per-slot math (bf16 activations, MXU attention dots
 accumulating in fp32, fp32 softmax over the masked cache) is the
@@ -375,11 +375,6 @@ class ChunkedServer:
             assert cfg.num_kv_heads % self.tp == 0, \
                 (f"tp={self.tp} must divide num_kv_heads="
                  f"{cfg.num_kv_heads} (the KV pool shards head-wise)")
-            ga, gm = transformer.serving_det_groups(cfg)
-            assert ga % self.tp == 0 and gm % self.tp == 0, \
-                (f"tp={self.tp} must divide the deterministic reduction "
-                 f"groups (attn={ga}, mlp={gm}) for exact tp-vs-1 "
-                 f"output parity")
         if self._plan is not None:
             self._param_sh = self._plan.param_shardings(cfg)
             self._cache_sh = self._plan.cache_sharding(cfg)
@@ -389,8 +384,7 @@ class ChunkedServer:
         self._quant = None
         if self.fp8_linear:
             assert self.tp == 1, \
-                ("fp8_linear serving is tp=1-only: the fp8 path has no "
-                 "grouped order-deterministic reduction structure")
+                "fp8_linear serving is tp=1-only"
             assert cfg.family != "moe", \
                 "fp8_linear serving is dense/vlm-only for now"
             self._quant = te_linear.quantize_serving_params(self.params)

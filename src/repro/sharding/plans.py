@@ -164,12 +164,10 @@ class ServingPlan:
         replicated), applied via sharding.axes.use_rules at trace time.
 
     Cross-shard float reductions (attention out-projection, MLP
-    down-projection) are made order-deterministic by the grouped
-    fixed-tree sums in models/{attention,mlp}.py
-    (models.transformer.serving_det_groups), so on the CPU greedy
-    outputs at any ``tp`` dividing the group counts are token-identical
-    to ``tp=1`` (on a TPU they agree within a tolerance; see
-    runtime/server.py).
+    down-projection) are single dots accumulating in fp32
+    (models/{attention,mlp}.py); the psum of their shard partials runs
+    in fp32, so ``tp>1`` agrees with ``tp=1`` to the order of those
+    sums, within a bf16 tolerance (see runtime/server.py).
     """
 
     mesh: Mesh

@@ -15,7 +15,6 @@ from jax.ad_checkpoint import checkpoint_name
 from repro.analysis import ast_lint, contracts, jaxpr_check, kernel_lint
 from repro.analysis.report import RULES, Finding, Report
 from repro.kernels import ops
-from repro.models.common import fixed_tree_sum
 
 TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
 REPO_ROOT = os.path.dirname(TESTS_DIR)
@@ -90,8 +89,7 @@ def test_jx003_undonated_cache_fires():
 
 def test_jx004_bf16_tree_reduction_fires():
     def bad(x):
-        parts = x.astype(jnp.bfloat16)
-        y = fixed_tree_sum(parts, tag="xshard_bad")
+        y = checkpoint_name(x.astype(jnp.bfloat16).sum(0), "xshard_bad")
         return checkpoint_name(y.astype(jnp.float32),
                                "serving_hot_path")
 

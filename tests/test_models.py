@@ -6,6 +6,8 @@ asserting output shapes and finiteness (the deliverable-(f) smoke
 tests).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -85,6 +87,86 @@ def test_decode_matches_prefill_dense(rng):
             np.asarray(logits, np.float32),
             np.asarray(full_logits[:, t], np.float32),
             rtol=0.15, atol=0.15)   # bf16 accumulation differences
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "codeqwen1.5-7b"])
+@pytest.mark.parametrize("proj", ["out_project", "mlp"])
+def test_serving_projections_match_f32_reference(arch, proj, rng):
+    """The serving forward's row-parallel projections (attention out, MLP
+    down; ``fp32_reduce=True``) are one contraction accumulating in fp32
+    and cast to bf16 once: each output
+    is an fp32 ``precision="highest"`` einsum of the same bf16 operands
+    rounded to bf16.  Rounding to nearest moves a value by at most half
+    a bf16 ulp, 2**-9 of it, and the fp32 sums may run in another order
+    (about 1e-7 of the sum of magnitudes); the tolerance, 2**-8
+    relative plus 1e-4 of the largest output, holds both with room,
+    and fails a contraction that drops or repeats a head or a hidden
+    group (an error of a whole term)."""
+    from repro.models import attention as attn
+    from repro.models import mlp as mlp_mod
+    from repro.models.common import activation
+    cfg = reduced_config(arch)
+    lp = jax.tree_util.tree_map(lambda a: a[0],
+                                api.init(cfg, rng)["layers"])
+    k1, k2 = jax.random.split(jax.random.fold_in(rng, 1))
+    hi = functools.partial(jnp.einsum, precision="highest",
+                           preferred_element_type=jnp.float32)
+    if proj == "out_project":
+        o = jax.random.normal(
+            k1, (3, 5, cfg.num_heads, cfg.head_dim)).astype(jnp.bfloat16)
+        got = attn.out_project(lp["attn"], o, fp32_reduce=True)
+        want = hi("bshk,hkd->bsd", o, lp["attn"]["wo"].astype(o.dtype))
+    else:
+        p = lp["mlp"]
+        x = jax.random.normal(k2, (3, 5, cfg.d_model)).astype(jnp.bfloat16)
+        got = mlp_mod.mlp(cfg, p, x, fp32_reduce=True)
+        # the hidden h exactly as the served path forms it, in bf16
+        gate = x @ p["w_gate"].astype(x.dtype) if "w_gate" in p else None
+        h = activation(cfg, x @ p["w_up"].astype(x.dtype), gate)
+        want = hi("bsf,fd->bsd", h, p["w_down"].astype(x.dtype))
+    assert got.dtype == jnp.bfloat16 and got.shape == want.shape
+    got, want = np.asarray(got, np.float32), np.asarray(want)
+    np.testing.assert_allclose(
+        got, want, rtol=2 ** -8, atol=1e-4 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("proj", ["out_project", "mlp"])
+def test_training_projections_keep_bf16_dot_outputs(proj, rng):
+    """Training calls the projections without ``fp32_reduce``: every dot
+    outputs the bf16 activation dtype, so remat="dots" saves bf16
+    residuals and a tp plan all-reduces bf16, and no ``xshard_`` tag
+    enters the training loss's trace.  The serving variant's last dot
+    outputs fp32 under its tag, so the check tells the two apart."""
+    from repro.analysis.jaxpr_check import collect_tags, iter_eqns
+    from repro.models import attention as attn
+    from repro.models import mlp as mlp_mod
+    from repro.models import transformer
+    cfg = reduced_config("yi-6b")
+    params = api.init(cfg, rng)
+    lp = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
+    if proj == "out_project":
+        arg = jnp.ones((3, 5, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
+        f = lambda a, r: attn.out_project(lp["attn"], a, fp32_reduce=r)  # noqa: E731
+    else:
+        arg = jnp.ones((3, 5, cfg.d_model), jnp.bfloat16)
+        f = lambda a, r: mlp_mod.mlp(cfg, lp["mlp"], a, fp32_reduce=r)  # noqa: E731
+
+    def dots_and_tags(reduce32):
+        jaxpr = jax.make_jaxpr(lambda a: f(a, reduce32))(arg).jaxpr
+        dots = [e.outvars[0].aval.dtype for e in iter_eqns(jaxpr)
+                if e.primitive.name == "dot_general"]
+        return dots, [t for t, _ in collect_tags(jaxpr)]
+
+    dots, tags = dots_and_tags(False)
+    assert dots and set(dots) == {jnp.dtype(jnp.bfloat16)} and not tags
+    dots, tags = dots_and_tags(True)
+    assert dots[-1] == jnp.float32 and len(tags) == 1, (dots, tags)
+    batch = {"tokens": jnp.zeros((2, 8), jnp.int32),
+             "labels": jnp.zeros((2, 8), jnp.int32)}
+    loss = jax.make_jaxpr(jax.grad(
+        lambda p: transformer.loss_fn(cfg, p, batch)))(params)
+    assert not [t for t, _ in collect_tags(loss.jaxpr)
+                if t.startswith("xshard_")]
 
 
 def test_decode_matches_prefill_ssm(rng):

@@ -11,6 +11,7 @@ every test file.  Nothing here runs on a TPU and nothing reports a time.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -26,6 +27,10 @@ from repro.launch import compile_cache
 from repro.models import api
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from bench import harness  # noqa: E402
 
 # serving geometry of chip_smoke.py: 8 slots of max_len 2048 in 16-token
 # blocks, 16-token prefill chunks
@@ -91,30 +96,98 @@ def test_paged_kernels_compile_for_v5e(one_chip, kind, pool):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("arch,max_len", [("yi-6b", MAX_LEN),
-                                          ("codeqwen1.5-7b", 1536)])
-def test_serving_steps_fit_one_v5e(one_chip, monkeypatch, arch, max_len):
-    """The server's chunk and span work units at full width with bf16
-    weights, 8 slots: arguments + temporaries - donated aliases fit one
-    chip's HBM.  Yi-6B at chip_smoke.py's 8 x 2048; CodeQwen1.5-7B
-    (weights 13.5 GiB) at its benchmark cell's 8 x 1536.  The server is
-    built over shapes only: weights and the KV pool are never
-    allocated."""
+@pytest.fixture(scope="module")
+def serving_compiles(one_chip):
+    """arch -> {program name: compiled} for the server's chunk and span
+    work units at full width with bf16 weights, at the slots and max_len
+    of the arch's benchmark configuration (bench/configs/<arch>.json,
+    read through bench/harness.py); each arch compiles once for every
+    test that asks.  The server is built over shapes only: weights and
+    the KV pool are never allocated."""
     from repro.analysis.jaxpr_check import serving_programs
     from repro.runtime.server import ChunkedServer
-    cfg = get_config(arch)
-    params = jax.eval_shape(
-        lambda: api.init(cfg, jax.random.PRNGKey(0), dtype=cfg.dtype))
-    real_init_cache = api.init_cache
-    monkeypatch.setattr(api, "init_cache", lambda *a, **k: jax.eval_shape(
-        lambda: real_init_cache(*a, **k)))
-    srv = ChunkedServer(cfg, params, batch_slots=SLOTS, max_len=max_len,
-                        chunk=CHUNK, block_size=BS)
-    for name, _, jitted, ops in serving_programs(srv):
-        mem = jitted.lower(*_on(one_chip, ops)).compile().memory_analysis()
+    done = {}
+
+    def compiled(arch):
+        if arch not in done:
+            cell = next(w["name"] for w in harness.load_benchmark()
+                        ["workloads"] if w["config"] == arch)
+            conf = harness.load_cell(cell)["conf"]
+            cfg = harness.program_config(conf)
+            params = jax.eval_shape(lambda: api.init(
+                cfg, jax.random.PRNGKey(0), dtype=cfg.dtype))
+            real_init_cache = api.init_cache
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(api, "init_cache",
+                           lambda *a, **k: jax.eval_shape(
+                               lambda: real_init_cache(*a, **k)))
+                srv = ChunkedServer(cfg, params,
+                                    batch_slots=conf["batch_slots"],
+                                    max_len=conf["max_len"])
+            done[arch] = {
+                name: jitted.lower(*_on(one_chip, ops)).compile()
+                for name, _, jitted, ops in serving_programs(srv)}
+        return done[arch]
+
+    return compiled
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "codeqwen1.5-7b"])
+def test_serving_steps_fit_one_v5e(serving_compiles, arch):
+    """The server's chunk and span work units at full width with bf16
+    weights, at each benchmark cell's slots x max_len (CodeQwen1.5-7B's
+    weights take 13.5 GiB): arguments + temporaries - donated aliases
+    fit one chip's HBM."""
+    for name, compiled in serving_compiles(arch).items():
+        mem = compiled.memory_analysis()
         need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 - mem.alias_size_in_bytes)
         assert need < hw.TPU_V5E.hbm_bytes, (name, need)
+
+
+def _layer_weight_producers(hlo, cfg):
+    """Instructions outside fused computations whose result is one
+    layer's weight matrix (bf16 [d,f], [f,d], [1,d,f], [1,f,d], or wo
+    as [1,H,hd,d], [H,hd,d] or [H*hd,d]): a slice copied out of the
+    stacked weights, or its relayout.  A slice read in place inside a
+    dot's fusion is not one."""
+    d, f = cfg.d_model, cfg.d_ff
+    H, hd = cfg.num_heads, cfg.head_dim
+    shapes = {(d, f), (f, d), (1, d, f), (1, f, d),
+              (1, H, hd, d), (H, hd, d), (H * hd, d)}
+    fused = {c for line in hlo.splitlines() if " fusion(" in line
+             for c in re.findall(r"calls=%?([\w.\-]+)", line)}
+    found, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        out = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]*)\]", line)
+        if comp not in fused and out and tuple(
+                int(n) for n in out.group(1).split(",") if n) in shapes:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("arch,span_temp_gib", [("yi-6b", 1.55),
+                                                ("codeqwen1.5-7b", 0.80)])
+def test_serving_steps_read_layer_weights_in_place(serving_compiles, arch,
+                                                   span_temp_gib):
+    """Neither the chunk step nor the decode span copies or relayouts a
+    layer's weight matrix out of the stacked weights: every projection
+    is one dot that reads its layer's slice in place.  A grouped
+    (per-head-group or per-hidden-group) contraction made the compiler
+    copy w_down and wo out of the stack and transpose w_up/w_gate on
+    every span step, and raised the span's temporaries above these
+    bounds."""
+    cfg = get_config(arch)
+    for name, compiled in serving_compiles(arch).items():
+        found = _layer_weight_producers(compiled.as_text(), cfg)
+        assert not found, (name, found)
+    span = serving_compiles(arch)["decode_span"].memory_analysis()
+    assert span.temp_size_in_bytes <= span_temp_gib * 2 ** 30, \
+        span.temp_size_in_bytes
 
 
 def test_sample_head_compiles_without_vocab_gather_or_scatter(one_chip):
